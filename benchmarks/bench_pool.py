@@ -28,6 +28,13 @@ microbench.py discipline; ``SRJT_RESULTS`` appends them to a file):
   aggregate >= 2.5x world-2: growing the world grows cross-rank
   volume per rank, so a healthy data plane scales super-linearly.
 
+All three stages are CPU functional gates, not device measurements:
+this process imports ``ops`` (which initialises the JAX backend at
+import) and then starts worker processes. A chip has one owner, so on
+a TPU the pool refuses to start from here
+(``sidecar_pool._refuse_second_chip_owner``), and a pool of more than
+one worker cannot share one chip at all (ROADMAP.md C4).
+
 Usage::
 
     python benchmarks/bench_pool.py                     # all stages

@@ -24,6 +24,10 @@ bench.py discipline; ``SRJT_RESULTS`` appends to a file):
    out-of-core query past the two re-attached partitions
    (``ooc.partition_resumes`` crossing processes).
 
+A CPU functional gate: the doomed child is pinned to
+``JAX_PLATFORMS=cpu`` (a parent that has touched the device holds the
+chip, so the child could not have it), and the row is no device number.
+
 Gates (exit 1): zero wrong answers, ``replays`` == 1 with a truncated
 tail, ``reattached`` > 0, ``resumes`` > 0, manifest rot counted on the
 torn sidecar, zero duplicate executions of DONE work, and the torn

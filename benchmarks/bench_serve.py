@@ -46,6 +46,13 @@ bench_pool.py discipline; ``SRJT_RESULTS`` appends them to a file):
   + evictions-landed gates apply (hit economics are meaningless while
   entries are being shot down).
 
+The chaos, gray and pool tiers are CPU functional gates, not device
+measurements: this process imports the models (which initialises the
+JAX backend at import) and then starts a ``SidecarPool``. A chip has
+one owner, so on a TPU the pool refuses to start from here
+(``sidecar_pool._refuse_second_chip_owner``); rows from these tiers
+name no device and are not speed results.
+
 Usage::
 
     python benchmarks/bench_serve.py                      # steady BENCH row

@@ -742,12 +742,12 @@ EOF
 # ISSUE 13 acceptance bar, enforced by the same gate when premerge
 # runs on-chip).
 rm -f artifacts/kernel_tier_metrics.jsonl artifacts/bench_kernel_tier.jsonl
-SRJT_PALLAS_INTERPRET=1 SRJT_METRICS_ENABLED=1 \
+SRJT_PALLAS_INTERPRET=1 SRJT_PALLAS_DECODE=1 SRJT_METRICS_ENABLED=1 \
   SRJT_METRICS_LOG=artifacts/kernel_tier_metrics.jsonl \
   python -m pytest tests/test_pallas_kernels.py -q
 SRJT_PALLAS_INTERPRET=1 SRJT_RESULTS=artifacts/bench_kernel_tier.jsonl \
   python benchmarks/microbench.py --bench join --rows 20000 --reps 2
-SRJT_PALLAS_INTERPRET=1 SRJT_RESULTS=artifacts/bench_kernel_tier.jsonl \
+SRJT_PALLAS_INTERPRET=1 SRJT_PALLAS_DECODE=1 SRJT_RESULTS=artifacts/bench_kernel_tier.jsonl \
   python benchmarks/microbench.py --bench ragged_decode --rows 20000 --reps 2
 python - <<'EOF'
 import json

@@ -26,6 +26,10 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
+from .utils import compile_cache as _compile_cache  # noqa: E402
+
+_compile_cache.configure()
+
 from . import columnar  # noqa: E402,F401
 from .columnar import Column, DType, Table, TypeId  # noqa: E402,F401
 

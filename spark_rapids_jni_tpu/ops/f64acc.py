@@ -1,7 +1,7 @@
 """Exact float64 accumulation + double-f32 arithmetic on integer-only
-datapaths (TPU v5e has no f64 ALU; XLA's x64 rewrite demotes f64
-arithmetic to f32 and this platform's compile helper rejects f64
-bitcasts outright — NOTES_ROUND3).
+datapaths (TPU v5e has no f64 ALU, and the design keeps f64 values as
+IEEE bit patterns in u64 lanes so that no f64 bitcast or f64 arithmetic
+is ever asked of the device).
 
 The reference sums doubles in real f64 on device (cudf segment reduce;
 SURVEY §2.8), so Spark ``sum(double)`` semantics require f64-accurate
@@ -156,7 +156,7 @@ def _accumulate_mxu(
 
     The round-4 payload formulation ([N, LIMBS+3] int64 stacked per
     element, segment-summed) was per-element ALU/relayout-bound: ~0.34 s
-    per fused-q1 iteration at 1M rows (NOTES_ROUND4 item 5). Here the
+    per fused-q1 iteration at 1M rows. Here the
     reduction rides the systolic array instead: each 32-bit limb splits
     into 8 nibble planes (values 0..15, int8), planes stack row-major as
     B [8*LIMBS+3, N], and a signed one-hot A [G, N] (+1/-1 by element
@@ -250,7 +250,7 @@ def _accumulate(bits, valid, seg, num_segments) -> _GroupSum:
     if num_segments * bits.shape[0] <= _MXU_ONEHOT_BUDGET:
         # hot path (round 5): signed one-hot int8 MXU contraction —
         # bit-identical to the payload reduction below, at matmul
-        # bandwidth instead of per-element i64 ALU (NOTES_ROUND4 item 5)
+        # bandwidth instead of per-element i64 ALU
         return _accumulate_mxu(
             neg, e_eff, mant, is_nan, is_pinf, is_ninf, live, emax, seg, num_segments
         )
@@ -869,7 +869,7 @@ def add2_f64bits(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
     The windowed accumulator with one segment per element is a scatter
     over [2N] rows — measured ~0.34 s/iter at 1M rows inside the fused
-    pipelines (the round-4 flagship regression, NOTES_ROUND4 item 5).
+    pipelines (the round-4 flagship regression).
     A two-addend sum needs no window at all: align the smaller mantissa
     into an 8-bit guard extension of the larger (61 bits total, flat
     u64 lanes), fold bits beyond the guard into a sticky (for effective

@@ -26,10 +26,12 @@ probe emits each row's match range in one fused pass, skipping the
 (nl + nr)-row concatenated sort entirely. Gather maps are BIT-IDENTICAL
 to the XLA path (both orders tie-break equal keys by original build
 row). Gate: ``SRJT_PALLAS_JOIN`` + backend (see kernel_tier_mode);
-unsupported dtypes/shapes, over-cap build sides, and ANY kernel-tier
-exception fall back to the XLA formulation silently — a kernel-tier
-failure must degrade, never error. The serving tier lands on the op
-span and the ``dispatch.tier.*`` counters (utils/dispatch.note_tier).
+unsupported dtypes/shapes and over-cap build sides select the XLA
+formulation (counted ``dispatch.tier.xla``: selection by shape, not a
+fallback). An exception from the kernel propagates — a Mosaic refusal
+must be seen, not answered from another path. The serving tier lands on
+the op span and the ``dispatch.tier.*`` counters
+(utils/dispatch.note_tier).
 """
 
 from __future__ import annotations
@@ -41,7 +43,6 @@ import numpy as np
 
 from ..columnar import Column, Table
 from ..columnar.dtype import TypeId
-from ..utils import metrics
 from ..utils.dispatch import note_tier, op_boundary
 from .aggregate import _segment_ids
 from .copying import concatenate, gather, gather_column
@@ -161,14 +162,10 @@ def join_gather_maps(
         raise ValueError(f"unsupported join type {how!r}")
     mode = _pallas_join_usable(left_keys, right_keys, how)
     if mode:
-        try:
-            maps = _pallas_join_maps(
-                left_keys, right_keys, how, mode == "interpret"
-            )
-        except Exception:  # srjt-lint: allow-broad-except(kernel-tier contract: any probe/build failure degrades to the XLA formulation, never errors the join)
-            maps = None
-            metrics.event("dispatch.tier_degrade", op="join", tier=mode)
-            note_tier("degrade", "join_gather_maps")
+        # a kernel exception propagates: None is selection by shape
+        maps = _pallas_join_maps(
+            left_keys, right_keys, how, mode == "interpret"
+        )
         if maps is not None:
             note_tier("pallas", "join_gather_maps")
             return maps

@@ -27,14 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu import fails without the TPU plugin; interpret mode still works
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover  # srjt-lint: allow-broad-except(optional TPU-plugin import guard; interpret mode works without pltpu)
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 __all__ = [
     "overlap_tiles",
@@ -52,15 +47,14 @@ __all__ = [
 ]
 
 
-from .pallas_kernels import on_tpu as _on_tpu  # noqa: E402  (memoized probes)
-from .pallas_kernels import pallas_available as _pallas_available  # noqa: E402
+from .pallas_kernels import on_tpu as _on_tpu  # noqa: E402  (memoized probe)
 
 
 def _use_pallas() -> bool:
-    # memoized probes (pallas_kernels): this gate sits on every ragged
+    # memoized probe (pallas_kernels): this gate sits on every ragged
     # helper's hot path and jax.default_backend() re-walks the backend
     # registry per call (ISSUE 13 satellite)
-    return _pallas_available() and _on_tpu()
+    return _on_tpu()
 
 
 def _pow2_ceil(v: int) -> int:
@@ -725,26 +719,21 @@ def ragged_compact_tiered(
     """EAGER kernel-tier dispatcher for ``ragged_compact`` (ISSUE 13):
     the fused Pallas decode kernel when ``SRJT_PALLAS_DECODE`` arms and
     the probed windows fit (pallas_kernels.pallas_ragged_compact), the
-    XLA formulation otherwise — bit-identical either way, and ANY
-    kernel-tier failure degrades silently. Host-syncs the window probe,
+    XLA formulation otherwise — bit-identical either way; an exception
+    from the kernel propagates. Host-syncs the window probe,
     so inside-jit callers (the fused multi-column decode program) keep
     calling ``ragged_compact`` directly; row_conversion batches its
     per-column probes through the ``hint`` path instead."""
-    from ..utils import metrics
     from ..utils.dispatch import note_tier
     from .pallas_kernels import kernel_tier_mode, pallas_ragged_compact
 
     mode = kernel_tier_mode("SRJT_PALLAS_DECODE")
     if mode and int(total) > 0:
-        try:
-            out = pallas_ragged_compact(
-                pool, base, offs, int(total), pool32=pool32,
-                interpret=mode == "interpret",
-            )
-        except Exception:  # srjt-lint: allow-broad-except(kernel-tier contract: any kernel failure degrades to the XLA formulation, never errors the decode)
-            out = None
-            metrics.event("dispatch.tier_degrade", op="ragged_compact", tier=mode)
-            note_tier("degrade", "ragged_compact")
+        # a kernel exception propagates: None is selection by shape
+        out = pallas_ragged_compact(
+            pool, base, offs, int(total), pool32=pool32,
+            interpret=mode == "interpret",
+        )
         if out is not None:
             note_tier("pallas", "ragged_compact")
             return out

@@ -24,15 +24,9 @@ from typing import Callable
 
 import jax
 
-try:
-    # modern spelling (jax >= 0.5); older jax ships it under
-    # experimental with the same (f, mesh, in_specs, out_specs)
-    # surface. Every distributed site imports the symbol from here so
-    # the whole parallel tier degrades together, not call-site by
-    # call-site.
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
+# every distributed site imports the symbol from here so the memo and
+# the primitive stay in one place
+shard_map = jax.shard_map
 
 _CACHE: OrderedDict = OrderedDict()
 _MAX_ENTRIES = 128

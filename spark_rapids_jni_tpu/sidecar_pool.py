@@ -483,6 +483,34 @@ class _Worker:
         self.probe_thread: Optional[threading.Thread] = None
 
 
+def _refuse_second_chip_owner(worker_env: Optional[dict]) -> None:
+    """A chip belongs to one process at a time: a parent that has
+    initialised the TPU backend holds it, and a worker that needs it
+    then fails or hangs. Refuse to start such a pool, naming the
+    conflict. (Importing ``.ops`` — hence ``.plan``, ``.pipeline``,
+    ``.models``, ``.cache`` — initialises the backend at import.)
+    Workers pinned to the CPU by ``JAX_PLATFORMS`` are no conflict."""
+    from jax._src import xla_bridge
+
+    if "tpu" not in xla_bridge._backends:
+        return
+    # JAX's own variable decides which device the worker takes
+    platforms = (worker_env or {}).get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
+    )
+    if platforms.split(",")[0].strip() == "cpu":
+        return
+    from .utils.errors import FatalDeviceError
+
+    raise FatalDeviceError(
+        "this process has initialised the TPU backend and so holds the "
+        "chip; a sidecar worker started from it would need the same "
+        "chip. Start the pool from a process that has not touched the "
+        "device (do not import spark_rapids_jni_tpu.ops/.plan/.models "
+        "there), or pin the workers with JAX_PLATFORMS=cpu"
+    )
+
+
 class SidecarPool:
     """Supervised pool of sidecar workers with health-checked routing,
     automatic respawn, slab re-hydration, and pool-scoped breaker
@@ -508,6 +536,7 @@ class SidecarPool:
             size = _env_int("SRJT_SIDECAR_POOL_SIZE")
         if size < 1:
             raise ValueError(f"pool size must be >= 1, got {size}")
+        _refuse_second_chip_owner(env)
         self.size = int(size)
         self._deadline_s = deadline_s
         self._heartbeat_s = heartbeat_s

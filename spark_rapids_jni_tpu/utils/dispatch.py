@@ -25,9 +25,9 @@ __all__ = ["op_boundary", "note_tier"]
 
 
 # Kernel-tier observability (ISSUE 13): tiered ops report which
-# formulation actually served a dispatch — ``pallas`` (kernel tier),
-# ``xla`` (the fallback formulation), or ``host`` (host-engine
-# degrade). Counted REGISTRY-DIRECT (the memory.split_retries
+# formulation actually served a dispatch — ``pallas`` (kernel tier) or
+# ``xla`` (the XLA formulation, selected by knob, backend or shape).
+# Counted REGISTRY-DIRECT (the memory.split_retries
 # discipline: durable bookkeeping, independent of the
 # SRJT_METRICS_ENABLED hot-path gate) so BENCH drivers and the premerge
 # kernel-tier gate can prove the pallas path engaged; with tracing

@@ -575,10 +575,11 @@ declare("SRJT_PALLAS_JOIN", "bool", True,
         "arm the paged-hash-table Pallas join tier for single int-key "
         "inner/left joins (0 forces the XLA sort-probe formulation; "
         "unsupported shapes/dtypes fall back automatically either way)")
-declare("SRJT_PALLAS_DECODE", "bool", True,
+declare("SRJT_PALLAS_DECODE", "bool", False,
         "arm the fused ragged-decode Pallas kernel for string-column "
-        "row decode (0 forces the XLA scatter/funnel formulation; "
-        "over-cap windows fall back automatically either way)")
+        "row decode (off: the XLA scatter/funnel formulation serves). "
+        "Off by default since the v5e's compiler refuses the kernel at "
+        "lowering (ROADMAP.md A2); interpret mode still runs it")
 declare("SRJT_PALLAS_INTERPRET", "bool", False,
         "run kernel-tier Pallas paths through the Pallas interpreter "
         "off-TPU (hermetic CI parity of the exact kernel bodies; "

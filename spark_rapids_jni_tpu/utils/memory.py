@@ -74,25 +74,37 @@ _STATS_DEV = None  # device whose memory_stats() reports live bytes_in_use
 _MIN_BUDGET = 64 << 20  # floor after bytes_in_use subtraction
 
 
-def _resolve_backend_budget() -> int:
-    """One-time probe of the backend's reported limit (or the platform
-    default); remembers the device handle when it can report live
-    ``bytes_in_use``."""
-    global _STATS_DEV
-    try:
-        import jax
+# HBM per chip by ``device_kind``, for a TPU whose runtime reports no
+# ``bytes_limit``. Source: Google Cloud documentation, "TPU v5e" (16 GB
+# per chip). A kind that is not listed is an error, not a default.
+_TPU_HBM_BYTES = {"TPU v5 lite": 16 << 30}
+_CPU_BUDGET = 4 << 30  # host RAM share; the CPU backend reports no limit
 
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats() if hasattr(dev, "memory_stats") else None
-        if stats and stats.get("bytes_limit"):
-            if stats.get("bytes_in_use") is not None:
-                _STATS_DEV = dev
-            return int(stats["bytes_limit"] * 0.5)
-        if dev.platform == "tpu":
-            return 8 << 30  # half of v5e's 16 GB HBM
-    except Exception:  # srjt-lint: allow-broad-except(backend probe is best-effort; any failure falls to the conservative platform default)
-        pass
-    return 4 << 30  # conservative CPU-tier default
+
+def _resolve_backend_budget() -> int:
+    """One-time probe: half of the limit the device reports (the other
+    half is headroom for XLA temps), else half of its kind's HBM from
+    the table above; remembers the device handle when it can report
+    live ``bytes_in_use``."""
+    global _STATS_DEV
+    import jax
+
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats and stats.get("bytes_limit"):
+        if stats.get("bytes_in_use") is not None:
+            _STATS_DEV = dev
+        return int(stats["bytes_limit"] * 0.5)
+    if dev.platform == "cpu":
+        return _CPU_BUDGET
+    hbm = _TPU_HBM_BYTES.get(dev.device_kind) if dev.platform == "tpu" else None
+    if hbm is None:
+        raise RuntimeError(
+            f"device {dev.device_kind!r} ({dev.platform}) reports no "
+            "bytes_limit and is not in utils/memory._TPU_HBM_BYTES: set "
+            "SRJT_DEVICE_MEMORY_BUDGET or add its HBM size with a source"
+        )
+    return hbm // 2
 
 
 def device_memory_budget() -> int:
@@ -100,9 +112,9 @@ def device_memory_budget() -> int:
 
     Resolution order: ``SRJT_DEVICE_MEMORY_BUDGET`` (bytes; read LIVE —
     the test hook and the operator override), else the memoized backend
-    probe — the reported limit when the backend exposes one, else a
-    platform default (v5e HBM less runtime reserve; host RAM share on
-    CPU) — minus the backend's live ``bytes_in_use`` when it reports
+    probe — half the limit the device reports, else half its kind's HBM from
+    a small table (an unknown kind raises), else the host RAM share on
+    CPU — minus the backend's live ``bytes_in_use`` when it reports
     one (floored at 64 MiB so transient allocator spikes degrade to
     splitting, never to a zero budget). The budget is per-op headroom,
     not the raw chip size: XLA temps routinely need a small multiple of

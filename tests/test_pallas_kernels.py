@@ -362,6 +362,7 @@ def test_fused_decode_window_gate_returns_none(rng):
 
 def test_tiered_decode_forced_fallback_mid_suite(rng, monkeypatch):
     monkeypatch.setenv("SRJT_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("SRJT_PALLAS_DECODE", "1")
     pool, base, offs, total = _ragged_case(rng, 400, 16, 4, 0.2)
     p0, x0 = _tier_count("pallas"), _tier_count("xla")
     a = np.asarray(ragged_compact_tiered(pool, base, offs, total))
@@ -370,7 +371,7 @@ def test_tiered_decode_forced_fallback_mid_suite(rng, monkeypatch):
     b = np.asarray(ragged_compact_tiered(pool, base, offs, total))
     assert _tier_count("xla") == x0 + 1
     np.testing.assert_array_equal(a, b)
-    monkeypatch.delenv("SRJT_PALLAS_DECODE")
+    monkeypatch.setenv("SRJT_PALLAS_DECODE", "1")
     c = np.asarray(ragged_compact_tiered(pool, base, offs, total))
     assert _tier_count("pallas") == p0 + 2
     np.testing.assert_array_equal(a, c)
@@ -383,6 +384,7 @@ def test_string_decode_through_row_conversion(rng, monkeypatch):
     from spark_rapids_jni_tpu.ops import row_conversion as rc
 
     monkeypatch.setenv("SRJT_PALLAS_INTERPRET", "1")
+    monkeypatch.setenv("SRJT_PALLAS_DECODE", "1")
     dtypes = [dt.INT32, dt.STRING, dt.FLOAT64, dt.STRING]
     profiles = {1: Profile(min_length=0, max_length=24), 3: Profile(min_length=1, max_length=9)}
     table = create_random_table(dtypes, 1500, seed=77, profiles=profiles)
@@ -436,7 +438,6 @@ def test_backend_probes_memoized(monkeypatch):
     from spark_rapids_jni_tpu.ops import pallas_kernels as pk
 
     pk._reset_probe_cache()
-    assert pk.pallas_available() in (True, False)
     assert pk.on_tpu() is False  # hermetic tier runs on CPU
     # memoized: even a monkeypatched backend probe is not re-consulted
     monkeypatch.setattr(
@@ -456,3 +457,86 @@ def test_kernel_tier_mode_gates(monkeypatch):
     assert pk.kernel_tier_mode("SRJT_PALLAS_JOIN") == "interpret"
     monkeypatch.setenv("SRJT_PALLAS_JOIN", "0")
     assert pk.kernel_tier_mode("SRJT_PALLAS_JOIN") == ""
+
+
+# ---------------------------------------------------------------------------
+# a kernel that fails must be seen (ISSUE 22): no silent XLA answer
+# ---------------------------------------------------------------------------
+
+
+from spark_rapids_jni_tpu.utils import errors
+
+
+class _MosaicRefusal(Exception):
+    pass
+
+
+def _refuse(*_a, **_k):
+    raise _MosaicRefusal("injected kernel failure")
+
+
+def _dispatch_join(rng):
+    lk = rng.integers(0, 40, 200).astype(np.int64)
+    rk = rng.integers(0, 40, 150).astype(np.int64)
+    return join_ops.join_gather_maps(_key_table(lk, dt.INT64), _key_table(rk, dt.INT64), "inner")
+
+
+def _dispatch_ragged(rng):
+    return ragged_compact_tiered(*_ragged_case(rng, 100, 16, 4))
+
+
+def _dispatch_string_decode(rng):
+    from spark_rapids_jni_tpu.models.datagen import Profile, create_random_table
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    table = create_random_table([dt.INT32, dt.STRING], 64, seed=5,
+                                profiles={1: Profile(min_length=1, max_length=9)})
+    with pytest.MonkeyPatch.context() as mp:  # encode on the XLA path
+        mp.setenv("SRJT_PALLAS_DECODE", "0")
+        rows = rc.convert_to_rows(table)[0]
+    return rc.convert_from_rows(rows, table.dtypes())
+
+
+_SITES = {
+    # dispatch site -> (driver, module holding the kernel entry, its name)
+    "join": (_dispatch_join, "spark_rapids_jni_tpu.ops.join", "_pallas_join_maps"),
+    "ragged_compact": (_dispatch_ragged, "spark_rapids_jni_tpu.ops.pallas_kernels",
+                       "pallas_ragged_compact"),
+    "string_decode": (_dispatch_string_decode, "spark_rapids_jni_tpu.ops.row_conversion",
+                      "_pallas_string_chars"),
+}
+
+
+@pytest.mark.parametrize("mode", ["tpu", "interpret"])
+@pytest.mark.parametrize("site", sorted(_SITES))
+def test_kernel_failure_propagates(rng, monkeypatch, site, mode):
+    import importlib
+
+    from spark_rapids_jni_tpu.ops import pallas_kernels as pk
+
+    drive, module, entry = _SITES[site]
+    monkeypatch.setattr(pk, "kernel_tier_mode", lambda _knob: mode)
+    monkeypatch.setattr(importlib.import_module(module), entry, _refuse)
+    x0 = _tier_count("xla")
+    # op_boundary ops re-raise in the device-error taxonomy, cause kept
+    with pytest.raises((_MosaicRefusal, errors.DeviceError)) as raised:
+        drive(rng)
+    e = raised.value
+    assert isinstance(e, _MosaicRefusal) or isinstance(e.__cause__, _MosaicRefusal)
+    assert _tier_count("xla") == x0  # nothing answered from the other path
+
+
+@pytest.mark.parametrize("site", sorted(_SITES))
+def test_none_from_kernel_selects_xla(rng, monkeypatch, site):
+    """A shape outside the kernel's caps (None) is selection, not a
+    fallback: XLA serves and is counted as such."""
+    import importlib
+
+    from spark_rapids_jni_tpu.ops import pallas_kernels as pk
+
+    drive, module, entry = _SITES[site]
+    monkeypatch.setattr(pk, "kernel_tier_mode", lambda _knob: "interpret")
+    monkeypatch.setattr(importlib.import_module(module), entry, lambda *a, **k: None)
+    p0, x0 = _tier_count("pallas"), _tier_count("xla")
+    drive(rng)
+    assert _tier_count("xla") > x0 and _tier_count("pallas") == p0

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from spark_rapids_jni_tpu import memgov, sidecar, sidecar_pool
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.columnar import dtype as dt
-from spark_rapids_jni_tpu.utils import faultinj, integrity, metrics, retry
+from spark_rapids_jni_tpu.utils import errors, faultinj, integrity, metrics, retry
 from spark_rapids_jni_tpu.utils.errors import DataCorruption, RetryableError
 
 
@@ -932,3 +932,56 @@ class TestRealWorkerPool:
         finally:
             pool.shutdown()
             sidecar.breaker().reset()
+
+
+# ---------------------------------------------------------------------------
+# one owner of the chip (ISSUE 22)
+# ---------------------------------------------------------------------------
+
+
+class TestOneChipOwner:
+    """A parent that has initialised the TPU backend holds the chip; a
+    pool whose workers need it must refuse to start, before any spawn."""
+
+    @pytest.fixture
+    def holds_tpu(self, monkeypatch):
+        from jax._src import xla_bridge
+
+        monkeypatch.setattr(xla_bridge, "_backends",
+                            {**xla_bridge._backends, "tpu": object()})
+
+    @pytest.mark.parametrize("inherited,override", [
+        (None, None),              # no JAX_PLATFORMS anywhere: worker takes the chip
+        ("tpu,cpu", None),         # what the chip machine exports
+        ("cpu", {"JAX_PLATFORMS": "tpu"}),
+    ])
+    def test_pool_refuses_when_parent_holds_the_chip(
+        self, monkeypatch, holds_tpu, inherited, override
+    ):
+        if inherited is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", inherited)
+        spawned = []
+
+        def spawn(**kw):
+            spawned.append(kw)
+            return _inproc_spawn()
+
+        with pytest.raises(errors.FatalDeviceError, match="holds the chip"):
+            sidecar_pool.SidecarPool(size=1, spawn_fn=spawn, env=override)
+        assert not spawned  # refused before any worker was started
+
+    @pytest.mark.parametrize("inherited,override", [
+        ("cpu", None),
+        ("tpu,cpu", {"JAX_PLATFORMS": "cpu"}),  # workers pinned to the CPU
+    ])
+    def test_cpu_workers_are_no_conflict(self, monkeypatch, holds_tpu, inherited, override):
+        monkeypatch.setenv("JAX_PLATFORMS", inherited)
+        with sidecar_pool.SidecarPool(size=1, spawn_fn=_inproc_spawn, env=override) as pool:
+            assert pool.live_count() == 1
+
+    def test_parent_off_the_chip_starts_the_pool(self, monkeypatch):
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)  # the conftest pin is on the live config
+        with sidecar_pool.SidecarPool(size=1, spawn_fn=_inproc_spawn) as pool:
+            assert pool.live_count() == 1

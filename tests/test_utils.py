@@ -161,3 +161,59 @@ class TestTracing:
             assert out.num_rows == 2
         finally:
             tracing.set_enabled(False)
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind, stats):
+        self.platform, self.device_kind, self._stats = platform, device_kind, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+class TestBackendBudget:
+    """utils/memory._resolve_backend_budget: read from the device, else
+    from a table keyed by device_kind that raises on an unknown kind."""
+
+    @pytest.mark.parametrize("dev,want", [
+        # the chip reports its limit: half of it is the per-op budget
+        (_FakeDevice("tpu", "TPU v5 lite", {"bytes_limit": 12 << 30, "bytes_in_use": 0}), 6 << 30),
+        # no limit reported: the device_kind table (v5e: 16 GB)
+        (_FakeDevice("tpu", "TPU v5 lite", None), 8 << 30),
+        (_FakeDevice("tpu", "TPU v5 lite", {}), 8 << 30),
+        # the CPU backend reports nothing: host-RAM share
+        (_FakeDevice("cpu", "cpu", None), 4 << 30),
+    ])
+    def test_budget_sources(self, monkeypatch, dev, want):
+        import jax
+
+        from spark_rapids_jni_tpu.utils import memory
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [dev])
+        monkeypatch.setattr(memory, "_STATS_DEV", None)
+        assert memory._resolve_backend_budget() == want
+
+    @pytest.mark.parametrize("dev", [
+        _FakeDevice("tpu", "TPU v9 imaginary", None),
+        _FakeDevice("gpu", "some gpu", None),
+    ])
+    def test_unknown_kind_is_an_error_not_a_default(self, monkeypatch, dev):
+        import jax
+
+        from spark_rapids_jni_tpu.utils import memory
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [dev])
+        with pytest.raises(RuntimeError, match="reports no bytes_limit"):
+            memory._resolve_backend_budget()
+
+    def test_probe_failure_propagates(self, monkeypatch):
+        import jax
+
+        from spark_rapids_jni_tpu.utils import memory
+
+        def boom():
+            raise OSError("backend did not start")
+
+        monkeypatch.setattr(jax, "local_devices", boom)
+        with pytest.raises(OSError):
+            memory._resolve_backend_budget()
