@@ -200,13 +200,12 @@ def main():
         """Run one bench stage with an attributable metrics window:
         registry + retry stats reset at entry, stage report captured at
         exit (timed through the op metrics namespace). With srjt-trace
-        armed too (ISSUE 12), a per-stage trace summary — span count,
-        max tree depth, p99 span duration — is captured from the same
-        reset registry window, so a BENCH latency regression can be
-        correlated with the span that grew. The trace summary rides
-        the TRACING gate alone (its counters are registry-direct), so
-        SRJT_TRACE_ENABLED=1 without SRJT_METRICS_ENABLED still emits
-        it."""
+        armed too (ISSUE 12), a per-stage trace summary — span, trace
+        and flushed-trace counts — is captured from the same reset
+        registry window (which span grew is read from the span log).
+        The trace summary rides the TRACING gate alone (its counters
+        are registry-direct), so SRJT_TRACE_ENABLED=1 without
+        SRJT_METRICS_ENABLED still emits it."""
         emit_trace = tracing.is_enabled()
         if not emit_metrics and not emit_trace:
             return fn()

@@ -343,10 +343,9 @@ def run_bench(args) -> int:
     if metrics.is_enabled():
         _emit({"metrics": metrics.stage_report("serve_bench")})
     if tracing.is_enabled():
-        # per-stage trace summary (ISSUE 12): span volume, max tree
-        # depth, and p99 span duration next to the metrics line, so a
-        # p99 latency regression in the BENCH row can be correlated
-        # with the span that grew
+        # per-stage trace summary (ISSUE 12): span, trace and
+        # flushed-trace counts next to the metrics line (which span
+        # grew is read from the span log)
         from spark_rapids_jni_tpu.utils import trace_sink
 
         _emit({"trace": {"stage": "serve_bench",

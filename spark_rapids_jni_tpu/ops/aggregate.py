@@ -33,6 +33,7 @@ import numpy as np
 from ..columnar import Column, Table
 from ..columnar import dtype as dt
 from ..columnar.dtype import TypeId
+from ..utils import tracing
 from ..utils.dispatch import op_boundary
 from . import bitutils
 from .copying import gather
@@ -334,21 +335,29 @@ def groupby_aggregate(
     ``{col}_{how}``. Row order is key-sorted (callers needing original
     first-appearance order can re-sort; SQL imposes none).
     """
+    # phase spans (srjt-trace): each times what the HOST did in the
+    # phase — dispatching the phase's programs, and in
+    # ``groupby.segments`` the one sync — not what the device did
     n = keys.num_rows
-    order = sorted_order(keys)
-    seg, num = _segment_ids(keys, order)
+    with tracing.span("groupby.sort", rows=n, keys=len(keys.columns)):
+        order = sorted_order(keys)
+    with tracing.span("groupby.segments") as sp:
+        seg, num = _segment_ids(keys, order)
+        sp.annotate(groups=num)
 
-    first_of_group = jnp.searchsorted(seg, jnp.arange(num, dtype=jnp.int32), side="left")
-    out_keys = gather(keys, order[first_of_group] if n else jnp.zeros((0,), jnp.int32))
+    with tracing.span("groupby.keys"):
+        first_of_group = jnp.searchsorted(seg, jnp.arange(num, dtype=jnp.int32), side="left")
+        out_keys = gather(keys, order[first_of_group] if n else jnp.zeros((0,), jnp.int32))
 
     out_cols: List[Column] = list(out_keys.columns)
     out_names: List[str] = list(out_keys.names)
     for col_name, how in aggs:
         col = values.column(col_name)
-        if how == "nunique":
-            out_cols.append(_nunique_column(keys, col, num))
-        else:
-            out_cols.append(_agg_column(col, order, seg, num, how))
+        with tracing.span(f"groupby.agg.{how}", col=col_name, dtype=col.dtype.id.name):
+            if how == "nunique":
+                out_cols.append(_nunique_column(keys, col, num))
+            else:
+                out_cols.append(_agg_column(col, order, seg, num, how))
         out_names.append(f"{col_name}_{how}")
     return Table(out_cols, out_names)
 

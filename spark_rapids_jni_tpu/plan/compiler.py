@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from ..columnar import Column, Table
 from ..columnar import dtype as dt
 from ..columnar.dtype import DType, TypeId
-from ..utils import knobs, metrics
+from ..utils import knobs, metrics, tracing
 from .exprs import PExpr, PlanError, conjoin, is_col, is_null_lit
 from .nodes import (
     Aggregate,
@@ -195,11 +195,16 @@ class _Exec:
         key = id(self)
         if key in ctx.cache:
             return ctx.cache[key]
-        if ctx.subcache is not None and self.cache_key is not None:
-            out = ctx.subcache.lookup_or_compute(
-                self.cache_key, lambda: self._run(ctx))
-        else:
-            out = self._run(ctx)
+        # one span a stage (its inputs' stages nest inside it): the
+        # host time of a stage that is in no operator is the span's
+        # self time
+        with tracing.span(f"plan.{self.kind}") as sp:
+            if ctx.subcache is not None and self.cache_key is not None:
+                out = ctx.subcache.lookup_or_compute(
+                    self.cache_key, lambda: self._run(ctx))
+            else:
+                out = self._run(ctx)
+            sp.annotate(rows_out=out.num_rows)
         ctx.actuals[key] = (out.num_rows, _table_nbytes(out))
         ctx.cache[key] = out
         return out

@@ -66,7 +66,8 @@ VERDICT r3 item 2):
                       out: serialized table (overflow BOOL8, product)
   8 DECIMAL128_DIV    in:  i32 quotient_scale, serialized table (a, b)
                       out: as op 7
-  10 STATS            -> utf-8 JSON: {"backend", "snapshot"} — the
+  10 STATS            -> utf-8 JSON: {"backend", "snapshot", "memgov",
+                         "device", "memory"} — the
                          worker's metrics-registry snapshot
                          (utils/metrics.py): per-op request counts,
                          error counts, op timings. The observability
@@ -124,6 +125,7 @@ Transitions are registry-direct metrics, visible in
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import socket
 import struct
@@ -201,6 +203,10 @@ STATUS_OK = 0
 STATUS_ERROR = 1
 STATUS_CAST_ERROR = 2
 
+# what an untraced request's handling runs under (the worker's
+# ``remote_scope`` otherwise): one shared, reusable null context
+_NO_SCOPE = contextlib.nullcontext()
+
 
 def _recv_exact(conn: socket.socket, n: int, fds: list = None) -> bytes:
     """Read exactly n bytes. With ``fds`` given, capture any SCM_RIGHTS
@@ -239,6 +245,20 @@ _REQ_FMT = threading.local()
 
 
 def _read_table(payload: bytes, pos: int = 0):
+    """``_decode_table`` under its span: the whole of the table's way
+    from wire bytes to device columns (per-column host views and
+    host-to-device puts; the span times what the host did)."""
+    from .utils import tracing
+
+    with tracing.span(
+        "sidecar.worker.decode_table", bytes=len(payload) - pos
+    ) as sp:
+        table = _decode_table(payload, pos)
+        sp.annotate(cols=len(table.columns))
+    return table
+
+
+def _decode_table(payload: bytes, pos: int = 0):
     """Deserialize a table from ``payload[pos:]``. Sniffs the versioned
     columnar frame magic (columnar/frames.py) first — framed payloads
     decode through the shared codec (per-column CRC verified); anything
@@ -336,57 +356,91 @@ def _write_table(table, framed: bool = None) -> bytes:
     import numpy as np
 
     from .columnar.dtype import TypeId
+    from .utils import tracing
 
     if framed is None:
         framed = getattr(_REQ_FMT, "framed", False)
     if framed:
         from .columnar import frames
 
-        return frames.encode_table(table)
-    out = [struct.pack("<I", len(table.columns))]
-    for col in table.columns:
-        d = col.dtype
-        n = len(col)
-        out.append(struct.pack("<ii", int(d.id.value), int(d.scale)))
-        out.append(struct.pack("<Q", n))
-        if col.validity is not None:
-            out.append(b"\x01")
-            out.append(np.asarray(col.validity, np.uint8).tobytes())
-        else:
-            out.append(b"\x00")
-        if d.id in (TypeId.STRING, TypeId.LIST):
-            offs = np.asarray(col.offsets, np.int32)
-            chars = (
-                np.asarray(col.chars, np.uint8)
-                if d.id == TypeId.STRING
-                else np.asarray(col.child.data).view(np.uint8)
+        # the shared codec brings each column to the host as it frames
+        # it: its device-to-host copies are inside this span
+        with tracing.span("sidecar.worker.encode_reply", framed=True) as sp:
+            resp = frames.encode_table(table)
+            sp.annotate(bytes=len(resp))
+        return resp
+    # two passes, so that neither span sits in the per-column loop:
+    # every device array to the host first (the wait for the kernel
+    # that produced it included), then the host copies into wire bytes
+    with tracing.span("sidecar.worker.d2h") as sp:
+        host = []
+        for col in table.columns:
+            d = col.dtype
+            validity = (
+                None if col.validity is None else np.asarray(col.validity, np.uint8)
             )
-            out.append(offs.tobytes())
-            out.append(struct.pack("<Q", chars.size))
-            out.append(chars.tobytes())
-        else:
-            raw = np.asarray(col.data)
+            if d.id in (TypeId.STRING, TypeId.LIST):
+                offs = np.asarray(col.offsets, np.int32)
+                chars = (
+                    np.asarray(col.chars, np.uint8)
+                    if d.id == TypeId.STRING
+                    else np.asarray(col.child.data).view(np.uint8)
+                )
+                host.append((d, len(col), validity, offs, chars))
+            else:
+                host.append((d, len(col), validity, None, np.asarray(col.data)))
+        sp.annotate(bytes=sum(
+            a.nbytes for h in host for a in h[2:] if a is not None
+        ))
+    with tracing.span("sidecar.worker.encode_reply") as sp:
+        out = [struct.pack("<I", len(host))]
+        for d, n, validity, offs, raw in host:
+            out.append(struct.pack("<ii", int(d.id.value), int(d.scale)))
+            out.append(struct.pack("<Q", n))
+            if validity is not None:
+                out.append(b"\x01")
+                out.append(validity.tobytes())
+            else:
+                out.append(b"\x00")
+            if offs is not None:  # STRING / LIST: offsets, then the byte child
+                out.append(offs.tobytes())
             out.append(struct.pack("<Q", raw.nbytes))
             out.append(raw.tobytes())
-    return b"".join(out)
+        resp = b"".join(out)
+        sp.annotate(bytes=len(resp))
+    return resp
 
 
 def _op_convert_to_rows(payload: bytes) -> bytes:
     import numpy as np
 
     from .ops.row_conversion import convert_to_rows
+    from .utils import tracing
 
     table = _read_table(payload)
     batches = convert_to_rows(table)
-    out = [struct.pack("<I", len(batches))]
-    for col in batches:
-        offs = np.asarray(col.offsets, np.int32)
-        blob = np.asarray(col.child.data).view(np.uint8)
-        out.append(struct.pack("<Q", len(col)))
-        out.append(offs.tobytes())
-        out.append(struct.pack("<Q", blob.size))
-        out.append(blob.tobytes())
-    return b"".join(out)
+    # device to host (the wait for the transcode kernel included), then
+    # the host copies into wire bytes: one span each, per request
+    with tracing.span("sidecar.worker.d2h") as sp:
+        host = [
+            (
+                len(col),
+                np.asarray(col.offsets, np.int32),
+                np.asarray(col.child.data).view(np.uint8),
+            )
+            for col in batches
+        ]
+        sp.annotate(bytes=sum(o.nbytes + b.nbytes for _, o, b in host))
+    with tracing.span("sidecar.worker.encode_reply") as sp:
+        out = [struct.pack("<I", len(host))]
+        for n, offs, blob in host:
+            out.append(struct.pack("<Q", n))
+            out.append(offs.tobytes())
+            out.append(struct.pack("<Q", blob.size))
+            out.append(blob.tobytes())
+        resp = b"".join(out)
+        sp.annotate(bytes=len(resp))
+    return resp
 
 
 def _op_convert_from_rows(payload: bytes) -> bytes:
@@ -466,23 +520,56 @@ def _op_decimal128(payload: bytes, div: bool) -> bytes:
     return _write_table(res)
 
 
+def _device_section() -> dict:
+    """What JAX says of this process's device(s): the worker owns the
+    chip, so only it can answer. ``memory`` is keyed by device id and
+    holds ``{}`` where the backend reports no memory statistics (the
+    CPU)."""
+    import jax
+
+    devs = jax.devices()
+    memory = {}
+    for d in devs:
+        ms = d.memory_stats() or {}
+        memory[str(d.id)] = {
+            k: int(ms[k])
+            for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+            if k in ms
+        }
+    return {
+        "device": {
+            "platform": devs[0].platform,
+            "kind": devs[0].device_kind,
+            "count": len(devs),
+        },
+        "memory": memory,
+    }
+
+
 def _op_stats(backend: str) -> bytes:
     """STATS verb: the worker's metrics-registry snapshot as JSON plus
     the memory governor's section (admission + catalog state — arena
     registrations surface here AND as ``memgov.arena*`` gauges in the
-    snapshot). The worker counts per-op requests/errors registry-direct
-    (always on, independent of SRJT_METRICS_ENABLED — the verb must
-    answer even when hot-path instrumentation is disarmed)."""
+    snapshot), its ``device`` (platform, kind, count) and per-device
+    ``memory``. The worker counts per-op requests/errors
+    registry-direct (always on, independent of SRJT_METRICS_ENABLED —
+    the verb must answer even when hot-path instrumentation is
+    disarmed); the compile counters (``xla.*``,
+    utils/compile_cache.py) ride the same snapshot. The verb is also a
+    flush point of the span log (utils/trace_sink.py): whoever polls
+    the worker can read its spans afterwards."""
     import json
 
     from . import memgov
-    from .utils import metrics
+    from .utils import metrics, trace_sink
 
+    trace_sink.flush()
     return json.dumps(
         {
             "backend": backend,
             "snapshot": metrics.snapshot(),
             "memgov": memgov.stats_section(),
+            **_device_section(),
         }
     ).encode()
 
@@ -549,10 +636,11 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
         trailer = b""
         if with_crc and integrity.is_enabled():
             status |= CRC_FLAG
-            trailer = integrity.pack_crc(
-                integrity.checksum(body if crc_body is None else crc_body)
-            )
+            covered = body if crc_body is None else crc_body
+            with tracing.span("integrity.crc", bytes=len(covered), where="reply"):
+                trailer = integrity.pack_crc(integrity.checksum(covered))
         ok = (status & ~_FLAG_MASK) == STATUS_OK
+        via, start = "stream", 0  # where the body goes: region / arena / stream
         if ok and region is not None and 0 < len(body) <= region[1]:
             # re-validate the in-slab header IMMEDIATELY before writing:
             # a slow-but-alive worker whose client already timed out and
@@ -569,20 +657,20 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
             off = region[0]
             magic, hgen, hrid, _cap, _plen = REGION_HDR.unpack_from(arena, off)
             if magic == REGION_MAGIC and hrid == region[2] and hgen == region[3]:
-                start = off + REGION_HDR_LEN
+                via, start = "region", off + REGION_HDR_LEN
+        elif (
+            ok and arena is not None and arena_mode == ARENA_MODE_LEGACY
+            and 0 < len(body) <= len(arena)
+        ):
+            via = "arena"
+        with tracing.span("sidecar.worker.reply_write", bytes=len(body), via=via):
+            if via == "stream":
+                conn.sendall(struct.pack("<IQ", status, len(body)) + trailer + body)
+            else:
                 arena[start : start + len(body)] = body
                 conn.sendall(
                     struct.pack("<IQ", status | ARENA_FLAG, len(body)) + trailer
                 )
-                return
-        if (
-            ok and arena is not None and arena_mode == ARENA_MODE_LEGACY
-            and 0 < len(body) <= len(arena)
-        ):
-            arena[: len(body)] = body
-            conn.sendall(struct.pack("<IQ", status | ARENA_FLAG, len(body)) + trailer)
-        else:
-            conn.sendall(struct.pack("<IQ", status, len(body)) + trailer + body)
 
     try:
         while True:
@@ -612,172 +700,184 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
                 if wire_op & TRACE_FLAG
                 else None
             )
-            region = None  # (offset, capacity) of a slab-mode region request
-            if in_arena and arena_mode == ARENA_MODE_SLAB:
-                # slab mode: the stream payload is a region DESCRIPTOR;
-                # the real payload sits behind the region header the
-                # client wrote into the shared slab. Every mismatch —
-                # stale generation, foreign request id, bad geometry —
-                # answers retryably so the client rewrites the region
-                # (or replays SET_ARENA) and re-sends.
-                desc = _recv_exact(conn, plen, fds) if plen else b""
-                err = None
-                if len(desc) != REGION_DESC.size:
-                    err = f"bad region descriptor length {len(desc)}"
-                elif arena is None:
-                    err = "no uploaded arena (re-send SET_ARENA)"
-                else:
-                    off, rid, gen = REGION_DESC.unpack(desc)
-                    if off + REGION_HDR_LEN > len(arena):
-                        err = f"region offset {off} out of bounds"
+            # srjt-trace: the caller's context covers the WHOLE handling
+            # of the request — payload read, CRC passes, the op, the
+            # reply — so every phase span below parents to the client's
+            # ``sidecar.request``; the span log is written when the
+            # scope exits (a disarmed worker's ``remote_scope`` is a
+            # pass). Untraced requests pay one shared null context.
+            with _NO_SCOPE if tctx is None else tracing.remote_scope(*tctx):
+                region = None  # (offset, capacity) of a slab-mode region request
+                if in_arena and arena_mode == ARENA_MODE_SLAB:
+                    # slab mode: the stream payload is a region DESCRIPTOR;
+                    # the real payload sits behind the region header the
+                    # client wrote into the shared slab. Every mismatch —
+                    # stale generation, foreign request id, bad geometry —
+                    # answers retryably so the client rewrites the region
+                    # (or replays SET_ARENA) and re-sends.
+                    desc = _recv_exact(conn, plen, fds) if plen else b""
+                    err = None
+                    if len(desc) != REGION_DESC.size:
+                        err = f"bad region descriptor length {len(desc)}"
+                    elif arena is None:
+                        err = "no uploaded arena (re-send SET_ARENA)"
                     else:
-                        magic, hgen, hrid, cap, pl = REGION_HDR.unpack_from(arena, off)
-                        if magic != REGION_MAGIC or hrid != rid or hgen != gen:
-                            err = (
-                                f"region header desync at {off} "
-                                f"(rid {hrid} != {rid} or gen {hgen} != {gen})"
-                            )
-                        elif pl > cap or off + REGION_HDR_LEN + cap > len(arena):
-                            err = f"region geometry invalid (len {pl} cap {cap})"
+                        off, rid, gen = REGION_DESC.unpack(desc)
+                        if off + REGION_HDR_LEN > len(arena):
+                            err = f"region offset {off} out of bounds"
                         else:
-                            region = (off, cap, rid, gen)
-                            start = off + REGION_HDR_LEN
-                            payload = bytes(arena[start : start + pl])
-                if err is not None:
-                    reply(
-                        STATUS_ERROR,
-                        f"RetryableError: arena region: {err}".encode(),
-                        with_crc,
-                    )
-                    continue
-            elif in_arena:
-                if arena is None or plen > len(arena):
-                    # retryable by prefix: a redialed connection lost its
-                    # per-connection arena — the client replays SET_ARENA
-                    # and re-sends (sidecar_pool._ensure_arena)
-                    reply(
-                        STATUS_ERROR,
-                        b"RetryableError: arena request without an uploaded"
-                        b" arena (re-send SET_ARENA)",
-                        with_crc,
-                    )
-                    continue
-                payload = bytes(arena[:plen])
-            else:
-                payload = _recv_exact(conn, plen, fds) if plen else b""
-            _REQ_FMT.framed = False  # set by _read_table when it sniffs a frame
-            if req_crc is not None and integrity.is_enabled():
-                reg.counter("sidecar.integrity.frames_checked").inc()
-                try:
-                    integrity.verify(payload, req_crc, "sidecar.request")
-                except DataCorruption as e:
-                    # taxonomy prefix on the wire: the client re-raises
-                    # DataCorruption (retryable) and re-sends the frame
-                    reply(STATUS_ERROR, f"{type(e).__name__}: {e}".encode(), with_crc)
-                    continue
-            # chaos mode (VERDICT r4 item 7): SRJT_CHAOS_EXIT_ON_OP=<n>
-            # makes the worker DIE mid-op — after consuming the request,
-            # before any response — modeling the round-4 "kernel fault"
-            # worker crash. Clients must classify the dead transport,
-            # fall back to the host engine, and reconnect cleanly.
-            from .utils import knobs
+                            magic, hgen, hrid, cap, pl = REGION_HDR.unpack_from(arena, off)
+                            if magic != REGION_MAGIC or hrid != rid or hgen != gen:
+                                err = (
+                                    f"region header desync at {off} "
+                                    f"(rid {hrid} != {rid} or gen {hgen} != {gen})"
+                                )
+                            elif pl > cap or off + REGION_HDR_LEN + cap > len(arena):
+                                err = f"region geometry invalid (len {pl} cap {cap})"
+                            else:
+                                region = (off, cap, rid, gen)
+                                via, start, plen = "region", off + REGION_HDR_LEN, pl
+                    if err is not None:
+                        reply(
+                            STATUS_ERROR,
+                            f"RetryableError: arena region: {err}".encode(),
+                            with_crc,
+                        )
+                        continue
+                elif in_arena:
+                    if arena is None or plen > len(arena):
+                        # retryable by prefix: a redialed connection lost its
+                        # per-connection arena — the client replays SET_ARENA
+                        # and re-sends (sidecar_pool._ensure_arena)
+                        reply(
+                            STATUS_ERROR,
+                            b"RetryableError: arena request without an uploaded"
+                            b" arena (re-send SET_ARENA)",
+                            with_crc,
+                        )
+                        continue
+                    via, start = "arena", 0
+                else:
+                    via = "stream"
+                # header parsed -> the payload's bytes in hand
+                with tracing.span("sidecar.worker.payload_read", bytes=plen, via=via):
+                    if via != "stream":
+                        payload = bytes(arena[start : start + plen])
+                    else:
+                        payload = _recv_exact(conn, plen, fds) if plen else b""
+                _REQ_FMT.framed = False  # set by _read_table when it sniffs a frame
+                if req_crc is not None and integrity.is_enabled():
+                    reg.counter("sidecar.integrity.frames_checked").inc()
+                    try:
+                        with tracing.span(
+                            "integrity.crc", bytes=len(payload),
+                            where="verify_request",
+                        ):
+                            integrity.verify(payload, req_crc, "sidecar.request")
+                    except DataCorruption as e:
+                        # taxonomy prefix on the wire: the client re-raises
+                        # DataCorruption (retryable) and re-sends the frame
+                        reply(STATUS_ERROR, f"{type(e).__name__}: {e}".encode(), with_crc)
+                        continue
+                # chaos mode (VERDICT r4 item 7): SRJT_CHAOS_EXIT_ON_OP=<n>
+                # makes the worker DIE mid-op — after consuming the request,
+                # before any response — modeling the round-4 "kernel fault"
+                # worker crash. Clients must classify the dead transport,
+                # fall back to the host engine, and reconnect cleanly.
+                from .utils import knobs
 
-            chaos = knobs.get_int("SRJT_CHAOS_EXIT_ON_OP")
-            if chaos is not None and op == chaos:
-                os._exit(42)
-            try:
-                # per-request fault hook (ISSUE 5): `crash` rules keyed
-                # `sidecar.worker.<OP>` SIGKILL the worker here — after
-                # consuming the request, before any response — and
-                # error kinds surface as status-1 replies
-                if faultinj.is_enabled():
-                    faultinj.maybe_inject(f"sidecar.worker.{op_name(op)}")
-                if op == OP_SET_ARENA:
-                    (size,) = struct.unpack_from("<Q", payload, 0)
-                    # >= 16-byte payloads carry the arena MODE word
-                    # (bit 0 = slab of per-request regions); the native
-                    # client's 8-byte payload keeps the legacy protocol
-                    mode = (
-                        struct.unpack_from("<Q", payload, 8)[0]
-                        if len(payload) >= 16
-                        else ARENA_MODE_LEGACY
-                    )
-                    if not fds:
-                        raise ValueError("SET_ARENA without an fd")
-                    fd = fds.pop(0)
-                    for extra in fds:
-                        os.close(extra)
-                    fds.clear()
-                    if arena is not None:
-                        # replace = unregister-then-register: close the
-                        # old mapping AND retire its accounting entry
-                        # before the new map exists, so a failed re-map
-                        # can't leave stale host-tier bytes and a
-                        # successful one never double-counts
-                        # (regression: memgov.arena* gauges stay flat
-                        # across re-uploads)
-                        arena.close()
-                        arena = None
-                        memgov.catalog().unregister(arena_key)
-                    arena = mmap.mmap(fd, size)
-                    arena_mode = (
-                        ARENA_MODE_SLAB
-                        if (mode & ARENA_MODE_SLAB)
-                        else ARENA_MODE_LEGACY
-                    )
-                    os.close(fd)
-                    memgov.catalog().register_host_bytes(
-                        arena_key, size, pinned=True, kind="arena"
-                    )
-                    reply(STATUS_OK, b"", with_crc)
-                    continue
-                if op == OP_SHUTDOWN:
-                    conn.sendall(struct.pack("<IQ", 0, 0))
-                    shutdown()
-                    return
-                # per-op wall time is hot-path instrumentation: gated
-                # (SRJT_METRICS_ENABLED), unlike the always-on request
-                # COUNTERS above — disarmed, no clock is touched
-                timed = metrics.is_enabled()
-                t0 = time.perf_counter() if timed else 0.0
-                if tctx is not None and tracing.is_enabled():
+                chaos = knobs.get_int("SRJT_CHAOS_EXIT_ON_OP")
+                if chaos is not None and op == chaos:
+                    os._exit(42)
+                try:
+                    # per-request fault hook (ISSUE 5): `crash` rules keyed
+                    # `sidecar.worker.<OP>` SIGKILL the worker here — after
+                    # consuming the request, before any response — and
+                    # error kinds surface as status-1 replies
+                    if faultinj.is_enabled():
+                        faultinj.maybe_inject(f"sidecar.worker.{op_name(op)}")
+                    if op == OP_SET_ARENA:
+                        (size,) = struct.unpack_from("<Q", payload, 0)
+                        # >= 16-byte payloads carry the arena MODE word
+                        # (bit 0 = slab of per-request regions); the native
+                        # client's 8-byte payload keeps the legacy protocol
+                        mode = (
+                            struct.unpack_from("<Q", payload, 8)[0]
+                            if len(payload) >= 16
+                            else ARENA_MODE_LEGACY
+                        )
+                        if not fds:
+                            raise ValueError("SET_ARENA without an fd")
+                        fd = fds.pop(0)
+                        for extra in fds:
+                            os.close(extra)
+                        fds.clear()
+                        if arena is not None:
+                            # replace = unregister-then-register: close the
+                            # old mapping AND retire its accounting entry
+                            # before the new map exists, so a failed re-map
+                            # can't leave stale host-tier bytes and a
+                            # successful one never double-counts
+                            # (regression: memgov.arena* gauges stay flat
+                            # across re-uploads)
+                            arena.close()
+                            arena = None
+                            memgov.catalog().unregister(arena_key)
+                        arena = mmap.mmap(fd, size)
+                        arena_mode = (
+                            ARENA_MODE_SLAB
+                            if (mode & ARENA_MODE_SLAB)
+                            else ARENA_MODE_LEGACY
+                        )
+                        os.close(fd)
+                        memgov.catalog().register_host_bytes(
+                            arena_key, size, pinned=True, kind="arena"
+                        )
+                        reply(STATUS_OK, b"", with_crc)
+                        continue
+                    if op == OP_SHUTDOWN:
+                        conn.sendall(struct.pack("<IQ", 0, 0))
+                        shutdown()
+                        return
+                    # per-op wall time is hot-path instrumentation: gated
+                    # (SRJT_METRICS_ENABLED), unlike the always-on request
+                    # COUNTERS above — disarmed, no clock is touched
+                    timed = metrics.is_enabled()
+                    t0 = time.perf_counter() if timed else 0.0
                     # the worker's half of the cross-process trace: one
                     # span per dispatched op, parented (via the wire
-                    # context) to the client's request span, streamed
-                    # to THIS process's span log for tracemerge to join
-                    with tracing.remote_scope(*tctx):
-                        with tracing.span(
-                            "sidecar.worker_op", op=op_name(op),
-                            backend=backend,
-                        ):
-                            resp = _dispatch(op, payload, backend)
-                else:
-                    resp = _dispatch(op, payload, backend)
-                if timed:
-                    reg.histogram(f"sidecar.worker.op_us.{op_name(op)}").record(
-                        (time.perf_counter() - t0) * 1e6
-                    )
-                wire_resp = resp
-                if faultinj.is_enabled():
-                    # `corrupt` chaos: flips bytes BELOW the checksum
-                    wire_resp = faultinj.maybe_corrupt(
-                        f"sidecar.worker.{op_name(op)}", resp
-                    )
-                reply(STATUS_OK, wire_resp, with_crc, crc_body=resp, region=region)
-            except Exception as e:  # srjt-lint: allow-broad-except(worker request loop: every failure must become a status-1 reply carrying the taxonomy prefix — the client re-raises the right class across the wire; the worker keeps serving)
-                from .ops.cast_string import CastError
+                    # context installed above) to the client's request
+                    # span, in THIS process's span log for tracemerge
+                    # to join
+                    with tracing.span(
+                        "sidecar.worker_op", op=op_name(op), backend=backend,
+                    ):
+                        resp = _dispatch(op, payload, backend)
+                    if timed:
+                        reg.histogram(f"sidecar.worker.op_us.{op_name(op)}").record(
+                            (time.perf_counter() - t0) * 1e6
+                        )
+                    wire_resp = resp
+                    if faultinj.is_enabled():
+                        # `corrupt` chaos: flips bytes BELOW the checksum
+                        wire_resp = faultinj.maybe_corrupt(
+                            f"sidecar.worker.{op_name(op)}", resp
+                        )
+                    reply(STATUS_OK, wire_resp, with_crc, crc_body=resp, region=region)
+                except Exception as e:  # srjt-lint: allow-broad-except(worker request loop: every failure must become a status-1 reply carrying the taxonomy prefix — the client re-raises the right class across the wire; the worker keeps serving)
+                    from .ops.cast_string import CastError
 
-                reg.counter("sidecar.worker.errors").inc()
-                if isinstance(e, CastError):
-                    # semantic ANSI failure: ships row + null-flag +
-                    # value so the client re-raises instead of
-                    # re-running on the host
-                    sv = e.string_with_error
-                    val = sv.encode() if isinstance(sv, str) else (bytes(sv) if sv else b"")
-                    msg = struct.pack("<qB", int(e.row_with_error), 1 if sv is None else 0) + val
-                    reply(STATUS_CAST_ERROR, msg, with_crc)
-                else:
-                    reply(STATUS_ERROR, f"{type(e).__name__}: {e}".encode(), with_crc)
+                    reg.counter("sidecar.worker.errors").inc()
+                    if isinstance(e, CastError):
+                        # semantic ANSI failure: ships row + null-flag +
+                        # value so the client re-raises instead of
+                        # re-running on the host
+                        sv = e.string_with_error
+                        val = sv.encode() if isinstance(sv, str) else (bytes(sv) if sv else b"")
+                        msg = struct.pack("<qB", int(e.row_with_error), 1 if sv is None else 0) + val
+                        reply(STATUS_CAST_ERROR, msg, with_crc)
+                    else:
+                        reply(STATUS_ERROR, f"{type(e).__name__}: {e}".encode(), with_crc)
     finally:
         if arena is not None:
             arena.close()
@@ -939,40 +1039,15 @@ class SupervisedClient:
             buf.extend(chunk)
         return bytes(buf)
 
-    def _raw_request(self, op: int, payload: bytes, arena_len: int = None,
-                     region=None):
-        """One request/response exchange on the live socket, bounded by
-        one per-request deadline end to end — under an active deadline
-        scope that is ``min(deadline_s, remaining budget)``, so a hung
-        worker can never cost more than the query has left. Any
-        transport fault closes the connection (desync discipline) and
-        raises RetryableError; an exhausted BUDGET raises
-        DeadlineExceeded instead (the caller must see the query
-        deadline, never a raw socket timeout).
+    def _frame_request(self, op: int, payload: bytes, arena_len, region,
+                       use_crc: bool):
+        """The request frame's parts: ``(wire_op, plen, trailer, stream
+        payload)``. With ``region`` / ``arena_len`` the body is resident
+        in shared memory and only a descriptor (or nothing) crosses the
+        socket; the CRC trailer always covers the body's IN-HAND bytes."""
+        from .utils import integrity, tracing
+        from .utils.errors import RetryableError
 
-        With ``arena_len`` the request payload is RESIDENT at
-        ``arena_mm[0:arena_len]`` (the legacy single-buffer data
-        plane): only the header — and the CRC trailer, computed over
-        the ARENA bytes — crosses the socket, under
-        ``wire_op | ARENA_FLAG``. With ``region`` (an
-        ``sidecar_pool.ArenaRegion``, the slab data plane) the payload
-        is resident inside the leased region and only the 20-byte
-        region descriptor crosses the socket — N such requests ride N
-        workers concurrently, nothing shared but the allocator."""
-        from .utils import deadline as deadline_mod, integrity
-        from .utils.errors import DataCorruption, RetryableError
-
-        d = deadline_mod.current()
-        budget_s = self._op_budget_s(op)
-        if d is not None:
-            d.check(f"sidecar_op_{op}")
-            budget_s = min(budget_s, max(d.remaining(), 1e-3))
-        deadline = time.monotonic() + budget_s
-        # integrity (ISSUE 5): one boolean read when off — the frame is
-        # byte-identical to the legacy protocol, same single sendall.
-        # When on, the 4-byte CRC trailer rides the SAME sendall and the
-        # worker echoes the flag back with a trailer this side verifies.
-        use_crc = integrity.is_enabled()
         wire_op = (op | CRC_FLAG) if use_crc else op
         if region is not None:
             wire_op |= ARENA_FLAG
@@ -1008,33 +1083,82 @@ class SupervisedClient:
                 )
             wire_op |= ARENA_FLAG
             body, plen, payload = bytes(self.arena_mm[:arena_len]), arena_len, b""
-        trailer = (
-            integrity.pack_crc(integrity.checksum(body)) if use_crc else b""
-        )
+        tracing.annotate(bytes=len(body))  # the caller's ``sidecar.client.send``
+        trailer = b""
+        if use_crc:
+            with tracing.span("integrity.crc", bytes=len(body), where="request"):
+                trailer = integrity.pack_crc(integrity.checksum(body))
+        return wire_op, plen, trailer, payload
+
+    def _raw_request(self, op: int, payload: bytes, arena_len: int = None,
+                     region=None):
+        """One request/response exchange on the live socket, bounded by
+        one per-request deadline end to end — under an active deadline
+        scope that is ``min(deadline_s, remaining budget)``, so a hung
+        worker can never cost more than the query has left. Any
+        transport fault closes the connection (desync discipline) and
+        raises RetryableError; an exhausted BUDGET raises
+        DeadlineExceeded instead (the caller must see the query
+        deadline, never a raw socket timeout).
+
+        With ``arena_len`` the request payload is RESIDENT at
+        ``arena_mm[0:arena_len]`` (the legacy single-buffer data
+        plane): only the header — and the CRC trailer, computed over
+        the ARENA bytes — crosses the socket, under
+        ``wire_op | ARENA_FLAG``. With ``region`` (an
+        ``sidecar_pool.ArenaRegion``, the slab data plane) the payload
+        is resident inside the leased region and only the 20-byte
+        region descriptor crosses the socket — N such requests ride N
+        workers concurrently, nothing shared but the allocator."""
+        from .utils import deadline as deadline_mod, integrity
+        from .utils.errors import DataCorruption, RetryableError
+
+        d = deadline_mod.current()
+        budget_s = self._op_budget_s(op)
+        if d is not None:
+            d.check(f"sidecar_op_{op}")
+            budget_s = min(budget_s, max(d.remaining(), 1e-3))
+        deadline = time.monotonic() + budget_s
+        # integrity (ISSUE 5): one boolean read when off — the frame is
+        # byte-identical to the legacy protocol, same single sendall.
+        # When on, the 4-byte CRC trailer rides the SAME sendall and the
+        # worker echoes the flag back with a trailer this side verifies.
+        use_crc = integrity.is_enabled()
         # srjt-trace (ISSUE 12): the active sampled context rides the
         # SAME sendall under the TRACE flag bit (negotiated per request
         # exactly like CRC_FLAG — one boolean read when tracing is off,
         # frame byte-identical); the worker's spans then parent to this
-        # request's span across the process boundary
+        # request's span across the process boundary. Packed BEFORE the
+        # phase spans below open, so the remote parent is the enclosing
+        # ``sidecar.request`` and the worker's spans are their siblings.
         from .utils import tracing
 
-        tblob = tracing.wire_context()
-        if tblob is not None:
-            wire_op |= TRACE_FLAG
-        else:
-            tblob = b""
+        tblob = tracing.wire_context() or b""
         try:
-            self._sock.settimeout(budget_s)
-            self._sock.sendall(
-                struct.pack("<IQ", wire_op, plen) + trailer + tblob + payload
-            )
-            hdr = self._recv_deadline(12, deadline)
-            status, rlen = struct.unpack("<IQ", hdr)
-            resp_crc = (
-                integrity.unpack_crc(self._recv_deadline(4, deadline))
-                if status & CRC_FLAG
-                else None
-            )
+            # phase spans (null when no traced query is active): send =
+            # request bytes in hand -> sendall returned (its CRC pass
+            # nests inside), wait = -> reply header received (the
+            # worker's whole handling), reply_read = the reply's bytes
+            # into this process's hands
+            with tracing.span("sidecar.client.send"):
+                wire_op, plen, trailer, payload = self._frame_request(
+                    op, payload, arena_len, region, use_crc
+                )
+                if tblob:
+                    wire_op |= TRACE_FLAG
+                self._sock.settimeout(budget_s)
+                self._sock.sendall(
+                    struct.pack("<IQ", wire_op, plen) + trailer + tblob + payload
+                )
+            with tracing.span("sidecar.client.wait"):
+                hdr = self._recv_deadline(12, deadline)
+                status, rlen = struct.unpack("<IQ", hdr)
+                resp_crc = (
+                    integrity.unpack_crc(self._recv_deadline(4, deadline))
+                    if status & CRC_FLAG
+                    else None
+                )
+            via = "stream"
             if status & ARENA_FLAG:
                 # the worker answered through the shared arena: only the
                 # header (and CRC trailer) crossed the socket — a client
@@ -1044,15 +1168,20 @@ class SupervisedClient:
                         raise ConnectionError(
                             "region-flagged response exceeds the leased region"
                         )
-                    resp = region.read(rlen)
+                    via = "region"
                 elif self.arena_mm is None or rlen > len(self.arena_mm):
                     raise ConnectionError(
                         "arena-flagged response without a client-side arena"
                     )
                 else:
+                    via = "arena"
+            with tracing.span("sidecar.client.reply_read", bytes=rlen, via=via):
+                if via == "region":
+                    resp = region.read(rlen)
+                elif via == "arena":
                     resp = bytes(self.arena_mm[:rlen])
-            else:
-                resp = self._recv_deadline(rlen, deadline) if rlen else b""
+                else:
+                    resp = self._recv_deadline(rlen, deadline) if rlen else b""
         except socket.timeout as e:
             self.close()
             if d is not None and d.done():
@@ -1069,7 +1198,10 @@ class SupervisedClient:
 
             metrics.registry().counter("sidecar.integrity.frames_checked").inc()
             try:
-                integrity.verify(resp, resp_crc, "sidecar.response")
+                with tracing.span(
+                    "integrity.crc", bytes=len(resp), where="verify_reply"
+                ):
+                    integrity.verify(resp, resp_crc, "sidecar.response")
             except DataCorruption:
                 # the stream is still framed (full frame consumed) but a
                 # link that corrupts one frame gets the desync treatment:
@@ -1516,8 +1648,10 @@ def serve(sock_path: str) -> None:
         # worker's lock-order graph NOW or the CI gate never sees the
         # worker side of the package's locks
         from .analysis import lockdep as _lockdep
+        from .utils import trace_sink
 
         _lockdep.flush_report()
+        trace_sink.flush()  # same reason: the span log's exit flush
         os._exit(0)
 
     try:

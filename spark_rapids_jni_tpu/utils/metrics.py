@@ -130,16 +130,6 @@ class Gauge:
         with self._lock:
             self._value += n
 
-    def set_max(self, v) -> None:
-        """Monotonic high-water update: compare-and-set under the
-        gauge's own lock, so two concurrent observers can never let a
-        smaller value overwrite a larger one (the trace.max_depth
-        contract — an unlocked read-then-set is exactly the
-        check-then-act the race tier polices)."""
-        with self._lock:
-            if v > self._value:
-                self._value = v
-
     @property
     def value(self):
         return self._value  # srjt-race: allow-unguarded(last-write-wins scalar; a reference read is GIL-atomic and any concurrent set is a valid value)
@@ -259,9 +249,6 @@ class _NullMetric:
         pass
 
     def set(self, v) -> None:
-        pass
-
-    def set_max(self, v) -> None:
         pass
 
     def record(self, value) -> None:
@@ -765,10 +752,9 @@ def stage_report(stage: str) -> dict:
                 + _REGISTRY.value("shuffle.tcp.adaptive_timeout_clamps")
             ),
         },
-        # ISSUE 12 tracing counters: per-stage span volume — bench
-        # drivers pair this with the dedicated {"trace": ...} summary
-        # line (trace_sink.stage_summary) so a BENCH latency regression
-        # can be correlated with the span that grew
+        # ISSUE 12 tracing counters: per-stage span volume, the same
+        # three counters as the bench drivers' dedicated
+        # {"trace": ...} summary line (trace_sink.stage_summary)
         "trace": {
             "spans": _REGISTRY.value("trace.spans"),
             "traces": _REGISTRY.value("trace.traces"),

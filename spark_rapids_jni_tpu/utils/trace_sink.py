@@ -9,8 +9,17 @@ span log and keeps the bounded ring of recently completed query traces.
   line, one file per process (client, each sidecar worker, each
   exchange peer), which is exactly the join input
   ``python -m spark_rapids_jni_tpu.analysis.tracemerge`` turns into
-  per-trace trees and Chrome/Perfetto JSON. Writes are one ``write()``
-  per line (the utils/metrics event-log discipline).
+  per-trace trees and Chrome/Perfetto JSON. The log is written PER
+  REQUEST, not per span: a finished span's record is appended to an
+  in-memory list, and the list is serialised and written in one
+  ``write()`` at a flush point — a root trace finishing
+  (``QueryTrace.finish``), a ``remote_scope`` exiting (the worker's /
+  exchange peer's end of a request), ``close_log()`` /
+  ``set_log_path()``, the sidecar ``STATS`` verb, interpreter exit,
+  and the list passing ``_BUFFER_MAX`` records (so a process that
+  never finishes a root cannot grow without bound). A span that
+  finishes after its request's flush point (a hedge loser, a
+  straggling thread) is flushed on its own.
 - **Flight recorder**: every finished ROOT trace lands in a ring of
   the last ``SRJT_TRACE_RING`` traces; queries that were shed, failed,
   cancelled, expired, or slower than ``SRJT_SLOW_QUERY_SEC`` are
@@ -21,10 +30,11 @@ span log and keeps the bounded ring of recently completed query traces.
   ring as an annotated span tree.
 
 Stage summary counters (``trace.spans`` / ``trace.traces`` /
-``trace.flushed`` / ``trace.max_depth`` gauges + the ``trace.span_us``
-histogram) are registry-direct so bench drivers can emit a per-stage
-trace summary next to their ``{"metrics": ...}`` lines and
-``metrics.reset()`` scopes them per stage.
+``trace.flushed`` / ``trace.unsampled``) are registry-direct so bench
+drivers can emit a per-stage trace summary next to their
+``{"metrics": ...}`` lines and ``metrics.reset()`` scopes them per
+stage. A finished span costs one counter increment and one list
+append.
 
 Disabled posture: nothing here runs unless utils/tracing's gate armed a
 span in the first place — the module's own fast-outs are one attribute
@@ -33,6 +43,7 @@ read (no path configured == no I/O).
 
 from __future__ import annotations
 
+import atexit
 import json
 import os
 import threading
@@ -43,7 +54,7 @@ from . import knobs
 
 __all__ = [
     "emit_span",
-    "note_span",
+    "flush",
     "note_trace",
     "note_unsampled",
     "record_trace",
@@ -64,6 +75,11 @@ _log_lock = threading.Lock()
 _log_base: Optional[str] = knobs.get_str("SRJT_TRACE_LOG") or None
 _log_file = None
 _log_file_path: Optional[str] = None
+# finished records waiting for the next flush point (guarded by
+# _log_lock); past _BUFFER_MAX the appender flushes, so the list is
+# bounded without a knob
+_buffer: List[dict] = []
+_BUFFER_MAX = 4096
 
 
 def log_path() -> Optional[str]:
@@ -84,74 +100,93 @@ def resolved_log_path() -> Optional[str]:
 
 
 def set_log_path(base: Optional[str]) -> None:
-    """Install (or clear) the span-log base path. The per-process file
-    opens lazily on the first span."""
-    global _log_base, _log_file, _log_file_path
+    """Install (or clear) the span-log base path. What is buffered goes
+    to the OLD path first; the per-process file of the new one opens
+    lazily on the first flush."""
+    global _log_base
     with _log_lock:
-        if _log_file is not None:
-            try:
-                _log_file.close()
-            finally:
-                _log_file = None
-                _log_file_path = None
+        _flush_locked()
+        _close_locked()
         _log_base = base
 
 
 def close_log() -> None:
+    """Flush what is buffered and close the file (a reader may open it
+    now; the next flush re-opens it for append)."""
     set_log_path(_log_base)
 
 
-def _write_line(rec: dict) -> None:
-    """One JSON line to the per-process span log; a bad path degrades
-    the log, never the op being traced."""
+def _close_locked() -> None:
     global _log_file, _log_file_path
+    if _log_file is not None:
+        try:
+            _log_file.close()
+        except OSError:
+            pass
+        _log_file = None
+        _log_file_path = None
+
+
+def _flush_locked() -> None:
+    """Serialise the buffered records and append them in ONE write; a
+    bad path degrades the log, never the op being traced."""
+    global _log_file, _log_file_path
+    if not _buffer:
+        return
+    recs = _buffer[:]
+    del _buffer[:]  # srjt-race: guarded-by(_log_lock)
+    path = resolved_log_path()
+    if path is None:
+        return
+    if _log_file is None or _log_file_path != path:
+        _close_locked()
+        d = os.path.dirname(path)
+        try:
+            if d:
+                os.makedirs(d, exist_ok=True)
+            _log_file = open(path, "a")
+            _log_file_path = path
+        except OSError:
+            return
+    try:
+        _log_file.write(
+            "".join(json.dumps(r, default=str) + "\n" for r in recs)
+        )
+        _log_file.flush()
+    except (OSError, ValueError):
+        pass
+
+
+def flush() -> None:
+    """Write every buffered record now (the flush points are listed in
+    the module docstring)."""
+    with _log_lock:
+        _flush_locked()
+
+
+atexit.register(flush)
+
+
+def _buffer_record(rec: dict) -> None:
     if _log_base is None:
         return
-    line = json.dumps(rec, default=str) + "\n"
     with _log_lock:
-        path = resolved_log_path()
-        if path is None:
-            return
-        if _log_file is None or _log_file_path != path:
-            if _log_file is not None:
-                try:
-                    _log_file.close()
-                except OSError:
-                    pass
-                _log_file = None
-            d = os.path.dirname(path)
-            try:
-                if d:
-                    os.makedirs(d, exist_ok=True)
-                _log_file = open(path, "a")
-                _log_file_path = path
-            except OSError:
-                return
-        try:
-            _log_file.write(line)
-            _log_file.flush()
-        except (OSError, ValueError):
-            pass
+        _buffer.append(rec)
+        if len(_buffer) >= _BUFFER_MAX:
+            _flush_locked()
 
 
 def emit_span(rec: dict) -> None:
-    """Stream one finished span record to the per-process log."""
-    _write_line(rec)
+    """One finished span: counted (``trace.spans``; metrics.reset()
+    scopes it per bench stage) and buffered for the per-process log."""
+    _registry().counter("trace.spans").inc()
+    _buffer_record(rec)
 
 
 def _registry():
     from . import metrics
 
     return metrics.registry()
-
-
-def note_span(dur_us: float, depth: int) -> None:
-    """Stage-summary accounting for one finished span (registry-direct;
-    metrics.reset() scopes it per bench stage)."""
-    reg = _registry()
-    reg.counter("trace.spans").inc()
-    reg.histogram("trace.span_us").record(dur_us)
-    reg.gauge("trace.max_depth").set_max(depth)
 
 
 def note_trace() -> None:
@@ -173,8 +208,9 @@ class FlightRecorder:
     expired / error) always flushes; an ok trace flushes when it ran
     longer than ``SRJT_SLOW_QUERY_SEC`` (unset: never). Flushing
     appends the FULL trace record — span tree + metrics delta — to the
-    span log, so a storm's evidence is on disk even if the process
-    dies before anyone calls explain_last()."""
+    span log at once (behind the spans buffered before it), so a
+    storm's evidence is on disk even if the process dies before anyone
+    calls explain_last()."""
 
     def __init__(self, capacity: Optional[int] = None):
         if capacity is None:
@@ -186,21 +222,21 @@ class FlightRecorder:
 
     def record(self, rec: dict) -> None:
         slow_s = knobs.get_float("SRJT_SLOW_QUERY_SEC")
-        flush = rec.get("status") != "ok" or (
+        to_log = rec.get("status") != "ok" or (
             slow_s is not None and rec.get("duration_s", 0.0) > slow_s
         )
-        if flush:
+        if to_log:
             rec = dict(rec)
             rec["flushed"] = True
         with self._lock:
             self._ring.append(rec)
             self._recorded += 1
-            if flush:
+            if to_log:
                 self._flushed += 1
-        reg = _registry()
-        if flush:
-            reg.counter("trace.flushed").inc()
-            _write_line(rec)
+        if to_log:
+            _registry().counter("trace.flushed").inc()
+            _buffer_record(rec)
+            flush()
 
     def last(self, n: int = 1) -> List[dict]:
         with self._lock:
@@ -342,20 +378,14 @@ def explain_last() -> Optional[str]:
 
 def stage_summary() -> dict:
     """The per-stage trace summary bench drivers emit next to their
-    ``{"metrics": ...}`` lines: span count, trace count, max tree
-    depth, and the p99 span duration — enough to correlate a latency
-    regression with the span that grew."""
-    from . import metrics
-
+    ``{"metrics": ...}`` lines: span, trace and flushed-trace counts of
+    the stage's registry window. Which span grew is read from the span
+    log (``analysis.tracemerge``), not from here."""
     reg = _registry()
-    h = reg.peek("trace.span_us")
-    p99 = h.quantile(0.99) if isinstance(h, metrics.Histogram) else None
     return {
         "spans": reg.value("trace.spans"),
         "traces": reg.value("trace.traces"),
         "flushed": reg.value("trace.flushed"),
-        "max_depth": reg.value("trace.max_depth"),
-        "p99_span_us": None if p99 is None else round(p99, 1),
     }
 
 

@@ -33,6 +33,9 @@ subsystem:
   installs it with ``remote_scope`` so its spans parent to the
   caller's span — in its OWN per-process span log, joined later by
   ``python -m spark_rapids_jni_tpu.analysis.tracemerge``.
+- **Span log** (utils/trace_sink.py): finished spans are kept in
+  memory and written when their request ends — the root trace
+  finishing, a ``remote_scope`` exiting — never one write per span.
 - **Flight recorder** (utils/trace_sink.py): every finished root trace
   lands in a bounded ring; slow (``SRJT_SLOW_QUERY_SEC``), shed, and
   failed queries auto-flush to ``SRJT_TRACE_LOG`` with their full span
@@ -254,9 +257,10 @@ class _Anchor:
 class TraceContext:
     """One query's trace identity plus its per-process span buffer.
     The buffer is BOUNDED (``SRJT_TRACE_MAX_SPANS``; overflow counted,
-    the span LOG is never capped) and SEALED when the root finishes —
-    a straggling hedge loser that completes after the query settled
-    still reaches the log, it just misses the in-memory record."""
+    the span LOG is never capped) and SEALED when the root finishes
+    (a remote context: when its ``remote_scope`` exits) — a straggling
+    hedge loser that completes after the query settled still reaches
+    the log, it just misses the in-memory record."""
 
     __slots__ = ("trace_id", "sampled", "remote", "_lock", "_spans",
                  "_dropped", "_sealed", "_counters0", "_max_spans")
@@ -273,14 +277,17 @@ class TraceContext:
         self._counters0: Optional[dict] = None
         self._max_spans = knobs.get_int("SRJT_TRACE_MAX_SPANS")
 
-    def add(self, rec: dict) -> None:
+    def add(self, rec: dict) -> bool:
+        """Buffer one finished span; False once the context is sealed
+        (a straggler past the root finish: log-only)."""
         with self._lock:
             if self._sealed:
-                return  # straggler past the root finish: log-only
+                return False
             if len(self._spans) < self._max_spans:
                 self._spans.append(rec)
             else:
                 self._dropped += 1
+            return True
 
     def seal(self):
         """Freeze the buffer; returns (spans, dropped)."""
@@ -313,18 +320,22 @@ def _sink():
     return trace_sink
 
 
-def _record_and_emit(ctx: TraceContext, rec: dict, depth: int) -> None:
-    """The one record pipeline every finished span goes through:
-    in-memory buffer, span log, stage-summary counters."""
-    ctx.add(rec)
+def _record_and_emit(ctx: TraceContext, rec: dict) -> None:
+    """The one record pipeline every finished span goes through: the
+    trace's in-memory buffer, then the sink (span counter + the span
+    log's buffer, written when the request ends). A straggler — its
+    context already sealed, so its request's flush has passed — is
+    flushed on its own."""
+    live = ctx.add(rec)
     sink = _sink()
     sink.emit_span(rec)
-    sink.note_span(rec["dur_us"], depth)
+    if not live:
+        sink.flush()
 
 
 def _finish_span(sp: Span) -> None:
     dur_s = time.perf_counter() - sp._t0
-    _record_and_emit(sp.ctx, sp._record(dur_s), sp.depth)
+    _record_and_emit(sp.ctx, sp._record(dur_s))
 
 
 @contextlib.contextmanager
@@ -378,7 +389,7 @@ def closed_span(name: str, dur_s: float, t_wall: Optional[float] = None,
     ctx, parent = a
     sp = Span(ctx, name, parent.span_id, parent.depth + 1, annotations)
     sp.t_wall = time.time() - dur_s if t_wall is None else t_wall
-    _record_and_emit(ctx, sp._record(max(float(dur_s), 0.0)), sp.depth)
+    _record_and_emit(ctx, sp._record(max(float(dur_s), 0.0)))
 
 
 def annotate(**kw) -> None:
@@ -440,8 +451,7 @@ class QueryTrace:
             return
         dur_s = time.perf_counter() - self.root._t0
         self.root.status = status
-        _record_and_emit(self.ctx, self.root._record(dur_s),
-                         self.root.depth)
+        _record_and_emit(self.ctx, self.root._record(dur_s))
         sink = _sink()
         spans, dropped = self.ctx.seal()
         delta = None
@@ -466,6 +476,7 @@ class QueryTrace:
             "dropped_spans": dropped,
             "metrics_delta": delta or {},
         })
+        sink.flush()  # the request ended: its spans reach the log now
 
 
 def _sampled() -> bool:
@@ -561,8 +572,9 @@ def decode_wire_context(blob: bytes):
 def remote_scope(trace_id: int, parent_span_id: int, sampled: bool = True):
     """Install a REMOTE context (decoded off the wire) for one
     request's dynamic extent: spans created inside parent to the
-    caller's span and stream to THIS process's span log — the root
-    lives in the submitting process; tracemerge joins the logs by
+    caller's span and go to THIS process's span log, written when the
+    scope exits (this process's end of the request) — the root lives
+    in the submitting process; tracemerge joins the logs by
     trace_id."""
     if not _enabled or not sampled:
         yield
@@ -573,3 +585,5 @@ def remote_scope(trace_id: int, parent_span_id: int, sampled: bool = True):
         yield
     finally:
         _current.reset(tok)
+        ctx.seal()
+        _sink().flush()

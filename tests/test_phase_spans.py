@@ -1,0 +1,621 @@
+"""Phase spans down the two served paths (ISSUE 26).
+
+The group-by and the plan stages name their phases, the sidecar request
+names its passes over the payload (worker and client, one spawned worker
+so the spans cross a real process boundary), compiles are counted and
+show as ``xla.compile`` spans, the span log is written per request and
+not per span, ``STATS`` answers for the worker's own device, and the ten
+per-layer readers under ``bench/readers/`` reduce a synthetic span list
+to the numbers worked out by hand here.
+"""
+
+import glob
+import importlib.util
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_jni_tpu import plan as P
+from spark_rapids_jni_tpu import serve, sidecar, sidecar_pool
+from spark_rapids_jni_tpu.columnar import Column, Table
+from spark_rapids_jni_tpu.columnar import dtype as dt
+from spark_rapids_jni_tpu.ops.aggregate import groupby_aggregate
+from spark_rapids_jni_tpu.utils import metrics, trace_sink, tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_DIR = os.path.join(REPO, "bench")
+
+
+@pytest.fixture(autouse=True)
+def _own_span_log(tmp_path):
+    """Tracing off and a span log of the test's own, as tests/test_tracing.py
+    has it; whatever the process had is put back."""
+    prev_base, prev_enabled = trace_sink.log_path(), tracing.is_enabled()
+    tracing.set_enabled(False)
+    trace_sink.reset_for_tests()
+    trace_sink.set_log_path(str(tmp_path / "spans.jsonl"))
+    yield
+    trace_sink.reset_for_tests()
+    trace_sink.set_log_path(prev_base)
+    tracing.set_enabled(prev_enabled)
+
+
+def _read_logs(pattern):
+    out = []
+    for path in sorted(glob.glob(pattern)):
+        with open(path) as f:
+            out += [json.loads(line) for line in f if line.strip()]
+    return out
+
+
+def _log_spans():
+    path = trace_sink.resolved_log_path()
+    return [r for r in _read_logs(path) if r.get("kind") == "span"]
+
+
+def _counter(name):
+    return metrics.registry().value(name)
+
+
+# ---------------------------------------------------------------------------
+# execution: plan.<kind> and groupby.* on a q1-shaped plan
+# ---------------------------------------------------------------------------
+
+N_ROWS = 4000
+
+
+def _lineitem(rng):
+    def f64(a):
+        return Column.from_numpy(np.ascontiguousarray(a, np.float64), dt.FLOAT64)
+
+    return Table(
+        [
+            Column.from_numpy(rng.integers(0, 3, N_ROWS).astype(np.int8), dt.INT8),
+            Column.from_numpy(rng.integers(0, 2, N_ROWS).astype(np.int8), dt.INT8),
+            f64(rng.integers(1, 51, N_ROWS)),
+            f64(rng.uniform(900.0, 105000.0, N_ROWS).round(2)),
+            f64(rng.integers(0, 11, N_ROWS) / 100.0),
+            Column.from_numpy(rng.integers(0, 2600, N_ROWS).astype(np.int32), dt.INT32),
+        ],
+        ["flag", "status", "qty", "price", "disc", "shipdate"],
+    )
+
+
+def _q1_shaped_plan():
+    disc_price = P.pcol("price") * (P.plit(1.0) - P.pcol("disc"))
+    x = P.Filter(P.Scan("lineitem"), P.pcol("shipdate") <= P.plit(np.int32(2436)))
+    x = P.Project(x, (
+        ("flag", P.pcol("flag")),
+        ("status", P.pcol("status")),
+        ("qty", P.pcol("qty")),
+        ("disc_price", disc_price),
+    ))
+    agg = P.Aggregate(x, keys=("flag", "status"), aggs=(
+        P.AggSpec("qty", "sum", "sum_qty"),
+        P.AggSpec("disc_price", "sum", "sum_disc_price"),
+        P.AggSpec("qty", "mean", "avg_qty"),
+        P.AggSpec(None, "count_all", "count_order"),
+    ))
+    return P.Sort(agg, (("flag", True), ("status", True)))
+
+
+@pytest.fixture(scope="module")
+def q1_spans():
+    """The span tree of one traced q1-shaped query through the scheduler
+    (the second run: the first has compiled everything)."""
+    table = _lineitem(np.random.default_rng(26))
+    cp = P.compile_ir(_q1_shaped_plan(), {"lineitem": table}, name="q1_shaped")
+    sched = serve.Scheduler(max_concurrent=1, name="phase-spans")
+    prev = tracing.is_enabled()
+    try:
+        sched.submit(cp).result()
+        trace_sink.reset_for_tests()
+        tracing.set_enabled(True)
+        out = sched.submit(cp).result()
+        jax.block_until_ready([c.data for c in out.columns])
+    finally:
+        tracing.set_enabled(prev)
+        sched.shutdown()
+    rec = trace_sink.recorder().last(1)[0]
+    assert rec["name"] == "serve.query" and rec["dropped_spans"] == 0
+    return rec["spans"]
+
+
+def _by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def _one(spans, name):
+    hits = _by_name(spans)[name]
+    assert len(hits) == 1, (name, len(hits))
+    return hits[0]
+
+
+PLAN_PARENTS = [
+    ("plan.sort", "serve.run"),
+    ("plan.aggregate", "plan.sort"),
+    ("plan.project", "plan.aggregate"),
+    ("plan.filter", "plan.project"),
+    ("plan.scan", "plan.filter"),
+    ("op.groupby_aggregate", "plan.aggregate"),
+]
+
+
+@pytest.mark.parametrize("child,parent", PLAN_PARENTS)
+def test_plan_stage_spans_nest_as_the_plan_does(q1_spans, child, parent):
+    assert _one(q1_spans, child)["parent"] == _one(q1_spans, parent)["span"]
+
+
+def test_plan_stage_spans_say_their_rows_out(q1_spans):
+    assert _one(q1_spans, "plan.scan")["annotations"] == {"rows_out": N_ROWS}
+    kept = _one(q1_spans, "plan.filter")["annotations"]["rows_out"]
+    assert 0 < kept < N_ROWS
+    assert _one(q1_spans, "plan.project")["annotations"] == {"rows_out": kept}
+    assert _one(q1_spans, "plan.aggregate")["annotations"] == {"rows_out": 6}
+
+
+def test_groupby_phase_spans_are_children_of_the_operator(q1_spans):
+    op = _one(q1_spans, "op.groupby_aggregate")["span"]
+    names = _by_name(q1_spans)
+    for phase in ("groupby.sort", "groupby.segments", "groupby.keys"):
+        assert _one(q1_spans, phase)["parent"] == op
+    assert _one(q1_spans, "groupby.sort")["annotations"] == {"rows": _one(
+        q1_spans, "plan.project")["annotations"]["rows_out"], "keys": 2}
+    assert _one(q1_spans, "groupby.segments")["annotations"] == {"groups": 6}
+    aggs = sorted(
+        (s["name"], s["annotations"]["col"], s["annotations"]["dtype"])
+        for n, ss in names.items() if n.startswith("groupby.agg.") for s in ss
+    )
+    assert aggs == [
+        ("groupby.agg.count_all", "flag", "INT8"),
+        ("groupby.agg.mean", "qty", "FLOAT64"),
+        ("groupby.agg.sum", "disc_price", "FLOAT64"),
+        ("groupby.agg.sum", "qty", "FLOAT64"),
+    ]
+    assert all(s["parent"] == op for n, ss in names.items()
+               if n.startswith("groupby.agg.") for s in ss)
+
+
+@pytest.mark.parametrize("whole,parts", [
+    ("op.groupby_aggregate", "groupby."),
+    ("serve.run", "plan."),
+])
+def test_phase_spans_cover_nine_tenths_of_their_parent(q1_spans, whole, parts):
+    parent = _one(q1_spans, whole)
+    covered = sum(s["dur_us"] for s in q1_spans
+                  if s["parent"] == parent["span"] and s["name"].startswith(parts))
+    assert covered >= 0.9 * parent["dur_us"], (covered, parent["dur_us"])
+    assert covered <= parent["dur_us"]
+
+
+# ---------------------------------------------------------------------------
+# compiles: the counters and the xla.compile span
+# ---------------------------------------------------------------------------
+
+
+def test_fresh_jit_is_one_compile_and_one_span_a_second_call_neither():
+    @jax.jit
+    def fresh(x):
+        return (x * 3 + 1).sum()
+
+    x = jnp.arange(37, dtype=jnp.int32)  # made before counting: arange compiles too
+    jax.block_until_ready(x)
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            with tracing.span("caller") as caller:
+                before = _counter("xla.backend_compiles")
+                seconds = _counter("xla.backend_compile_s")
+                fresh(x).block_until_ready()
+                assert _counter("xla.backend_compiles") == before + 1
+                assert _counter("xla.backend_compile_s") > seconds
+                fresh(x).block_until_ready()
+                assert _counter("xla.backend_compiles") == before + 1
+        qt.finish("ok")
+    compiles = [s for s in _log_spans() if s["name"] == "xla.compile"]
+    assert len(compiles) == 1
+    assert compiles[0]["parent"] == f"{caller.span_id:016x}"
+    assert "fresh" in compiles[0]["annotations"]["fun"]
+    assert compiles[0]["dur_us"] > 0
+
+
+def test_compile_counters_are_in_the_snapshot_with_tracing_off():
+    assert not tracing.is_enabled()
+    before = _counter("xla.backend_compiles")
+    jax.jit(lambda x: x - 41)(jnp.ones((3,), jnp.float32)).block_until_ready()
+    assert _counter("xla.backend_compiles") > before
+    counters = metrics.snapshot()["counters"]
+    assert {"xla.backend_compiles", "xla.backend_compile_s"} <= set(counters)
+    assert _log_spans() == []
+
+
+# ---------------------------------------------------------------------------
+# tracing off: no record, the shared null span at every new site
+# ---------------------------------------------------------------------------
+
+
+def test_tracing_off_makes_no_record_and_hands_out_the_null_span():
+    assert not tracing.is_enabled()
+    spans_before = _counter("trace.spans")
+    for name in ("plan.aggregate", "groupby.sort", "groupby.agg.sum", "integrity.crc",
+                 "sidecar.worker.decode_table", "sidecar.worker.d2h",
+                 "sidecar.client.send", "sidecar.client.wait"):
+        with tracing.span(name, bytes=1) as sp:
+            assert sp is tracing._NULL_SPAN
+    keys = Table([Column.from_numpy(np.array([1, 1, 2], np.int32), dt.INT32)], ["k"])
+    vals = Table([Column.from_numpy(np.array([1, 2, 3], np.int64), dt.INT64)], ["v"])
+    out = groupby_aggregate(keys, vals, [("v", "sum")])
+    assert np.asarray(out.columns[1].data).tolist() == [3, 3]
+    table = Table([Column(dt.INT32, data=jnp.arange(16, dtype=jnp.int32))], ["a"])
+    sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, sidecar._write_table(table, framed=False), "cpu")
+    assert _counter("trace.spans") == spans_before
+    assert trace_sink.recorder().last(5) == []
+    trace_sink.close_log()
+    assert not os.path.exists(trace_sink.resolved_log_path())
+
+
+# ---------------------------------------------------------------------------
+# the sink: nothing before a request ends, everything after
+# ---------------------------------------------------------------------------
+
+
+def test_sink_writes_nothing_before_the_root_finishes_and_everything_after():
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            for i in range(5):
+                with tracing.span(f"s{i}"):
+                    pass
+            assert _log_spans() == []
+        assert _log_spans() == []
+        qt.finish("ok")
+    assert [s["name"] for s in _log_spans()] == ["s0", "s1", "s2", "s3", "s4", "q"]
+
+
+def test_close_log_writes_what_is_buffered():
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            with tracing.span("early"):
+                pass
+            assert _log_spans() == []
+            trace_sink.close_log()
+            assert [s["name"] for s in _log_spans()] == ["early"]
+        qt.finish("ok")
+    assert [s["name"] for s in _log_spans()] == ["early", "q"]
+
+
+def test_set_log_path_writes_the_buffer_to_the_old_path(tmp_path):
+    old = trace_sink.resolved_log_path()
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            with tracing.span("before_the_move"):
+                pass
+            trace_sink.set_log_path(str(tmp_path / "moved.jsonl"))
+        qt.finish("ok")
+    assert [r["name"] for r in _read_logs(old)] == ["before_the_move"]
+    assert [s["name"] for s in _log_spans()] == ["q"]
+
+
+def test_the_length_cap_bounds_a_process_that_never_finishes_a_root(monkeypatch):
+    monkeypatch.setattr(trace_sink, "_BUFFER_MAX", 8)
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            for i in range(19):
+                with tracing.span(f"s{i}"):
+                    pass
+            assert len(_log_spans()) == 16  # two full buffers; three spans wait
+            assert len(trace_sink._buffer) == 3
+        qt.finish("ok")
+    assert len(_log_spans()) == 20
+
+
+def test_the_stats_verb_is_a_flush_point():
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            with tracing.span("held"):
+                pass
+            assert _log_spans() == []
+            sidecar._dispatch(sidecar.OP_STATS, b"", "cpu")
+            assert [s["name"] for s in _log_spans()] == ["held"]
+        qt.finish("ok")
+
+
+def test_a_remote_scope_writes_its_spans_when_it_exits():
+    with tracing.enabled():
+        with tracing.remote_scope(0xABC, 0xDEF, True):
+            with tracing.span("worker_side"):
+                pass
+            assert _log_spans() == []
+        spans = _log_spans()
+    assert [s["name"] for s in spans] == ["worker_side"]
+    assert spans[0]["parent"] == f"{0xDEF:016x}" and spans[0]["trace"] == f"{0xABC:016x}"
+
+
+def test_a_straggler_past_its_root_reaches_the_log_on_its_own():
+    import contextvars
+
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            late = contextvars.copy_context()
+        qt.finish("ok")
+        assert [s["name"] for s in _log_spans()] == ["q"]
+
+        def straggle():
+            with tracing.span("hedge_loser"):
+                pass
+
+        late.run(straggle)
+    assert [s["name"] for s in _log_spans()] == ["q", "hedge_loser"]
+
+
+def test_a_flushed_trace_record_follows_its_spans_in_the_log():
+    with tracing.enabled():
+        qt = tracing.start_trace("q")
+        with qt.activate():
+            with tracing.span("inner"):
+                pass
+        qt.finish("failed")
+    recs = _read_logs(trace_sink.resolved_log_path())
+    assert [(r["kind"], r["name"]) for r in recs] == [
+        ("span", "inner"), ("span", "q"), ("trace", "q")]
+    assert recs[-1]["flushed"] and len(recs[-1]["spans"]) == 2
+
+
+# ---------------------------------------------------------------------------
+# the sidecar request: one spawned worker, CONVERT_TO_ROWS through a region
+# ---------------------------------------------------------------------------
+
+WORKER_SPANS = ("sidecar.worker.payload_read", "sidecar.worker.decode_table", "sidecar.worker.d2h",
+                "sidecar.worker.encode_reply", "sidecar.worker.reply_write")
+CLIENT_SPANS = ("sidecar.client.send", "sidecar.client.wait", "sidecar.client.reply_read")
+
+
+@pytest.fixture(scope="module")
+def traced_convert(tmp_path_factory):
+    """One traced CONVERT_TO_ROWS through SidecarPool(1) with a real worker
+    process: the spans of both processes, the frame sizes, and the worker's
+    STATS reply (polled after the request: it flushes the worker's log)."""
+    base = str(tmp_path_factory.mktemp("phase") / "spans")
+    rng = np.random.default_rng(7)
+    table = Table(
+        [
+            Column.from_numpy(rng.integers(-9, 9, 500).astype(np.int32), dt.INT32,
+                              validity=rng.random(500) > 0.1),
+            Column.from_numpy(rng.integers(0, 1 << 40, 500).astype(np.int64), dt.INT64),
+            Column.from_numpy(rng.integers(0, 100, 500).astype(np.int8), dt.INT8),
+        ],
+        ["a", "b", "c"],
+    )
+    payload = sidecar._write_table(table, framed=False)
+    want = sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, payload, "cpu")
+    prev_base, prev_enabled = trace_sink.log_path(), tracing.is_enabled()
+    pool = sidecar_pool.SidecarPool(
+        size=1, deadline_s=120, heartbeat_s=1e9, startup_timeout_s=120.0, slab_bytes=1 << 20,
+        env={"SRJT_TRACE_ENABLED": "1", "SRJT_TRACE_LOG": base},
+    )
+    try:
+        assert pool.call(sidecar.OP_PING).decode() == "cpu"  # untraced: no span of it
+        trace_sink.set_log_path(base)
+        tracing.set_enabled(True)
+        region = pool.lease(max(len(payload), len(want)))
+        try:
+            region.write(payload)
+            qt = tracing.start_trace("test.request")
+            with qt.activate():
+                reply = pool.call(sidecar.OP_CONVERT_TO_ROWS, region=region)
+            qt.finish("ok")
+        finally:
+            region.release()
+        tracing.set_enabled(False)
+        trace_sink.close_log()
+        stats = pool.worker_stats(fold=False)["w0"]
+    finally:
+        tracing.set_enabled(prev_enabled)
+        trace_sink.set_log_path(prev_base)
+        pool.shutdown()
+    assert reply == want
+    # the worker's handler thread closes its last span (the reply's write)
+    # and writes its log AFTER the client has the reply: wait for it
+    end = time.monotonic() + 10.0
+    while True:
+        spans = [r for r in _read_logs(base + ".*.jsonl") if r.get("kind") == "span"]
+        if any(s["name"] == "sidecar.worker.reply_write" for s in spans) or time.monotonic() > end:
+            break
+        time.sleep(0.02)
+    return {"spans": spans, "request_bytes": len(payload), "reply_bytes": len(want),
+            "stats": stats, "cols": 3}
+
+
+def _descends_from(span, ancestor_id, by_id):
+    while span is not None:
+        if span["parent"] == ancestor_id:
+            return True
+        span = by_id.get(span["parent"])
+    return False
+
+
+@pytest.mark.parametrize("name", WORKER_SPANS + CLIENT_SPANS + ("sidecar.worker_op", "op.convert_to_rows"))
+def test_every_phase_of_the_request_is_one_span_under_the_clients_request(traced_convert, name):
+    spans = traced_convert["spans"]
+    request = _one(spans, "sidecar.request")
+    span = _one(spans, name)
+    assert _descends_from(span, request["span"], {s["span"]: s for s in spans})
+    assert span["trace"] == request["trace"]
+    # two processes, one tree: the worker's spans carry another pid
+    assert (span["pid"] != request["pid"]) == (not name.startswith("sidecar.client."))
+
+
+def test_worker_handling_spans_are_siblings_of_the_worker_op(traced_convert):
+    spans = traced_convert["spans"]
+    request = _one(spans, "sidecar.request")["span"]
+    for name in ("sidecar.worker_op", "sidecar.worker.payload_read", "sidecar.worker.reply_write") + CLIENT_SPANS:
+        assert _one(spans, name)["parent"] == request, name
+    op = _one(spans, "sidecar.worker_op")["span"]
+    for name in ("sidecar.worker.decode_table", "op.convert_to_rows", "sidecar.worker.d2h",
+                 "sidecar.worker.encode_reply"):
+        assert _one(spans, name)["parent"] == op, name
+
+
+def test_the_four_crc_passes_are_named_and_sized_by_their_frames(traced_convert):
+    crcs = {s["annotations"]["where"]: s for s in traced_convert["spans"] if s["name"] == "integrity.crc"}
+    assert sorted(crcs) == ["reply", "request", "verify_reply", "verify_request"]
+    req, rep = traced_convert["request_bytes"], traced_convert["reply_bytes"]
+    assert {w: s["annotations"]["bytes"] for w, s in crcs.items()} == {
+        "request": req, "verify_request": req, "reply": rep, "verify_reply": rep}
+    request = _one(traced_convert["spans"], "sidecar.request")
+    by_id = {s["span"]: s for s in traced_convert["spans"]}
+    assert all(_descends_from(s, request["span"], by_id) for s in crcs.values())
+    assert crcs["request"]["pid"] == crcs["verify_reply"]["pid"] == request["pid"]
+    assert crcs["verify_request"]["pid"] == crcs["reply"]["pid"] != request["pid"]
+
+
+def test_bytes_annotations_equal_the_frame_sizes(traced_convert):
+    spans, req, rep = traced_convert["spans"], traced_convert["request_bytes"], traced_convert["reply_bytes"]
+    ann = {name: _one(spans, name).get("annotations") for name in WORKER_SPANS + CLIENT_SPANS}
+    assert ann["sidecar.client.send"] == {"bytes": req}
+    assert ann["sidecar.worker.payload_read"] == {"bytes": req, "via": "region"}
+    assert ann["sidecar.worker.decode_table"] == {"bytes": req, "cols": traced_convert["cols"]}
+    assert ann["sidecar.worker.encode_reply"] == {"bytes": rep}
+    assert ann["sidecar.worker.reply_write"] == {"bytes": rep, "via": "region"}
+    assert ann["sidecar.client.reply_read"] == {"bytes": rep, "via": "region"}
+    # offsets and blob, less the reply's 20 bytes of counts and lengths
+    assert ann["sidecar.worker.d2h"] == {"bytes": rep - 20}
+    assert ann["sidecar.client.wait"] is None
+
+
+def test_the_request_is_covered_by_its_phases(traced_convert):
+    spans = traced_convert["spans"]
+    request = _one(spans, "sidecar.request")
+    client = sum(_one(spans, n)["dur_us"] for n in CLIENT_SPANS)
+    assert client >= 0.9 * request["dur_us"]
+    wait = _one(spans, "sidecar.client.wait")["dur_us"]
+    worker = sum(s["dur_us"] for s in spans
+                 if s["parent"] == request["span"] and s["pid"] != request["pid"])
+    assert 0.8 * wait <= worker <= wait
+
+
+def test_stats_carries_the_workers_device_and_memory(traced_convert):
+    stats = traced_convert["stats"]
+    assert stats["device"] == {"platform": "cpu", "kind": jax.devices()[0].device_kind,
+                               "count": len(jax.devices())}
+    assert sorted(stats["memory"]) == sorted(str(d.id) for d in jax.devices())
+    assert all(m == {} for m in stats["memory"].values())  # the CPU reports none
+    assert {"backend", "snapshot", "memgov"} <= set(stats)
+    counters = stats["snapshot"]["counters"]
+    assert counters["xla.backend_compiles"] >= 1 and counters["xla.backend_compile_s"] > 0
+
+
+def test_device_section_reads_memory_stats_where_the_backend_gives_them(monkeypatch):
+    class Chip:
+        id, platform, device_kind = 0, "tpu", "TPU v5 lite"
+
+        def memory_stats(self):
+            return {"bytes_in_use": 5, "peak_bytes_in_use": 9, "bytes_limit": 16, "num_allocs": 3}
+
+    monkeypatch.setattr(jax, "devices", lambda: [Chip()])
+    assert sidecar._device_section() == {
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+        "memory": {"0": {"bytes_in_use": 5, "peak_bytes_in_use": 9, "bytes_limit": 16}},
+    }
+
+
+# ---------------------------------------------------------------------------
+# the ten readers, loaded by path, on a synthetic span list
+# ---------------------------------------------------------------------------
+
+
+def _reader(name):
+    if BENCH_DIR not in sys.path:
+        sys.path.insert(0, BENCH_DIR)  # the readers import benchlib.tracered
+    spec = importlib.util.spec_from_file_location(
+        f"phase_reader_{name}", os.path.join(BENCH_DIR, "readers", f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def _s(name, dur_ms, span=None, parent=None):
+    return {"name": name, "dur_us": dur_ms * 1e3, "ts": 1.0, "span": span or name, "parent": parent}
+
+
+# two requests; names that no reader may count stand beside those it must
+SYNTHETIC = [
+    _s("sidecar.worker.payload_read", 200), _s("sidecar.worker.payload_read", 220),
+    _s("sidecar.worker.decode_table", 1500), _s("sidecar.worker.decode_table", 1700),
+    _s("sidecar.worker.d2h", 900), _s("sidecar.worker.d2h", 1100),
+    _s("sidecar.worker.encode_reply", 1000), _s("sidecar.worker.encode_reply", 1400),
+    _s("sidecar.worker.reply_write", 300), _s("sidecar.worker.reply_write", 500),
+    _s("sidecar.client.reply_read", 250), _s("sidecar.client.reply_read", 350),
+    _s("sidecar.client.send", 7), _s("sidecar.client.wait", 8000), _s("sidecar.worker_op", 5000),
+    *[_s("integrity.crc", ms) for ms in (100, 110, 150, 160, 100, 110, 150, 160)],
+    _s("groupby.sort", 400), _s("groupby.sort", 600), _s("groupby.segments", 30), _s("groupby.segments", 50),
+    _s("groupby.keys", 8), _s("groupby.keys", 12), _s("op.groupby_aggregate", 9999),
+    _s("groupby.agg.sum", 1000), _s("groupby.agg.sum", 1200), _s("groupby.agg.mean", 1500),
+    _s("groupby.agg.count_all", 300), _s("groupby.aggregate_not_an_agg", 77),
+]
+EXPECTED = {
+    "sidecar_payload_read_ms": 210.0,
+    "sidecar_decode_table_ms": 1600.0,
+    "sidecar_d2h_ms": 1000.0,
+    "sidecar_encode_reply_ms": 1200.0,
+    "sidecar_reply_write_ms": 400.0,
+    "sidecar_reply_read_ms": 300.0,
+    "sidecar_crc_ms": 520.0,
+    "groupby_order_ms": 550.0,
+    "groupby_agg_ms": 2000.0,
+}
+ALL_READERS = sorted(EXPECTED) + ["plan_stage_self_ms"]
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_reader_reduces_the_synthetic_spans_to_the_mean_per_request(name):
+    got = _reader(name)({"spans": SYNTHETIC, "requests": [object(), object()]})
+    assert got == pytest.approx(EXPECTED[name])
+
+
+@pytest.mark.parametrize("name", ALL_READERS)
+def test_reader_finds_nothing_to_read_on_the_parents_spans(name):
+    """The parent commit has none of these spans: the reader returns None
+    (the line then leaves the metric out) and does not raise."""
+    parents = [_s("serve.run", 11000), _s("op.groupby_aggregate", 9000), _s("sidecar.worker_op", 5000),
+               _s("sidecar.request", 8000)]
+    read = _reader(name)
+    assert read({"spans": parents, "requests": [object()]}) is None
+    assert read({"spans": [], "requests": []}) is None
+
+
+def test_plan_stage_self_time_on_a_three_deep_nest():
+    # plan.sort 100 > plan.aggregate 90 > plan.project 30 > plan.filter 20 (an op.* of 5 inside);
+    # op.groupby_aggregate 50 inside plan.aggregate, op.sort_by_key 4 inside plan.sort;
+    # a groupby.* child of the operator and a serve.run above are not plan stages' children
+    spans = [
+        _s("serve.run", 101, "run"),
+        _s("plan.sort", 100, "sort", "run"),
+        _s("op.sort_by_key", 4, "sbk", "sort"),
+        _s("plan.aggregate", 90, "agg", "sort"),
+        _s("op.groupby_aggregate", 50, "gb", "agg"),
+        _s("groupby.sort", 20, "gbs", "gb"),
+        _s("plan.project", 30, "proj", "agg"),
+        _s("plan.filter", 20, "filt", "proj"),
+        _s("op.apply_boolean_mask", 5, "abm", "filt"),
+        _s("xla.compile", 3, "xc", "filt"),
+    ]
+    # self: sort 100-4-90 = 6, aggregate 90-50-30 = 10, project 30-20 = 10, filter 20-5 = 15
+    read = _reader("plan_stage_self_ms")
+    assert read({"spans": spans, "requests": [object()]}) == pytest.approx(41.0)
+    again = [dict(s, span=s["span"] + "2", parent=s["parent"] and s["parent"] + "2") for s in spans]
+    assert read({"spans": spans + again, "requests": [object(), object()]}) == pytest.approx(41.0)
