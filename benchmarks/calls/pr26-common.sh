@@ -1,4 +1,4 @@
-# PR 26 chip calls: shared by pr26-call*.sh (sourced). Parent and change run from two
+# PR 26 and 27 chip calls: shared by pr26-call*.sh and pr27-call*.sh (sourced; PR_TAG names the output directory). Parent and change run from two
 # checkouts inside one copy: the change is the tree itself, the parent is .bench_checkout/
 # (git archive of the parent commit with this PR's BENCHMARK.json, bench/ and
 # benchmarks/trace_cost.py laid over it, made before the call: the copy holds no .git).
@@ -6,7 +6,7 @@
 # One compile cache for both sides, so neither pays the other's cold compile.
 set -x
 HERE=$PWD
-OUT=$HERE/chiprun_out/pr26
+OUT=$HERE/chiprun_out/${PR_TAG:-pr26}
 mkdir -p "$OUT"
 export JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-$HERE/.jax_cache}
 side_dir() { if [ "$1" = parent ]; then echo "$HERE/.bench_checkout"; else echo "${CHANGE_DIR:-$HERE}"; fi; }

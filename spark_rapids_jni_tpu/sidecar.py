@@ -50,7 +50,11 @@ VERDICT r3 item 2):
   3 CONVERT_FROM_ROWS in:  u32 ncols, i32[ncols] type_ids, i32[ncols]
                            scales, u64 nrows, i32[nrows+1] offsets,
                            u64 blob_len, u8 blob
-                      out: serialized table (_write_table)
+                      out: serialized table (_write_table; inside the
+                           worker a reply may be ``ReplyPieces``, the
+                           headers and host arrays in wire order, which
+                           ``reply()`` gathers onto the wire unjoined:
+                           the bytes on the wire are the same)
   4 CAST_TO_INTEGER   in:  u8 ansi, i32 out_type_id, serialized table
                            (one STRING column)
                       out: serialized table (one column); ANSI failures
@@ -208,30 +212,110 @@ STATUS_CAST_ERROR = 2
 _NO_SCOPE = contextlib.nullcontext()
 
 
+class ReplyPieces:
+    """An op's reply as an ordered list of bytes-like pieces: small
+    ``struct.pack`` headers and the host arrays the device-to-host copy
+    produced, as byte views. The wire carries the pieces back to back,
+    so it is byte for byte what ``tobytes()`` gives; ``reply()`` gathers
+    them into the region, the arena or the stream under a running CRC
+    and never builds that one object. A plain ``bytes`` reply is the
+    one-piece case (``pieces_of``)."""
+
+    __slots__ = ("pieces", "_len")
+
+    def __init__(self, pieces):
+        self.pieces = [_byte_view(p) for p in pieces]
+        self._len = sum(len(p) for p in self.pieces)
+
+    def __len__(self) -> int:
+        return self._len
+
+    def tobytes(self) -> bytes:
+        """One owned object, for the callers that need one: the host
+        fallback's return value, the ``corrupt`` chaos hook."""
+        return b"".join(self.pieces)
+
+
+def _byte_view(piece) -> memoryview:
+    """``piece`` (bytes-like or a host ndarray) as a flat byte view of
+    the same memory; the view keeps its owner alive."""
+    if hasattr(piece, "dtype"):  # ndarray: flat (a copy only if strided), as bytes
+        piece = piece.reshape(-1).view("u1")
+    return memoryview(piece)
+
+
+def pieces_of(body) -> list:
+    """The bytes-like pieces of a reply, in wire order."""
+    return body.pieces if isinstance(body, ReplyPieces) else [body]
+
+
+def as_bytes(body) -> bytes:
+    """A reply as one ``bytes`` (a ``bytes`` reply is returned as is)."""
+    return body.tobytes() if isinstance(body, ReplyPieces) else body
+
+
+def _take_fds(ancdata, fds: list) -> None:
+    """Capture SCM_RIGHTS file descriptors from a ``recvmsg`` into ``fds``."""
+    import array
+
+    for level, ctype, cdata in ancdata:
+        if level == socket.SOL_SOCKET and ctype == socket.SCM_RIGHTS:
+            a = array.array("i")
+            a.frombytes(cdata[: len(cdata) - (len(cdata) % a.itemsize)])
+            fds.extend(a)
+
+
+_FD_SPACE = socket.CMSG_SPACE(4 * 4)  # room for four SCM_RIGHTS ints
+
+
 def _recv_exact(conn: socket.socket, n: int, fds: list = None) -> bytes:
     """Read exactly n bytes. With ``fds`` given, capture any SCM_RIGHTS
     file descriptors that arrive attached to the stream (the
     OP_SET_ARENA memfd travels with its header bytes) into it; without,
     plain recv (client-side use, where no fds ever arrive)."""
-    import array
-
     buf = bytearray()
     while len(buf) < n:
         if fds is None:
             chunk = conn.recv(n - len(buf))  # srjt-lint: allow-blocking(worker/probe-side request wait: the CLIENT owns every deadline; the server parks here between requests by design)
         else:
             chunk, ancdata, _flags, _addr = conn.recvmsg(  # srjt-lint: allow-blocking(worker-side request wait, SCM_RIGHTS variant; the client owns the deadline)
-                n - len(buf), socket.CMSG_SPACE(4 * array.array("i").itemsize)
+                n - len(buf), _FD_SPACE
             )
-            for level, ctype, cdata in ancdata:
-                if level == socket.SOL_SOCKET and ctype == socket.SCM_RIGHTS:
-                    a = array.array("i")
-                    a.frombytes(cdata[: len(cdata) - (len(cdata) % a.itemsize)])
-                    fds.extend(a)
+            _take_fds(ancdata, fds)
         if not chunk:
             raise ConnectionError("sidecar: peer closed")
         buf.extend(chunk)
     return bytes(buf)
+
+
+def _recv_into(conn: socket.socket, view: memoryview, fds: list) -> None:
+    """Fill ``view`` from the stream (the worker's payload read into its
+    kept buffer): ``_recv_exact`` with the caller's memory as the
+    destination, SCM_RIGHTS captured alike."""
+    got = 0
+    while got < len(view):
+        n, ancdata, _flags, _addr = conn.recvmsg_into([view[got:]], _FD_SPACE)
+        _take_fds(ancdata, fds)
+        if not n:
+            raise ConnectionError("sidecar: peer closed")
+        got += n
+
+
+_IOV_MAX = 512  # buffers a sendmsg; POSIX guarantees 1024 on Linux
+
+
+def _send_gathered(conn: socket.socket, pieces) -> None:
+    """``sendall`` of the pieces back to back without joining them: one
+    gathering ``sendmsg`` after another until every byte is out."""
+    pending = [p for p in map(memoryview, pieces) if len(p)]
+    at = 0
+    while at < len(pending):
+        sent = conn.sendmsg(pending[at : at + _IOV_MAX])
+        while at < len(pending) and sent >= len(pending[at]):
+            sent -= len(pending[at])
+            at += 1
+        if sent:
+            pending[at] = pending[at][sent:]
 
 
 # wire table format negotiation (ISSUE 6): the worker answers each
@@ -319,7 +403,9 @@ def _decode_table(payload: bytes, pos: int = 0):
         else:
             (dlen,) = struct.unpack_from("<Q", payload, pos)
             pos += 8
-            raw = payload[pos : pos + dlen]
+            # a view of the payload, not a copy (``payload`` is the
+            # worker's kept buffer or a host-fallback caller's bytes)
+            raw = memoryview(payload)[pos : pos + dlen]
             pos += dlen
             if tid == TypeId.DECIMAL128:
                 data = np.frombuffer(raw, np.uint32).reshape(n, 4)
@@ -346,13 +432,18 @@ def _op_groupby_sum(payload: bytes) -> bytes:
     return np.asarray(sums, np.float32).tobytes() + np.asarray(counts, np.int64).tobytes()
 
 
-def _write_table(table, framed: bool = None) -> bytes:
+def _write_table(table, framed: bool = None):
     """Serialize a Table for the wire. ``framed=None`` (the worker's
     posture) echoes the format the current request's ``_read_table``
     sniffed, so the C++ client parses responses with the same legacy
     walker it serializes requests with, and framed clients decode the
     shared codec. LIST<INT8|UINT8> columns reuse the STRING framing
-    (offsets + byte child) in the legacy form."""
+    (offsets + byte child) in the legacy form.
+
+    The legacy form comes back as ``ReplyPieces``: the small headers
+    and the host arrays themselves, in wire order, never joined here
+    (``reply()`` gathers them; ``.tobytes()`` gives the one object).
+    The framed form is the codec's ``bytes``."""
     import numpy as np
 
     from .columnar.dtype import TypeId
@@ -371,7 +462,7 @@ def _write_table(table, framed: bool = None) -> bytes:
         return resp
     # two passes, so that neither span sits in the per-column loop:
     # every device array to the host first (the wait for the kernel
-    # that produced it included), then the host copies into wire bytes
+    # that produced it included), then the list of wire pieces
     with tracing.span("sidecar.worker.d2h") as sp:
         host = []
         for col in table.columns:
@@ -395,23 +486,22 @@ def _write_table(table, framed: bool = None) -> bytes:
     with tracing.span("sidecar.worker.encode_reply") as sp:
         out = [struct.pack("<I", len(host))]
         for d, n, validity, offs, raw in host:
-            out.append(struct.pack("<ii", int(d.id.value), int(d.scale)))
-            out.append(struct.pack("<Q", n))
+            out.append(struct.pack("<iiQ", int(d.id.value), int(d.scale), n))
             if validity is not None:
                 out.append(b"\x01")
-                out.append(validity.tobytes())
+                out.append(validity)
             else:
                 out.append(b"\x00")
             if offs is not None:  # STRING / LIST: offsets, then the byte child
-                out.append(offs.tobytes())
+                out.append(offs)
             out.append(struct.pack("<Q", raw.nbytes))
-            out.append(raw.tobytes())
-        resp = b"".join(out)
-        sp.annotate(bytes=len(resp))
+            out.append(raw)
+        resp = ReplyPieces(out)
+        sp.annotate(bytes=len(resp), pieces=len(resp.pieces))
     return resp
 
 
-def _op_convert_to_rows(payload: bytes) -> bytes:
+def _op_convert_to_rows(payload: bytes) -> ReplyPieces:
     import numpy as np
 
     from .ops.row_conversion import convert_to_rows
@@ -420,7 +510,7 @@ def _op_convert_to_rows(payload: bytes) -> bytes:
     table = _read_table(payload)
     batches = convert_to_rows(table)
     # device to host (the wait for the transcode kernel included), then
-    # the host copies into wire bytes: one span each, per request
+    # the list of wire pieces over those host arrays: one span each
     with tracing.span("sidecar.worker.d2h") as sp:
         host = [
             (
@@ -435,11 +525,11 @@ def _op_convert_to_rows(payload: bytes) -> bytes:
         out = [struct.pack("<I", len(host))]
         for n, offs, blob in host:
             out.append(struct.pack("<Q", n))
-            out.append(offs.tobytes())
+            out.append(offs)
             out.append(struct.pack("<Q", blob.size))
-            out.append(blob.tobytes())
-        resp = b"".join(out)
-        sp.annotate(bytes=len(resp))
+            out.append(blob)
+        resp = ReplyPieces(out)
+        sp.annotate(bytes=len(resp), pieces=len(resp.pieces))
     return resp
 
 
@@ -574,7 +664,10 @@ def _op_stats(backend: str) -> bytes:
     ).encode()
 
 
-def _dispatch(op: int, payload: bytes, backend: str) -> bytes:
+def _dispatch(op: int, payload, backend: str):
+    """Run one op over ``payload`` (any bytes-like object). The reply is
+    ``bytes`` or, from the ops that answer with host arrays,
+    ``ReplyPieces``; ``as_bytes`` makes one object of either."""
     # fresh wire-format slot per dispatch: host-fallback callers reuse
     # threads, and a stale `framed` sniff from an earlier request would
     # make an op that never reads a table echo the wrong table layout
@@ -618,14 +711,25 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
     # it registers as a host-tier PINNED catalog entry, keyed per
     # connection, and surfaces in the STATS verb / stats_report()
     arena_key = f"sidecar.arena.conn{id(conn)}"
+    # the kept request buffer: every payload is copied (arena, region)
+    # or received (stream) into it, so no request faults in a fresh
+    # object of its own size. It grows to the largest payload seen and
+    # is host memory this connection holds between requests: registered
+    # like the arena mapping, dropped with the connection.
+    scratch = bytearray()
+    scratch_key = f"sidecar.scratch.conn{id(conn)}"
     fds: list = []
 
-    def reply(status: int, body: bytes, with_crc: bool, crc_body: bytes = None,
-              region=None):
-        """One response frame. ``crc_body`` is what the trailer covers
-        when it differs from the bytes on the wire — the injected
-        ``corrupt`` chaos flips bytes AFTER checksumming, exactly like
-        a transport fault, so the client's CRC check MUST fail.
+    def reply(status: int, body, with_crc: bool, crc_body=None, region=None):
+        """One response frame. ``body`` is ``bytes`` or ``ReplyPieces``:
+        the trailer is a running CRC over the pieces in order (equal to
+        the CRC of the joined bytes) and each piece is written at its
+        offset into the region or the arena, or gathered down the
+        stream — the body is never joined. ``crc_body`` is what the
+        trailer covers when it differs from the bytes on the wire — the
+        injected ``corrupt`` chaos flips bytes AFTER checksumming,
+        exactly like a transport fault, so the client's CRC check MUST
+        fail.
         ``region`` is the (offset, capacity, request_id, generation) of
         a slab-mode region request: a fitting OK response lands back
         inside that region (header-only frame) after the in-slab header
@@ -633,12 +737,21 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
         connections never answer through the arena otherwise — the
         legacy single-buffer opportunism is exactly what serialized the
         whole pool on one lock."""
+        reg.counter(
+            "sidecar.worker.reply.gathered_bytes"
+            if isinstance(body, ReplyPieces)
+            else "sidecar.worker.reply.joined_bytes"
+        ).inc(len(body))
         trailer = b""
         if with_crc and integrity.is_enabled():
             status |= CRC_FLAG
             covered = body if crc_body is None else crc_body
+            # over the worker's own in-hand arrays, never the shared pages
             with tracing.span("integrity.crc", bytes=len(covered), where="reply"):
-                trailer = integrity.pack_crc(integrity.checksum(covered))
+                crc = 0
+                for piece in pieces_of(covered):
+                    crc = integrity.checksum(piece, crc)
+                trailer = integrity.pack_crc(crc)
         ok = (status & ~_FLAG_MASK) == STATUS_OK
         via, start = "stream", 0  # where the body goes: region / arena / stream
         if ok and region is not None and 0 < len(body) <= region[1]:
@@ -665,9 +778,15 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
             via = "arena"
         with tracing.span("sidecar.worker.reply_write", bytes=len(body), via=via):
             if via == "stream":
-                conn.sendall(struct.pack("<IQ", status, len(body)) + trailer + body)
+                _send_gathered(
+                    conn,
+                    [struct.pack("<IQ", status, len(body)) + trailer,
+                     *pieces_of(body)],
+                )
             else:
-                arena[start : start + len(body)] = body
+                for piece in pieces_of(body):
+                    arena[start : start + len(piece)] = piece
+                    start += len(piece)
                 conn.sendall(
                     struct.pack("<IQ", status | ARENA_FLAG, len(body)) + trailer
                 )
@@ -759,12 +878,31 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
                     via, start = "arena", 0
                 else:
                     via = "stream"
-                # header parsed -> the payload's bytes in hand
+                # header parsed -> the payload's bytes in hand, in the
+                # kept buffer. The copy stays: the CRC below is verified
+                # over bytes in hand, never a re-read of shared pages,
+                # and the reply lands where the request lay. Overwriting
+                # the previous request's bytes is safe: a connection
+                # handles one request at a time, and whatever viewed
+                # them (host arrays of ``_decode_table``, which the CPU
+                # backend's ``jnp.asarray`` may alias; the reply's
+                # pieces) was dropped when that request was answered.
                 with tracing.span("sidecar.worker.payload_read", bytes=plen, via=via):
-                    if via != "stream":
-                        payload = bytes(arena[start : start + plen])
+                    if plen > len(scratch):
+                        scratch = bytearray(plen)
+                        memgov.catalog().register_host_bytes(
+                            scratch_key, plen, pinned=True, kind="scratch"
+                        )
+                        reg.counter("sidecar.worker.scratch.grows").inc()
+                    elif plen:
+                        reg.counter("sidecar.worker.scratch.reuses").inc()
+                    dst = memoryview(scratch)[:plen]
+                    if via == "stream":
+                        _recv_into(conn, dst, fds)
                     else:
-                        payload = _recv_exact(conn, plen, fds) if plen else b""
+                        with memoryview(arena) as shared:
+                            dst[:] = shared[start : start + plen]
+                    payload = dst.toreadonly()
                 _REQ_FMT.framed = False  # set by _read_table when it sniffs a frame
                 if req_crc is not None and integrity.is_enabled():
                     reg.counter("sidecar.integrity.frames_checked").inc()
@@ -860,10 +998,14 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
                     wire_resp = resp
                     if faultinj.is_enabled():
                         # `corrupt` chaos: flips bytes BELOW the checksum
+                        # (the hook copies one object, so pieces are joined)
                         wire_resp = faultinj.maybe_corrupt(
-                            f"sidecar.worker.{op_name(op)}", resp
+                            f"sidecar.worker.{op_name(op)}", as_bytes(resp)
                         )
                     reply(STATUS_OK, wire_resp, with_crc, crc_body=resp, region=region)
+                    # the reply's host arrays (and what they view) die
+                    # with the request, not with the next one
+                    resp = wire_resp = None
                 except Exception as e:  # srjt-lint: allow-broad-except(worker request loop: every failure must become a status-1 reply carrying the taxonomy prefix — the client re-raises the right class across the wire; the worker keeps serving)
                     from .ops.cast_string import CastError
 
@@ -882,6 +1024,7 @@ def _handle_conn(conn: socket.socket, backend: str, shutdown) -> None:
         if arena is not None:
             arena.close()
             memgov.catalog().unregister(arena_key)
+        memgov.catalog().unregister(scratch_key)
         for fd in fds:
             os.close(fd)
         conn.close()
@@ -1370,7 +1513,7 @@ class SupervisedClient:
             self.host_fallbacks += 1
             metrics.counter("sidecar.host_fallbacks").inc()
             metrics.event("sidecar.breaker_fast_fail", op=op_name(op))
-            return _dispatch(op, payload, "host-fallback")
+            return as_bytes(_dispatch(op, payload, "host-fallback"))
         try:
             resp = retry.call_with_retry(
                 self.request, op, payload, op_name=f"sidecar_op_{op}"
@@ -1405,7 +1548,7 @@ class SupervisedClient:
                 "sidecar.degrade_to_host", op=op_name(op), cls=type(e).__name__
             )
             self.close()
-            return _dispatch(op, payload, "host-fallback")
+            return as_bytes(_dispatch(op, payload, "host-fallback"))
         except Exception:
             # semantic errors (ANSI cast failures, worker API errors)
             # round-tripped the transport: a healthy device path

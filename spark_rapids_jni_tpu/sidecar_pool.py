@@ -1515,9 +1515,9 @@ class SidecarPool:
         br = sidecar.breaker()
         if not br.allow():
             self._host_fallback_count(op, "breaker_open")
-            return sidecar._dispatch(
+            return sidecar.as_bytes(sidecar._dispatch(
                 op, payload if region_req is None else region_req, "host-fallback"
-            )
+            ))
         try:
             resp = retry.call_with_retry(
                 self._attempt, op, payload, region, region_req,
@@ -1539,9 +1539,9 @@ class SidecarPool:
                 # exists to remember
                 br.record_failure(cause=type(e).__name__)
             self._host_fallback_count(op, type(e).__name__)
-            return sidecar._dispatch(
+            return sidecar.as_bytes(sidecar._dispatch(
                 op, payload if region_req is None else region_req, "host-fallback"
-            )
+            ))
         except Exception:
             br.record_success()  # semantic error: transport healthy
             raise

@@ -219,12 +219,12 @@ class TestSidecarSupervision:
             tbl = Table(
                 [Column(dt.INT32, data=jnp.arange(64, dtype=jnp.int32))], ["a"]
             )
-            payload = sidecar._write_table(tbl)
+            payload = sidecar.as_bytes(sidecar._write_table(tbl))
             t0 = time.monotonic()
             with retry.enabled(max_attempts=3, base_delay_ms=1):
                 resp = client.call(sidecar.OP_CONVERT_TO_ROWS, payload)
             elapsed = time.monotonic() - t0
-            host = sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, payload, "cpu")
+            host = sidecar.as_bytes(sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, payload, "cpu"))
             assert resp == host  # host fallback produced the real result
             assert client.host_fallbacks == 1
             assert retry.stats()["retries"] == 0  # fatal: zero retries
@@ -277,7 +277,7 @@ class TestSidecarSupervision:
                 tbl = Table(
                     [Column(dt.INT32, data=jnp.arange(8, dtype=jnp.int32))], ["a"]
                 )
-                payload = sidecar._write_table(tbl)
+                payload = sidecar.as_bytes(sidecar._write_table(tbl))
                 t0 = time.monotonic()
                 with pytest.raises(errors.RetryableError, match="DEADLINE_EXCEEDED"):
                     client.request(sidecar.OP_CONVERT_TO_ROWS, payload)

@@ -213,7 +213,7 @@ class TestFramedWire:
             ])
             with client:
                 legacy = client.request(
-                    sidecar.OP_ZORDER, sidecar._write_table(t, framed=False)
+                    sidecar.OP_ZORDER, sidecar.as_bytes(sidecar._write_table(t, framed=False))
                 )
                 framed = client.request(
                     sidecar.OP_ZORDER, frames.encode_table(t)
@@ -243,9 +243,9 @@ class TestFramedWire:
         t = Table([Column(dt.INT32, data=jnp.arange(16, dtype=jnp.int32))])
         sidecar._REQ_FMT.framed = True  # stale from an aborted framed op
         resp = sidecar._dispatch(
-            sidecar.OP_ZORDER, sidecar._write_table(t, framed=False), "cpu"
+            sidecar.OP_ZORDER, sidecar.as_bytes(sidecar._write_table(t, framed=False)), "cpu"
         )
-        assert not frames.is_frame(resp)
+        assert not frames.is_frame(sidecar.as_bytes(resp))
 
 
 # ---------------------------------------------------------------------------

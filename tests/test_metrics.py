@@ -409,7 +409,7 @@ def test_sidecar_stats_verb_and_fold(tmp_path):
                     ["a"],
                 )
                 client.request(sidecar.OP_CONVERT_TO_ROWS,
-                               sidecar._write_table(tbl))
+                               sidecar.as_bytes(sidecar._write_table(tbl)))
                 snap = metrics.snapshot()
                 assert snap["counters"]["sidecar.requests"] == 1
                 assert snap["histograms"]["sidecar.request_us"]["count"] == 1
@@ -440,12 +440,12 @@ def test_sidecar_degrade_records_fallback_metrics(tmp_path):
                     [Column(dt.INT32, data=jnp.arange(16, dtype=jnp.int32))],
                     ["a"],
                 )
-                payload = sidecar._write_table(tbl)
+                payload = sidecar.as_bytes(sidecar._write_table(tbl))
                 with retry.enabled(max_attempts=3, base_delay_ms=1):
                     resp = client.call(sidecar.OP_CONVERT_TO_ROWS, payload)
-                assert resp == sidecar._dispatch(
+                assert resp == sidecar.as_bytes(sidecar._dispatch(
                     sidecar.OP_CONVERT_TO_ROWS, payload, "cpu"
-                )
+                ))
             snap = metrics.snapshot()["counters"]
         assert snap["sidecar.host_fallbacks"] == 1
         assert client.host_fallbacks == 1  # instance attr stays in step

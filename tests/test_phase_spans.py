@@ -256,7 +256,7 @@ def test_tracing_off_makes_no_record_and_hands_out_the_null_span():
     out = groupby_aggregate(keys, vals, [("v", "sum")])
     assert np.asarray(out.columns[1].data).tolist() == [3, 3]
     table = Table([Column(dt.INT32, data=jnp.arange(16, dtype=jnp.int32))], ["a"])
-    sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, sidecar._write_table(table, framed=False), "cpu")
+    sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, sidecar.as_bytes(sidecar._write_table(table, framed=False)), "cpu")
     assert _counter("trace.spans") == spans_before
     assert trace_sink.recorder().last(5) == []
     trace_sink.close_log()
@@ -400,8 +400,8 @@ def traced_convert(tmp_path_factory):
         ],
         ["a", "b", "c"],
     )
-    payload = sidecar._write_table(table, framed=False)
-    want = sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, payload, "cpu")
+    payload = sidecar.as_bytes(sidecar._write_table(table, framed=False))
+    want = sidecar.as_bytes(sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, payload, "cpu"))
     prev_base, prev_enabled = trace_sink.log_path(), tracing.is_enabled()
     pool = sidecar_pool.SidecarPool(
         size=1, deadline_s=120, heartbeat_s=1e9, startup_timeout_s=120.0, slab_bytes=1 << 20,
@@ -489,7 +489,8 @@ def test_bytes_annotations_equal_the_frame_sizes(traced_convert):
     assert ann["sidecar.client.send"] == {"bytes": req}
     assert ann["sidecar.worker.payload_read"] == {"bytes": req, "via": "region"}
     assert ann["sidecar.worker.decode_table"] == {"bytes": req, "cols": traced_convert["cols"]}
-    assert ann["sidecar.worker.encode_reply"] == {"bytes": rep}
+    # one batch: count, rows, offsets, blob length, blob
+    assert ann["sidecar.worker.encode_reply"] == {"bytes": rep, "pieces": 5}
     assert ann["sidecar.worker.reply_write"] == {"bytes": rep, "via": "region"}
     assert ann["sidecar.client.reply_read"] == {"bytes": rep, "via": "region"}
     # offsets and blob, less the reply's 20 bytes of counts and lengths

@@ -353,7 +353,7 @@ def test_ansi_cast_error_status2_on_the_wire(tmp_path):
         col = Column.from_pylist(["5", "oops", "7"], dt.STRING)
         payload = (
             struct.pack("<Bi", 1, int(dt.TypeId.INT32.value))
-            + _write_table(Table([col]))
+            + _write_table(Table([col])).tobytes()
         )
         conn.sendall(struct.pack("<IQ", OP_CAST_TO_INTEGER, len(payload)) + payload)
         status, rlen = struct.unpack("<IQ", _recv_exact(conn, 12))

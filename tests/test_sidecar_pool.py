@@ -835,7 +835,7 @@ def _roundtrip_table_through_pool(pool, table):
     """Ship ``table`` through the pool's device row-conversion pair
     (CONVERT_TO_ROWS -> CONVERT_FROM_ROWS) and rebuild it — the
     mid-query device traffic the failover must carry."""
-    payload = sidecar._write_table(table)
+    payload = sidecar.as_bytes(sidecar._write_table(table))
     resp = pool.call(sidecar.OP_CONVERT_TO_ROWS, payload)
     (nbatches,) = struct.unpack_from("<I", resp, 0)
     assert nbatches == 1
@@ -919,8 +919,8 @@ class TestRealWorkerPool:
             tbl = Table(
                 [Column(dt.INT32, data=jnp.arange(128, dtype=jnp.int32))], ["a"]
             )
-            tp = sidecar._write_table(tbl)
-            want_c = sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, tp, "cpu")
+            tp = sidecar.as_bytes(sidecar._write_table(tbl))
+            want_c = sidecar.as_bytes(sidecar._dispatch(sidecar.OP_CONVERT_TO_ROWS, tp, "cpu"))
             with retry.enabled(max_attempts=8, base_delay_ms=1):
                 for _ in range(4):
                     assert pool.call(sidecar.OP_CONVERT_TO_ROWS, tp) == want_c
@@ -985,3 +985,347 @@ class TestOneChipOwner:
         monkeypatch.delenv("JAX_PLATFORMS", raising=False)  # the conftest pin is on the live config
         with sidecar_pool.SidecarPool(size=1, spawn_fn=_inproc_spawn) as pool:
             assert pool.live_count() == 1
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 27: a kept request buffer, a gathered reply
+# ---------------------------------------------------------------------------
+
+
+def _ref_write_table(table) -> bytes:
+    """The unframed table encoding as PR 26 had it (``tobytes`` and one
+    ``join``): the plain reference the gathered reply must equal."""
+    out = [struct.pack("<I", len(table.columns))]
+    for col in table.columns:
+        d = col.dtype
+        out.append(struct.pack("<ii", int(d.id.value), int(d.scale)))
+        out.append(struct.pack("<Q", len(col)))
+        if col.validity is not None:
+            out.append(b"\x01")
+            out.append(np.asarray(col.validity, np.uint8).tobytes())
+        else:
+            out.append(b"\x00")
+        if d.id in (dt.TypeId.STRING, dt.TypeId.LIST):
+            out.append(np.asarray(col.offsets, np.int32).tobytes())
+            raw = (
+                np.asarray(col.chars, np.uint8)
+                if d.id == dt.TypeId.STRING
+                else np.asarray(col.child.data).view(np.uint8)
+            )
+        else:
+            raw = np.asarray(col.data)
+        out.append(struct.pack("<Q", raw.nbytes))
+        out.append(raw.tobytes())
+    return b"".join(out)
+
+
+def _ref_rows_reply(batches) -> bytes:
+    """CONVERT_TO_ROWS's reply, joined: the same plain reference."""
+    out = [struct.pack("<I", len(batches))]
+    for col in batches:
+        blob = np.asarray(col.child.data).view(np.uint8)
+        out.append(struct.pack("<Q", len(col)))
+        out.append(np.asarray(col.offsets, np.int32).tobytes())
+        out.append(struct.pack("<Q", blob.size))
+        out.append(blob.tobytes())
+    return b"".join(out)
+
+
+def _from_rows_request(rows, dtypes) -> bytes:
+    blob = np.asarray(rows.child.data).view(np.uint8)
+    return (
+        struct.pack("<I", len(dtypes))
+        + np.asarray([int(d.id.value) for d in dtypes], np.int32).tobytes()
+        + np.asarray([int(d.scale) for d in dtypes], np.int32).tobytes()
+        + struct.pack("<Q", len(rows))
+        + np.asarray(rows.offsets, np.int32).tobytes()
+        + struct.pack("<Q", blob.size)
+        + blob.tobytes()
+    )
+
+
+def _wire_tables(n=97):
+    rng = np.random.default_rng(27)
+    i32 = Column(dt.INT32, data=jnp.asarray(rng.integers(-999, 999, n), jnp.int32))
+    i64 = Column(dt.INT64, data=jnp.asarray(rng.integers(-(2**40), 2**40, n), jnp.int64))
+    return {
+        "fixed_width": Table([i32, i64]),
+        "string": Table([
+            Column.from_pylist([f"s{i % 13}" * (i % 4) for i in range(n)], dt.STRING),
+            i32,
+        ]),
+        "validity": Table([
+            Column(dt.INT32, data=i32.data, validity=jnp.asarray(rng.random(n) > 0.3)),
+            i64,
+        ]),
+    }
+
+
+def _wire_case(reply_kind):
+    """(op, request bytes, the reply the plain reference encoding gives)."""
+    from spark_rapids_jni_tpu.ops.row_conversion import (
+        convert_from_rows,
+        convert_to_rows,
+    )
+
+    if reply_kind == "to_rows":
+        table = _wire_tables()["validity"]
+        return (
+            sidecar.OP_CONVERT_TO_ROWS,
+            _ref_write_table(table),
+            _ref_rows_reply(convert_to_rows(table)),
+        )
+    table = _wire_tables()[reply_kind]
+    dtypes = list(table.dtypes())
+    (rows,) = convert_to_rows(table)
+    return (
+        sidecar.OP_CONVERT_FROM_ROWS,
+        _from_rows_request(rows, dtypes),
+        _ref_write_table(convert_from_rows(rows, dtypes)),
+    )
+
+
+class _Wire:
+    """One raw connection to an in-process worker, speaking one of the
+    three transports at the level of frames: ``exchange`` returns the
+    reply's status, CRC trailer and body bytes exactly as they crossed."""
+
+    SIZE = 1 << 20
+    OFF = 4096  # where the slab transport puts its one region
+
+    def __init__(self, worker, transport):
+        import mmap
+
+        self.transport = transport
+        self.conn = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+        self.conn.connect(worker.sock_path)
+        self.mm = None
+        self.generation = 0
+        if transport != "stream":
+            import array
+
+            fd = os.memfd_create("issue27-arena")
+            os.ftruncate(fd, self.SIZE)
+            self.mm = mmap.mmap(fd, self.SIZE)
+            body = struct.pack("<Q", self.SIZE)
+            if transport == "region":
+                body += struct.pack("<Q", sidecar.ARENA_MODE_SLAB)
+            self.conn.sendmsg(
+                [struct.pack("<IQ", sidecar.OP_SET_ARENA, len(body)) + body],
+                [(socket.SOL_SOCKET, socket.SCM_RIGHTS,
+                  array.array("i", [fd]).tobytes())],
+            )
+            os.close(fd)
+            status, rlen = struct.unpack("<IQ", sidecar._recv_exact(self.conn, 12))
+            assert (status, rlen) == (sidecar.STATUS_OK, 0)
+
+    def exchange(self, op, payload):
+        crc = integrity.pack_crc(integrity.checksum(payload))
+        wire_op = op | sidecar.CRC_FLAG
+        if self.transport == "stream":
+            self.conn.sendall(struct.pack("<IQ", wire_op, len(payload)) + crc + payload)
+        elif self.transport == "arena":
+            self.mm[: len(payload)] = payload
+            self.conn.sendall(
+                struct.pack("<IQ", wire_op | sidecar.ARENA_FLAG, len(payload)) + crc
+            )
+        else:
+            self.generation += 1
+            cap = self.SIZE - self.OFF - sidecar.REGION_HDR_LEN
+            sidecar.REGION_HDR.pack_into(
+                self.mm, self.OFF, sidecar.REGION_MAGIC, self.generation, 7, cap,
+                len(payload),
+            )
+            at = self.OFF + sidecar.REGION_HDR_LEN
+            self.mm[at : at + len(payload)] = payload
+            desc = sidecar.REGION_DESC.pack(self.OFF, 7, self.generation)
+            self.conn.sendall(
+                struct.pack("<IQ", wire_op | sidecar.ARENA_FLAG, len(desc)) + crc + desc
+            )
+        status, rlen = struct.unpack("<IQ", sidecar._recv_exact(self.conn, 12))
+        assert status & sidecar.CRC_FLAG
+        trailer = sidecar._recv_exact(self.conn, 4)
+        if self.transport == "stream":
+            assert not status & sidecar.ARENA_FLAG
+            body = sidecar._recv_exact(self.conn, rlen) if rlen else b""
+        else:
+            assert status & sidecar.ARENA_FLAG, "the reply did not ride the arena"
+            at = 0 if self.transport == "arena" else self.OFF + sidecar.REGION_HDR_LEN
+            body = bytes(self.mm[at : at + rlen])
+        return status & ~sidecar._FLAG_MASK, trailer, body
+
+    def ping(self):
+        self.conn.sendall(struct.pack("<IQ", sidecar.OP_PING, 0))
+        status, rlen = struct.unpack("<IQ", sidecar._recv_exact(self.conn, 12))
+        if status & sidecar.ARENA_FLAG:  # the legacy arena answers through itself
+            return bytes(self.mm[:rlen])
+        return sidecar._recv_exact(self.conn, rlen)
+
+    def close(self):
+        self.conn.close()
+        if self.mm is not None:
+            self.mm.close()
+
+
+TRANSPORTS = ["stream", "arena", "region"]
+
+
+@pytest.fixture
+def inproc_worker():
+    w = _InProcWorker()
+    yield w
+    w.kill()
+
+
+class TestGatheredReply:
+    @pytest.mark.parametrize("transport", TRANSPORTS)
+    @pytest.mark.parametrize(
+        "reply_kind", ["to_rows", "fixed_width", "string", "validity"]
+    )
+    def test_wire_bytes_and_trailer_equal_the_joined_encoding(
+        self, inproc_worker, transport, reply_kind
+    ):
+        op, request, want = _wire_case(reply_kind)
+        wire = _Wire(inproc_worker, transport)
+        try:
+            status, trailer, body = wire.exchange(op, request)
+        finally:
+            wire.close()
+        assert status == sidecar.STATUS_OK, body
+        assert body == want
+        assert trailer == integrity.pack_crc(integrity.checksum(want))
+        # the reply reached reply() as pieces, and nothing joined it
+        assert _counter("sidecar.worker.reply.gathered_bytes") == len(want)
+        assert _counter("sidecar.worker.reply.joined_bytes") == 0
+
+    @pytest.mark.parametrize("pieces", [
+        [b"abc"],
+        [b"", b"abc"],
+        [b"ab", b"", b"c" * 70000, b""],
+        [struct.pack("<I", 3), np.arange(5, dtype=np.int32), np.zeros(0, np.uint8),
+         np.arange(12, dtype=np.uint32).reshape(3, 4)],
+    ], ids=["one", "empty_first", "empty_between_and_last", "host_arrays"])
+    def test_running_crc_over_pieces_is_the_crc_of_the_joined_bytes(self, pieces):
+        reply = sidecar.ReplyPieces(pieces)
+        joined = b"".join(
+            p.tobytes() if isinstance(p, np.ndarray) else p for p in pieces
+        )
+        assert reply.tobytes() == joined and len(reply) == len(joined)
+        crc = 0
+        for piece in sidecar.pieces_of(reply):
+            crc = integrity.checksum(piece, crc)
+        assert crc == integrity.checksum(joined)
+        # a plain bytes reply is the one-piece case of the same walk
+        assert sidecar.pieces_of(joined) == [joined]
+        assert sidecar.as_bytes(joined) is joined
+
+    @pytest.mark.parametrize("via", ["stream", "region"])
+    def test_corrupt_fault_on_a_gathered_reply_fails_verify_and_heals(self, via):
+        op, request, want = _wire_case("to_rows")
+        pool = sidecar_pool.SidecarPool(
+            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+        )
+        region = None
+        try:
+            if via == "region":
+                region = pool.lease(max(len(request), len(want)))
+                region.write(request)
+            faultinj.configure(
+                {"seed": 27, "faults": {"sidecar.worker.CONVERT_TO_ROWS": {
+                    "type": "corrupt", "percent": 100, "interceptionCount": 1}}}
+            )
+            before = _counter("sidecar.integrity.crc_mismatch")
+            client = pool._workers[0].client
+            with pytest.raises(DataCorruption) as ei:
+                client.request(op, b"" if region else request, region=region)
+            assert isinstance(ei.value, RetryableError)
+            assert _counter("sidecar.integrity.crc_mismatch") == before + 1
+            # budget spent: armed with retry, the same call now heals
+            faultinj.configure(
+                {"seed": 27, "faults": {"sidecar.worker.CONVERT_TO_ROWS": {
+                    "type": "corrupt", "percent": 100, "interceptionCount": 1}}}
+            )
+            with retry.enabled(max_attempts=4, base_delay_ms=1):
+                got = pool.call(op, b"" if region else request, region=region)
+            assert got == want
+            assert retry.stats()["retries"] >= 1
+        finally:
+            if region is not None:
+                region.release()
+            pool.shutdown()
+            sidecar.breaker().reset()
+
+
+class TestKeptRequestBuffer:
+    @pytest.mark.parametrize("transport", TRANSPORTS)
+    def test_large_small_large_on_one_connection(self, inproc_worker, transport):
+        """The buffer grows once, to the largest payload, and is reused
+        after; every answer is right, and the first, held by the caller,
+        is what it was after the buffer has been overwritten twice."""
+        large, small = _groupby_payload(n=5000, seed=1), _groupby_payload(n=40, seed=2)
+        large2 = _groupby_payload(n=5000, seed=3)
+        wants = [
+            sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, p, "cpu")
+            for p in (large, small, large2)
+        ]
+        host0 = memgov.catalog().kind_stats("scratch")
+        wire = _Wire(inproc_worker, transport)
+        try:
+            # SET_ARENA's own payload (8 or 16 bytes) made the buffer
+            # grow once already on the shared-memory transports
+            grows = _counter("sidecar.worker.scratch.grows")
+            reuses = _counter("sidecar.worker.scratch.reuses")
+            answers, steps = [], []
+            for payload in (large, small, large2):
+                status, _trailer, body = wire.exchange(
+                    sidecar.OP_GROUPBY_SUM_F32, payload
+                )
+                assert status == sidecar.STATUS_OK
+                answers.append(body)
+                steps.append((
+                    _counter("sidecar.worker.scratch.grows") - grows,
+                    _counter("sidecar.worker.scratch.reuses") - reuses,
+                ))
+            assert steps == [(1, 0), (1, 1), (1, 2)]
+            assert answers == wants
+            # host memory the worker now holds between requests: one
+            # entry of the largest payload's size, seen by the governor
+            n, nbytes = memgov.catalog().kind_stats("scratch")
+            assert (n - host0[0], nbytes - host0[1]) == (1, len(large))
+            # an empty payload reads nothing and counts nothing
+            assert wire.ping() == b"cpu"
+            assert _counter("sidecar.worker.scratch.reuses") - reuses == 2
+        finally:
+            wire.close()
+        deadline = time.monotonic() + 5
+        while memgov.catalog().kind_stats("scratch") != host0:
+            assert time.monotonic() < deadline, "scratch entry outlived its connection"
+            time.sleep(0.01)  # the handler's finally unregisters
+
+    @pytest.mark.parametrize("who", ["client", "pool"])
+    def test_host_fallback_still_returns_bytes(self, who):
+        op, request, want = _wire_case("to_rows")
+        assert isinstance(sidecar._dispatch(op, request, "cpu"), sidecar.ReplyPieces)
+        try:
+            if who == "client":
+                client = sidecar.SupervisedClient(
+                    tempfile.mktemp(prefix="srjt-nobody-") + ".sock",
+                    deadline_s=2, heartbeat_s=1e9,
+                )
+                with retry.enabled(max_attempts=2, base_delay_ms=1):
+                    got = client.call(op, request)
+                assert client.host_fallbacks == 1
+            else:
+                pool = sidecar_pool.SidecarPool(
+                    size=1, deadline_s=5, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+                )
+                try:
+                    pool._respawn_max = 0
+                    pool._workers[0].proc.kill()
+                    with retry.enabled(max_attempts=2, base_delay_ms=1):
+                        got = pool.call(op, request)
+                finally:
+                    pool.shutdown()
+        finally:
+            sidecar.breaker().reset()
+        assert type(got) is bytes and got == want
