@@ -596,8 +596,8 @@ def phase_c(args) -> dict:
     def device_ids(table):
         return sorted({d.id for c in table.columns for d in c.data.sharding.device_set})
 
-    # TPC-DS q95: two group-bys and two membership joins, every one
-    # shuffled; against pandas. (Not against tpcds.q95 in this process:
+    # TPC-DS q95 as a compiled plan over the mesh (plan.compile_ir(..., mesh=)):
+    # three exchanges, two shard-local group-bys and two membership joins; against pandas. (Not against tpcds.q95 in this process:
     # cold, that op-tier twin alone ran past 580 s on the chip without
     # finishing; tests/test_table_ops.py holds the two equal on the CPU.)
     web = tpcds.gen_web(sz["web"], seed=args.seed)
@@ -615,7 +615,8 @@ def phase_c(args) -> dict:
                                rtol=1e-9, err_msg="q95 on the mesh vs pandas")
     note(f"q95_distributed equals pandas: {got_q} in {timings['q95_cold_s']:.1f}s")
 
-    # Table-level GROUP BY across the mesh vs. the single-device operator
+    # Table-level GROUP BY across the mesh (the same sharded layer, end to end:
+    # place, exchange, shard-local group-by, gather) vs. the single-device operator
     fact = tpcds.gen_store(sz["store"], seed=args.seed)["store_sales"]
     sharded = shard_table_rows(fact, mesh)
     aggs = [("ss_ext_sales_price", "sum", "ss_ext_sales_price_sum"),

@@ -19,9 +19,7 @@ import numpy as np
 from ..columnar import Column, Table
 from ..columnar import dtype as dt
 from ..ops import bitutils, copying
-from ..ops.aggregate import groupby_aggregate
 from ..ops.expressions import col, lit
-from ..ops.join import left_semi_join
 from ..ops.sort import sort_by_key
 
 __all__ = [
@@ -885,70 +883,41 @@ def _q98_pipeline(n_cats: int, n_brands: int, month: int, year: int):
 
 
 def _q95_family(tables: Dict[str, Table], returns_how: str, ship_lo: int, ship_hi: int, mesh=None) -> dict:
-    """Shared plan of TPC-DS q95 (EXISTS returns) and q94 (NOT EXISTS
-    returns): per-order multi-warehouse detection, ship-date filter,
-    semi-join on the multi-warehouse set, then a semi (q95) or anti
-    (q94) join on returned orders, per-order sums, exact totals. One
-    definition so the four entry points cannot drift. ``mesh=None``
-    runs single-chip ops; a mesh routes every exchange-bearing step
-    through the distributed Table operators (results must be identical
-    — the distributed tests pin it)."""
-    ws = tables["web_sales"]
+    """TPC-DS q95 (EXISTS returns) and q94 (NOT EXISTS returns) as ONE
+    plan, so the four entry points cannot drift: per-order multi-warehouse
+    detection, ship-date filter, semi-join on the multi-warehouse set, then
+    a semi (q95) or anti (q94) join on returned orders, per-order sums,
+    exact totals. ``mesh=None`` compiles it for one chip; over a mesh both
+    fact tables are row-sharded, ``web_sales`` is shuffled once on the
+    order number for the warehouse group-by, the filtered line items and
+    ``web_returns`` once each to meet it (``plan.insert_exchanges``), and
+    the two membership joins and the per-order aggregate run where the
+    rows lie (results must be identical — the distributed tests pin it)."""
+    from .. import plan as P
 
-    if mesh is None:
-        per_order = groupby_aggregate(
-            ws.select(["ws_order_number"]),
-            ws.select(["ws_warehouse_sk"]),
-            [("ws_warehouse_sk", "min"), ("ws_warehouse_sk", "max")],
-        )
-    else:
-        from ..parallel.table_ops import distributed_groupby_table
-
-        per_order, ovf = distributed_groupby_table(
-            ws, ["ws_order_number"],
-            [("ws_warehouse_sk", "min", "ws_warehouse_sk_min"),
-             ("ws_warehouse_sk", "max", "ws_warehouse_sk_max")],
-            mesh,
-        )
-        if ovf:
-            raise RuntimeError("groupby capacity overflow — raise group_capacity")
-    multi = (col("ws_warehouse_sk_min") != col("ws_warehouse_sk_max")).evaluate(per_order)
-    ws_wh = copying.apply_boolean_mask(per_order, multi).select(["ws_order_number"])
-
-    wr = tables["web_returns"]
-    wr_keys = Table(wr.select(["wr_order_number"]).columns, ["ws_order_number"])
-
-    pred = (
-        (col("ws_ship_date_sk") >= lit(np.int32(ship_lo)))
-        & (col("ws_ship_date_sk") <= lit(np.int32(ship_hi)))
-    ).evaluate(ws)
-    ws1 = copying.apply_boolean_mask(ws, pred)
-    if mesh is None:
-        from ..ops.join import left_anti_join
-
-        ws1 = left_semi_join(ws1, ws_wh, on=["ws_order_number"])
-        join2 = left_anti_join if returns_how == "left_anti" else left_semi_join
-        ws1 = join2(ws1, wr_keys, on=["ws_order_number"])
-        per = groupby_aggregate(
-            ws1.select(["ws_order_number"]),
-            ws1.select(["ws_ext_ship_cost", "ws_net_profit"]),
-            [("ws_ext_ship_cost", "sum"), ("ws_net_profit", "sum")],
-        )
-    else:
-        from ..parallel.table_ops import distributed_groupby_table, distributed_join_table
-
-        ws1, o1 = distributed_join_table(ws1, ws_wh, on=["ws_order_number"], mesh=mesh, how="left_semi")
-        ws1, o2 = distributed_join_table(ws1, wr_keys, on=["ws_order_number"], mesh=mesh, how=returns_how)
-        if o1 or o2:
-            raise RuntimeError("join capacity overflow — raise capacity")
-        per, o3 = distributed_groupby_table(
-            ws1, ["ws_order_number"],
-            [("ws_ext_ship_cost", "sum", "ws_ext_ship_cost_sum"),
-             ("ws_net_profit", "sum", "ws_net_profit_sum")],
-            mesh,
-        )
-        if o3:
-            raise RuntimeError("groupby capacity overflow — raise group_capacity")
+    sharded = ("web_sales", "web_returns")
+    ws_wh = P.Aggregate(P.Scan("web_sales", columns=("ws_order_number", "ws_warehouse_sk")),
+                        keys=("ws_order_number",),
+                        aggs=(P.AggSpec("ws_warehouse_sk", "min", "wh_lo"),
+                              P.AggSpec("ws_warehouse_sk", "max", "wh_hi")))
+    ws_wh = P.Project(P.Filter(ws_wh, P.pcol("wh_lo") != P.pcol("wh_hi")),
+                      (("ws_order_number", P.pcol("ws_order_number")),))
+    ws1 = P.Filter(P.Scan("web_sales", columns=("ws_order_number", "ws_ship_date_sk",
+                                                "ws_ext_ship_cost", "ws_net_profit")),
+                   (P.pcol("ws_ship_date_sk") >= P.plit(np.int32(ship_lo)))
+                   & (P.pcol("ws_ship_date_sk") <= P.plit(np.int32(ship_hi))))
+    ws1 = P.Join(ws1, ws_wh, on=(("ws_order_number", "ws_order_number"),), how="semi")
+    ws1 = P.Join(ws1, P.Scan("web_returns", columns=("wr_order_number",)),
+                 on=(("ws_order_number", "wr_order_number"),),
+                 how="anti" if returns_how == "left_anti" else "semi")
+    plan = P.Aggregate(ws1, keys=("ws_order_number",),
+                       aggs=(P.AggSpec("ws_ext_ship_cost", "sum", "ws_ext_ship_cost_sum"),
+                             P.AggSpec("ws_net_profit", "sum", "ws_net_profit_sum")))
+    binding = None
+    if mesh is not None:
+        binding = P.MeshBinding(mesh, sharded, axis=mesh.axis_names[-1])
+        plan = P.insert_exchanges(plan, binding.world, sharded=sharded)
+    per = P.compile_ir(plan, {t: tables[t] for t in sharded}, name="q95_family", mesh=binding)()
     return {
         "order_count": int(per.num_rows),
         "total_shipping_cost": _exact_total(per.column("ws_ext_ship_cost_sum")),
@@ -963,7 +932,7 @@ def q94(tables: Dict[str, Table], ship_lo: int = 400, ship_hi: int = 460) -> dic
 
 
 def q94_distributed(tables: Dict[str, Table], mesh, ship_lo: int = 400, ship_hi: int = 460) -> dict:
-    """q94 over the distributed Table operators; identical to
+    """q94 compiled for ``mesh``, as ``q95_distributed``; identical to
     single-chip ``q94`` (pinned by test)."""
     return _q95_family(tables, "left_anti", int(ship_lo), int(ship_hi), mesh=mesh)
 
@@ -988,9 +957,9 @@ def q95(tables: Dict[str, Table], ship_lo: int = 400, ship_hi: int = 460) -> dic
 
 
 def q95_distributed(tables: Dict[str, Table], mesh, ship_lo: int = 400, ship_hi: int = 460) -> dict:
-    """q95 on the Table-level distributed operators (parallel/table_ops):
-    the same plan with every exchange-bearing step — both groupbys and
-    both membership joins — running as shuffled shard_map programs over
-    the mesh. Must produce results identical to single-chip ``q95``."""
+    """q95 compiled for ``mesh`` (``plan.compile_ir(..., mesh=)``): the same
+    plan, its fact tables row-sharded, three Exchange stages as all-to-alls
+    and the group-bys and membership joins where the rows then lie. Must
+    produce results identical to single-chip ``q95``."""
     return _q95_family(tables, "left_semi", int(ship_lo), int(ship_hi), mesh=mesh)
 

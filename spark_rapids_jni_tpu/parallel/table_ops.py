@@ -48,6 +48,7 @@ from ..columnar import dtype as dt
 from ..columnar.dtype import TypeId
 from ..ops import bitutils
 from ..ops.hashing import murmur3_raw
+from ..utils import metrics, tracing
 from ..utils.dispatch import op_boundary
 from ..utils.errors import FatalDeviceError
 from .distributed import _hash_dest_multi
@@ -62,7 +63,30 @@ __all__ = [
     "exchange_table",
     "distributed_groupby_table",
     "distributed_join_table",
+    "ShardedTable",
+    "ExchangeOverflow",
+    "shard_table",
+    "replicate_table",
+    "exchange_sharded",
+    "groupby_sharded",
+    "join_sharded",
+    "gather_table",
 ]
+
+
+def _exchange_counter(name: str):
+    """``exchange.<name>``, registry-direct as the plan tier's counters
+    are: an overflow has to be countable in a run that records nothing
+    else."""
+    return metrics.registry().counter(f"exchange.{name}")
+
+
+def _count_exchange(rows_in: int, lane_bytes: int, programs: int = 1) -> None:
+    """One all-to-all program (or one more side of it): the rows that
+    entered and the bytes of their lanes."""
+    _exchange_counter("programs").inc(programs)
+    _exchange_counter("rows_in").inc(rows_in)
+    _exchange_counter("bytes_offered").inc(rows_in * lane_bytes)
 
 
 def default_capacity(per_shard: int, n_parts: int) -> int:
@@ -174,152 +198,48 @@ def _rebuild(meta, data, validity) -> Column:
     return Column(aux, data=data, validity=validity)
 
 
+def _encoded(table: Table):
+    """STRING columns as their dictionary codes (INT32 lanes ride an
+    exchange; the dictionaries stay where they are), and the dictionaries
+    that bring them back."""
+    cols, dicts = [], {}
+    for name, c in zip(table.names, table.columns):
+        if c.dtype.id == TypeId.STRING:
+            c, dicts[name] = dict_encode(c)
+        elif c.dtype.id in (TypeId.LIST, TypeId.STRUCT):
+            raise ValueError("nested columns: exchange leaf lanes individually")
+        cols.append(c)
+    return Table(cols, list(table.names)), dicts
+
+
+def _decoded(table: Table, dicts) -> Table:
+    cols = [dict_decode(c.data, dicts[name], validity=c.validity) if name in dicts else c
+            for name, c in zip(table.names, table.columns)]
+    return Table(cols, list(table.names))
+
+
 @op_boundary("exchange_table")
-def exchange_table(
-    table: Table,
-    key_cols: Sequence[str],
-    mesh: Mesh,
-    axis: str = "data",
-    capacity: Optional[int] = None,
-) -> Tuple[Table, bool]:
-    """Hash-repartition a row-sharded Table (strings included) over the
-    mesh; returns the received rows as a compacted global Table plus the
-    overflow flag. Rows of equal key tuples land on one shard."""
-    n_parts = mesh.shape[axis]
-    n = table.num_rows
-
-    lanes: List[jnp.ndarray] = []
-    metas = []
-    has_v: List[bool] = []
-    lane_pos: List[int] = []  # data-lane index per column
-    for c in table.columns:
-        data, validity, meta = _col_lanes(c)
-        lane_pos.append(len(lanes))
-        lanes.append(data)
-        metas.append(meta)
-        has_v.append(validity is not None)
-        if validity is not None:
-            lanes.append(validity)
-
-    lanes, present = _pad_lanes(lanes, n, n_parts)
-    per_shard = present.shape[0] // n_parts
-    if capacity is None:
-        capacity = default_capacity(per_shard, n_parts)
-
-    # memory tier: refuse buffer footprints past the device budget
-    # BEFORE dispatch (retryable — the caller splits or the task
-    # re-runs), instead of letting XLA OOM with a possibly poisoned
-    # client (utils/memory.py)
-    from ..utils.memory import (
-        MemoryBudgetExceeded,
-        device_memory_budget,
-        exchange_bytes_estimate,
-    )
-
-    row_bytes = 8 * len(lanes)  # flat upper bound: every lane <= 8B
-    est = exchange_bytes_estimate(row_bytes, n_parts, int(capacity))
-    budget = device_memory_budget()
-    if est > budget:
-        raise MemoryBudgetExceeded(
-            f"exchange at capacity {capacity} needs ~{est} device bytes "
-            f"(budget {budget}); split the batch or lower the capacity"
-        )
-
-    # keys are derived INSIDE the body from the payload lanes at these
-    # positions (no duplicate key operands through shard_map); null
-    # rows' garbage data is masked to 0 so every null key hashes
-    # identically, and the validity lane joins the hash chain so null
-    # keys co-locate
-    key_pos = []
-    for k in key_cols:
-        ki = table.names.index(k)
-        key_pos.append((lane_pos[ki], lane_pos[ki] + 1 if has_v[ki] else None))
-
-    def body(*arrs):
-        pres, payload = arrs[0], arrs[1:]
-        ks = []
-        for dpos, vpos in key_pos:
-            data = payload[dpos]
-            if vpos is not None:
-                validity = payload[vpos]
-                ks.append(jnp.where(validity, data, jnp.zeros((), data.dtype)))
-                ks.append(validity.astype(jnp.int32))
-            else:
-                ks.append(data)
-        dest = _hash_dest_multi(ks, n_parts)
-        a2a = lambda x: lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
-        outs = []
-        ovf = jnp.zeros((), bool)
-        mask = None
-        for a in (pres,) + tuple(payload):
-            b, m, o = _bucketize(a, dest, n_parts, capacity)
-            outs.append(a2a(b).reshape((-1,) + a.shape[1:]))
-            ovf = ovf | o
-            mask = m
-        rm = a2a(mask).reshape(-1) & outs[0]  # occupied AND real row
-        return tuple(outs[1:]) + (rm, ovf[None])
-
-    spec = P(axis)
-    f = cached_sm(
-        ("exchange_table", mesh, axis, int(capacity), len(lanes),
-         tuple(str(a.dtype) for a in lanes),
-         tuple(key_pos), tuple(has_v)),  # body statics: which lanes hash as keys
-        lambda: jax.jit(shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(spec,) * (1 + len(lanes)),
-            out_specs=(spec,) * (len(lanes) + 2),
-        )),
-    )
-    *received, recv_mask, ovf = f(present, *lanes)
-
-    # compact received slots (host boundary of the eager op tier)
-    keep = np.asarray(recv_mask)
-    sel = jnp.asarray(np.flatnonzero(keep))
-    cols = []
-    it = iter(received)
-    for meta, nullable in zip(metas, has_v):
-        data = next(it)[sel]
-        validity = next(it)[sel] if nullable else None
-        cols.append(_rebuild(meta, data, validity))
-    return Table(cols, names=list(table.names)), bool(np.asarray(ovf).any())
+def exchange_table(table: Table, key_cols: Sequence[str], mesh: Mesh, axis: str = "data") -> Tuple[Table, bool]:
+    """Hash-repartition a Table (strings included) over the mesh and hand
+    the received rows back as one compacted Table: the sharded layer below
+    from end to end (``shard_table`` -> ``exchange_sharded`` ->
+    ``gather_table``). Rows of equal key tuples met on one shard. The flag
+    is always False and stays for the callers that unpack it: a bucket
+    that overflows makes the exchange run again larger, inside."""
+    enc, dicts = _encoded(table)
+    out = gather_table(exchange_sharded(shard_table(enc, mesh, axis), key_cols))
+    return _decoded(out, dicts), False
 
 
 # ---------------------------------------------------------------------------
 # distributed groupby on Tables
 # ---------------------------------------------------------------------------
 
-_AGG_HOWS = ("sum", "count", "min", "max", "mean")
 
-
-def _value_lane(col: Column) -> jnp.ndarray:
-    """Aggregate-value lane. FLOAT64 stays in its u64 IEEE-bit storage —
-    the shard aggregator runs the EXACT windowed integer accumulator on
-    it (ops/f64acc), so distributed sums/means/extrema are bit-identical
-    to the single-chip exact path (no f32 hop; VERDICT r3 item 5)."""
-    return col.data
-
-
-def _shard_groupby_aggs(key_arrays, val_arrays, hows, present, val_present, capacity: int,
-                        f64_flags=None):
-    """Static-shape multi-aggregate groupby (shard-local). Returns
-    (key_arrays[capacity], agg_arrays, agg_valid_arrays, group_valid,
-    overflow). An aggregate over a group whose values are ALL null is
-    itself null (Spark) — agg_valid carries that; count is the
-    exception (0, always valid)."""
-    order = jnp.lexsort(tuple(reversed(list(key_arrays))) + (~present,))
-    ks = [k[order] for k in key_arrays]
-    ps = present[order]
-
-    changed = jnp.zeros((ks[0].shape[0] - 1,), bool)
-    for k in ks:
-        changed = changed | (k[1:] != k[:-1])
-    new_seg = jnp.concatenate([jnp.ones((1,), bool), changed]) & ps
-    seg = jnp.cumsum(new_seg).astype(jnp.int32) - 1
-    num_groups = jnp.maximum(seg[-1] + 1, 0)
-    overflow = num_groups > capacity
-    seg = jnp.where(ps, jnp.clip(seg, 0, capacity - 1), capacity)
-
+def _segment_aggs(val_arrays, hows, val_present, f64_flags, order, ps, seg, capacity: int):
+    """The aggregates of rows taken in ``order``: ``seg`` numbers each
+    row's group (``capacity`` for a row that is in none), ``ps`` marks the
+    rows that count. Returns (agg_arrays, agg_valid_arrays)."""
     if f64_flags is None:
         f64_flags = [False] * len(val_arrays)
     aggs = []
@@ -399,14 +319,8 @@ def _shard_groupby_aggs(key_arrays, val_arrays, hows, present, val_present, capa
                 aggs.append(f(x, seg, num_segments=capacity + 1)[:capacity])
             agg_valid.append(cnt > 0)
         else:
-            raise ValueError(f"unknown agg {how!r} (supported: {_AGG_HOWS})")
-
-    out_keys = [
-        jnp.zeros((capacity,), k.dtype).at[seg].set(kk, mode="drop")
-        for k, kk in zip(key_arrays, ks)
-    ]
-    group_valid = jnp.arange(capacity, dtype=jnp.int32) < num_groups
-    return out_keys, aggs, agg_valid, group_valid, overflow
+            raise ValueError(f"unknown agg {how!r} (supported: {_SHARDED_HOWS})")
+    return aggs, agg_valid
 
 
 @op_boundary("distributed_groupby_table")
@@ -416,52 +330,30 @@ def distributed_groupby_table(
     aggs: Sequence[Tuple[str, str, str]],  # (value_col, how, out_name)
     mesh: Mesh,
     axis: str = "data",
-    capacity: Optional[int] = None,
-    group_capacity: Optional[int] = None,
 ) -> Tuple[Table, bool]:
     """GROUP BY key_cols with multiple aggregates across the mesh —
-    Table in, compacted Table out (keys + one column per aggregate).
-    String keys group via dictionary codes and decode on the way out.
-    One compiled program end-to-end; host touches only the compaction.
-    Defaulted capacities recompute 4x larger on overflow (once).
-    """
-    for _v, how, _o in aggs:
-        if how not in _AGG_HOWS:
+    Table in, compacted Table out (keys + one column per aggregate): the
+    sharded layer below from end to end (``shard_table`` ->
+    ``exchange_sharded`` -> ``groupby_sharded`` -> ``gather_table``).
+    String keys group via dictionary codes and decode on the way out. An
+    exchange that would not fit the device budget, at its first capacity
+    or at the one a skewed key escalates to, splits the batch instead
+    (``_groupby_split_retry``: the reference's 2 GiB batching discipline).
+    The flag is always False and stays for the callers that unpack it."""
+    from ..utils.memory import MemoryBudgetExceeded
+
+    for v, how, _o in aggs:
+        if how not in _SHARDED_HOWS:
             raise ValueError(f"unknown agg {how!r}")
-    n_parts = mesh.shape[axis]
-    n_global = table.num_rows
-    per_shard = (n_global + n_parts - 1) // n_parts
-    auto = capacity is None and group_capacity is None
-    if capacity is None:
-        capacity = default_capacity(max(per_shard, 1), n_parts)
-    if group_capacity is None:
-        group_capacity = min(capacity * n_parts, max(per_shard, 64))
-    # memory tier guards the FIRST dispatch too: a batch whose default
-    # capacity already exceeds the budget must split, not OOM
-    from ..utils.memory import device_memory_budget, exchange_bytes_estimate
-
-    row_bytes = _exchange_row_bytes(table, key_cols, aggs)
-    if auto and exchange_bytes_estimate(row_bytes, n_parts, int(capacity)) > device_memory_budget():
+        if table.column(v).dtype.id == TypeId.STRING:
+            raise ValueError("aggregating STRING columns is not supported")
+    used = list(dict.fromkeys(list(key_cols) + [v for v, _h, _o in aggs]))  # only these ride the exchange
+    enc, dicts = _encoded(table.select(used))
+    try:
+        st = exchange_sharded(shard_table(enc, mesh, axis), key_cols)
+    except MemoryBudgetExceeded:
         return _groupby_split_retry(table, key_cols, aggs, mesh, axis)
-    out = _groupby_once(table, key_cols, aggs, mesh, axis, int(capacity), int(group_capacity))
-    if out[1] and auto:
-        capacity = max(per_shard, 1)
-        # same budget check for the escalated capacity: a skewed key
-        # must not grow buckets until XLA OOMs — split instead (the
-        # reference's 2 GiB batching discipline), merging partials
-        if exchange_bytes_estimate(row_bytes, n_parts, capacity) > device_memory_budget():
-            return _groupby_split_retry(table, key_cols, aggs, mesh, axis)
-        out = _groupby_once(
-            table, key_cols, aggs, mesh, axis, capacity, capacity * n_parts
-        )
-    return out
-
-
-def _exchange_row_bytes(table: Table, key_cols: Sequence[str], aggs) -> int:
-    """Bytes per exchanged row for the groupby shuffle: 8B upper bound
-    per lane, two lanes (data + possible validity) per key and per
-    aggregate value."""
-    return 16 * (len(key_cols) + len(aggs))
+    return _decoded(gather_table(groupby_sharded(st, key_cols, aggs)), dicts), False
 
 
 _MERGE_HOW = {"sum": "sum", "count": "sum", "count_all": "sum", "min": "min", "max": "max"}
@@ -504,17 +396,7 @@ def _groupby_split_retry(
     parts = []
     for lo, hi in ((0, mid), (mid, n)):
         half = slice_table(table, lo, hi)
-        out, ovf = distributed_groupby_table(half, key_cols, inner_aggs, mesh, axis=axis)
-        if ovf:
-            # a half that still overflows after its own escalation/split
-            # cannot produce the caller's schema from here — surface the
-            # retryable pressure instead of a partial with alien columns
-            from ..utils.memory import MemoryBudgetExceeded
-
-            raise MemoryBudgetExceeded(
-                "groupby split-retry: half-batch still overflows its capacity"
-            )
-        parts.append(out)
+        parts.append(distributed_groupby_table(half, key_cols, inner_aggs, mesh, axis=axis)[0])
 
     from ..ops.copying import concatenate
 
@@ -560,167 +442,6 @@ def _groupby_split_retry(
             out_cols.append(mcol)
         out_names.append(oname)
     return Table(out_cols, out_names), False
-
-
-
-def _groupby_once(
-    table: Table,
-    key_cols: Sequence[str],
-    aggs: Sequence[Tuple[str, str, str]],
-    mesh: Mesh,
-    axis: str,
-    capacity: int,
-    group_capacity: int,
-) -> Tuple[Table, bool]:
-    n_parts = mesh.shape[axis]
-    n_global = table.num_rows
-    cap_g = int(group_capacity)
-
-    # key lanes: data (+ validity as an extra lane so null keys form
-    # their own group and route to one shard)
-    key_metas = []
-    key_lanes: List[jnp.ndarray] = []
-    key_lane_of: List[Tuple[int, bool]] = []  # (lane index, is_validity)
-    for kname in key_cols:
-        col = table.column(kname)
-        data, validity, meta = _col_lanes(col)
-        key_metas.append(meta)
-        key_lane_of.append((len(key_lanes), validity is not None))
-        key_lanes.append(jnp.where(validity, data, jnp.zeros((), data.dtype)) if validity is not None else data)
-        if validity is not None:
-            key_lanes.append(validity.astype(jnp.int32))
-
-    val_lanes: List[jnp.ndarray] = []
-    val_valid: List[Optional[jnp.ndarray]] = []
-    hows: List[str] = []
-    f64_flags: List[bool] = []
-    out_meta: List[Tuple[str, str]] = []
-    for vname, how, oname in aggs:
-        col = table.column(vname)
-        if col.dtype.id == TypeId.STRING:
-            raise ValueError("aggregating STRING columns is not supported")
-        val_lanes.append(_value_lane(col))
-        val_valid.append(col.validity)
-        hows.append(how)
-        f64_flags.append(col.dtype.id == TypeId.FLOAT64)
-        out_meta.append((oname, how))
-    n_keys = len(key_lanes)
-    n_vals = len(val_lanes)
-    valid_lanes = [v for v in val_valid if v is not None]
-    all_lanes, present = _pad_lanes(
-        key_lanes + val_lanes + valid_lanes, n_global, n_parts
-    )
-    key_lanes = all_lanes[:n_keys]
-    val_lanes = all_lanes[n_keys : n_keys + n_vals]
-    valid_lanes = all_lanes[n_keys + n_vals :]
-
-    def body(*arrs):
-        ks = list(arrs[:n_keys])
-        pres = arrs[n_keys]
-        vs = list(arrs[n_keys + 1 : n_keys + 1 + n_vals])
-        vps = list(arrs[n_keys + 1 + n_vals :])
-        dest = _hash_dest_multi(ks, n_parts)
-        a2a = lambda x: lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
-        ovf = jnp.zeros((), bool)
-        kr = []
-        mask = None
-        for k in ks:
-            b, m, o = _bucketize(k, dest, n_parts, capacity)
-            kr.append(a2a(b).reshape(-1))
-            ovf, mask = ovf | o, m
-        pb, _, _ = _bucketize(pres, dest, n_parts, capacity)
-        pr = a2a(pb).reshape(-1)
-        vr = []
-        for v in vs:
-            b, _, _ = _bucketize(v, dest, n_parts, capacity)
-            vr.append(a2a(b).reshape(-1))
-        vpr = []
-        for vp in vps:
-            b, _, _ = _bucketize(vp, dest, n_parts, capacity)
-            vpr.append(a2a(b).reshape(-1))
-        mr = a2a(mask).reshape(-1) & pr
-        # re-thread optional validity lanes
-        vp_full: List[Optional[jnp.ndarray]] = []
-        j = 0
-        for orig in val_valid:
-            if orig is not None:
-                vp_full.append(vpr[j])
-                j += 1
-            else:
-                vp_full.append(None)
-        gks, gas, gavs, gv, ovf2 = _shard_groupby_aggs(
-            kr, vr, hows, mr, vp_full, cap_g, f64_flags=f64_flags
-        )
-        return (
-            tuple(gk[None] for gk in gks)
-            + tuple(a[None] for a in gas)
-            + tuple(av[None] for av in gavs)
-            + (gv[None], (ovf | ovf2)[None])
-        )
-
-    spec = P(axis)
-    f = cached_sm(
-        ("gb_table", mesh, axis, int(capacity), cap_g, n_keys, n_vals,
-         tuple(hows), tuple(f64_flags), tuple(v is not None for v in val_valid),
-         tuple(str(a.dtype) for a in key_lanes + val_lanes)),
-        lambda: jax.jit(shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(spec,) * (n_keys + 1 + n_vals + len(valid_lanes)),
-            out_specs=(spec,) * (n_keys + 2 * n_vals + 2),
-        )),
-    )
-    outs = f(*key_lanes, present, *val_lanes, *valid_lanes)
-    gks = outs[:n_keys]
-    gas = outs[n_keys : n_keys + n_vals]
-    gavs = outs[n_keys + n_vals : n_keys + 2 * n_vals]
-    gv = np.asarray(outs[n_keys + 2 * n_vals]).reshape(-1)
-    ovf = bool(np.asarray(outs[n_keys + 2 * n_vals + 1]).any())
-
-    sel = jnp.asarray(np.flatnonzero(gv))
-    cols: List[Column] = []
-    names: List[str] = []
-    li = 0
-    for kname, meta, (lane, nullable) in zip(key_cols, key_metas, key_lane_of):
-        data = jnp.asarray(gks[li]).reshape(-1)[sel]
-        li += 1
-        validity = None
-        if nullable:
-            validity = jnp.asarray(gks[li]).reshape(-1)[sel].astype(bool)
-            li += 1
-        cols.append(_rebuild(meta, data, validity))
-        names.append(kname)
-    sel_np = np.flatnonzero(gv)
-    # ONE host transfer for every aggregate's validity lane (K separate
-    # np.asarray pulls would block once per aggregate on a remote
-    # backend); nulls re-upload only for the rare all-null-group case
-    gavs_h = jax.device_get(list(gavs))
-    for (oname, how), g, gav_h, (vname, _h, _o) in zip(out_meta, gas, gavs_h, aggs):
-        arr = jnp.asarray(g).reshape(-1)[sel]
-        av_np = gav_h.reshape(-1)[sel_np]
-        validity = None if av_np.all() else jnp.asarray(av_np)
-        src = table.column(vname)
-        src_is_f64 = src.dtype.id == TypeId.FLOAT64
-        # exact paths return ready-made FLOAT64 IEEE bits: every agg of
-        # a FLOAT64 column, and the exact integer mean (mean_i64_div) —
-        # keyed off the COLUMN dtype, never the lane dtype (a genuine
-        # UINT64 min/max result is an integer that happens to be u64)
-        if (src_is_f64 and how in ("sum", "mean", "min", "max")) or (
-            how == "mean" and jnp.issubdtype(src.data.dtype, jnp.integer)
-        ):
-            cols.append(Column(dt.FLOAT64, data=arr, validity=validity))
-        elif how == "mean":
-            cols.append(Column(dt.FLOAT64, data=bitutils.float_store(arr, dt.FLOAT64), validity=validity))
-        elif how == "count":
-            cols.append(Column(dt.INT64, data=arr))
-        elif arr.dtype == jnp.uint64 and how == "sum":
-            cols.append(Column(dt.UINT64, data=arr, validity=validity))
-        elif jnp.issubdtype(arr.dtype, jnp.integer) and how == "sum":
-            cols.append(Column(dt.INT64, data=arr.astype(jnp.int64), validity=validity))
-        else:
-            cols.append(Column(src.dtype, data=arr, validity=validity))
-        names.append(oname)
-    return Table(cols, names=names), ovf
 
 
 # ---------------------------------------------------------------------------
@@ -780,12 +501,16 @@ def distributed_join_table(
         out_capacity = (
             max(per_l, 64) if how != "inner" else max(2 * max(per_l, per_r), 64)
         )
-    for _attempt in range(max_retries + 1):
+    for attempt in range(max_retries + 1):
         table, ovf = _join_once(
-            left, right, on, mesh, how, axis, int(capacity), int(out_capacity)
+            left, right, on, mesh, how, axis, int(capacity), int(out_capacity), attempt
         )
+        if ovf:
+            _exchange_counter("overflows").inc()
         if not ovf or not auto:
             return table, ovf
+        if attempt < max_retries:
+            _exchange_counter("capacity_retries").inc()
         capacity = min(capacity * 4, max(per_l, per_r, 1))
         out_capacity *= 4
     return table, ovf
@@ -800,6 +525,7 @@ def _join_once(
     axis: str,
     capacity: int,
     out_capacity: int,
+    attempt: int = 0,
 ) -> Tuple[Table, bool]:
     n_parts = mesh.shape[axis]
     cap_out = int(out_capacity)
@@ -939,7 +665,11 @@ def _join_once(
             body, mesh=mesh, in_specs=(spec,) * len(in_lanes), out_specs=(spec,) * n_out
         )),
     )
-    outs = f(*in_lanes)
+    with tracing.span("exchange.join", rows_in=left.num_rows + right.num_rows, keys=list(on),
+                      capacity=int(capacity), parts=n_parts, attempt=attempt, how=how):
+        outs = f(*in_lanes)
+    _count_exchange(left.num_rows, sum(int(a.dtype.itemsize) for a in l_lanes))
+    _count_exchange(right.num_rows, sum(int(a.dtype.itemsize) for a in r_lanes), programs=0)
     ovf = bool(np.asarray(outs[-1]).any())
     keep = np.asarray(outs[-3])
     sel = jnp.asarray(np.flatnonzero(keep))
@@ -966,3 +696,452 @@ def _join_once(
             names.append(nm if nm not in names else f"{nm}_right")
             cols.append(c)
     return Table(cols, names=names), ovf
+
+
+# ---------------------------------------------------------------------------
+# the sharded layer: Tables that STAY laid out over the mesh between stages
+# ---------------------------------------------------------------------------
+#
+# ``exchange_table`` and ``distributed_groupby_table`` above are this
+# layer from end to end: they place a Table, run one stage and hand back a
+# compacted Table, so whatever partitioning the exchange established is
+# gone with the compaction. A plan compiled for a mesh (plan/compiler.py
+# under a ``plan.distribute.MeshBinding``) keeps its fact tables in the
+# layout below from the scan to the last keyed stage: one exchange
+# establishes a partitioning, and the group-bys and joins on the same key
+# after it run shard-local, with no collective. ``distributed_join_table``
+# is another operator, not this layer's twin: it shuffles BOTH sides on a
+# composite key and pairs many rows to many; ``join_sharded`` probes one
+# integer key where the rows already lie.
+
+
+class ExchangeOverflow(RuntimeError):
+    """A destination bucket overflowed at the largest capacity there is:
+    raised, never answered with rows missing."""
+
+
+class ShardedTable:
+    """Fixed-width columns as global arrays of ``world x L`` slots,
+    row-sharded over ``axis``; ``present`` marks the slots that hold a
+    row (padding, filtered-out rows and empty bucket slots do not).
+    ``part`` names the columns on which equal keys are known to share a
+    shard (``()``: rows lie where the file order or a filter left them);
+    ``ordered`` a column whose values ascend over the slots of every shard,
+    absent slots included, and are distinct where present (a group-by's
+    key: a probe of it needs no sort). STRING columns do not ride here:
+    they stay on replicated tables."""
+
+    __slots__ = ("table", "present", "mesh", "axis", "part", "ordered")
+
+    def __init__(self, table: Table, present, mesh: Mesh, axis: str = "data",
+                 part: Tuple[str, ...] = (), ordered: Optional[str] = None):
+        self.table, self.present, self.mesh, self.axis = table, present, mesh, axis
+        self.part, self.ordered = tuple(part), ordered
+
+    @property
+    def names(self) -> List[str]:
+        return self.table.names
+
+    @property
+    def num_rows(self) -> int:
+        """Slots, not rows: counting the rows would wait for the device."""
+        return int(self.present.shape[0])
+
+    @property
+    def n_parts(self) -> int:
+        return self.mesh.shape[self.axis]
+
+    def column(self, name) -> Column:
+        return self.table.column(name)
+
+    def select(self, names) -> "ShardedTable":
+        names = list(names)
+        return ShardedTable(self.table.select(names), self.present, self.mesh, self.axis,
+                            self.part if set(self.part) <= set(names) else (),
+                            self.ordered if self.ordered in names else None)
+
+    def replace(self, table: Table = None, present=None, part=None) -> "ShardedTable":
+        """Other columns over the same slots, or fewer rows present: the
+        slots keep their order, so what is known of it holds for every
+        column that is carried over as it was."""
+        ordered = self.ordered
+        if table is not None and ordered is not None:
+            kept = ordered in table.names and table.column(ordered).data is self.column(ordered).data
+            ordered = ordered if kept else None
+        return ShardedTable(self.table if table is None else table,
+                            self.present if present is None else present,
+                            self.mesh, self.axis, self.part if part is None else part, ordered)
+
+
+@op_boundary("shard_table")
+def shard_table(table: Table, mesh: Mesh, axis: str = "data") -> ShardedTable:
+    """Place a Table row-sharded in file order: shard i holds rows
+    [i*L, (i+1)*L), the tail padded with absent slots."""
+    from .mesh import row_sharding
+
+    n_parts, n = mesh.shape[axis], table.num_rows
+    with tracing.span("exchange.place", rows=n, parts=n_parts, how="sharded",
+                      cols=table.num_columns):
+        sh = row_sharding(mesh, axis)
+        for c in table.columns:
+            if not c.dtype.is_fixed_width:
+                raise ValueError(f"a {c.dtype!r} column cannot be row-sharded: keep its table replicated")
+        lanes, spots = _lanes_of(table)
+        lanes, present = _pad_lanes(lanes, n, n_parts)
+        if n == 0:  # one absent slot a shard: no program is traced over no slots at all
+            lanes = [jnp.zeros((n_parts,) + a.shape[1:], a.dtype) for a in lanes]
+            present = jnp.zeros((n_parts,), bool)
+        lanes = [jax.device_put(a, sh) for a in lanes]
+        return ShardedTable(_table_from(table, spots, lanes), jax.device_put(present, sh), mesh, axis)
+
+
+@op_boundary("replicate_table")
+def replicate_table(table: Table, mesh: Mesh) -> Table:
+    """A whole copy of a (small) Table on every chip of the mesh."""
+    from .mesh import replicated
+
+    with tracing.span("exchange.place", rows=table.num_rows, parts=int(mesh.size), how="replicated",
+                      cols=table.num_columns):
+        return jax.device_put(table, replicated(mesh))
+
+
+def _tight_capacity(per_shard: int, n_parts: int) -> int:
+    """First-try bucket capacity of the sharded exchange: half again the
+    even share. A hash spreads keys to within a fraction of a percent at
+    these sizes; a skewed key overflows, and the exchange runs again
+    larger (up to ``per_shard``, which cannot overflow)."""
+    return min(per_shard, max(3 * ((per_shard + n_parts - 1) // n_parts) // 2, 64))
+
+
+def _lanes_of(table: Table):
+    """The arrays of a Table of fixed-width columns in one list (a column's
+    validity right behind its data), and where each column's are."""
+    lanes, spots = [], []
+    for c in table.columns:
+        spots.append((len(lanes), c.validity is not None))
+        lanes.append(c.data)
+        if c.validity is not None:
+            lanes.append(c.validity)
+    return lanes, spots
+
+
+def _table_from(like: Table, spots, lanes) -> Table:
+    """``_lanes_of`` undone, over other arrays of the same kinds."""
+    return Table([Column(c.dtype, data=lanes[i], validity=lanes[i + 1] if v else None)
+                  for c, (i, v) in zip(like.columns, spots)], list(like.names))
+
+
+def _route(dest, present, n_parts: int, capacity: int):
+    """Where each bucket slot reads from: rows sorted by destination
+    (absent rows last, bound for nowhere), bucket p slot s <- the s-th
+    row of run p. Gathers only. Returns (src[n_parts, capacity],
+    filled[n_parts, capacity], overflow)."""
+    n = dest.shape[0]
+    d = jnp.where(present, dest.astype(jnp.int32), jnp.int32(n_parts))
+    order = jnp.argsort(d, stable=False)  # which row of a run lands in which slot of its bucket is free
+    start = jnp.searchsorted(d[order], jnp.arange(n_parts + 1, dtype=jnp.int32), side="left").astype(jnp.int32)
+    count = start[1:] - start[:-1]
+    slot = jnp.arange(capacity, dtype=jnp.int32)[None, :]
+    src = order[jnp.clip(start[:-1, None] + slot, 0, n - 1)]
+    return src, slot < count[:, None], jnp.any(count > capacity)
+
+
+@op_boundary("exchange_sharded")
+def exchange_sharded(st: ShardedTable, key_cols: Sequence[str]) -> ShardedTable:
+    """Hash-repartition on ``key_cols`` with one all-to-all a lane: rows
+    of equal keys end on one shard, and the result says so (``part``).
+    A bucket that overflows its first-try capacity makes the whole
+    exchange run again at four times the capacity (counted in
+    ``exchange.capacity_retries``), up to ``per_shard``, which no bucket
+    can overflow: a row is never dropped."""
+    n_parts = st.n_parts
+    per_shard = st.num_rows // n_parts
+    cap = _tight_capacity(per_shard, n_parts)
+    attempt = 0
+    while True:
+        with tracing.span("exchange.table", keys=list(key_cols), capacity=cap, parts=n_parts,
+                          attempt=attempt) as sp:
+            out, rows_in, lane_bytes, ovf = _exchange_sharded_once(st, key_cols, cap)
+            sp.annotate(rows_in=rows_in)
+        _count_exchange(rows_in, lane_bytes)
+        if not ovf:
+            return out
+        _exchange_counter("overflows").inc()
+        if cap >= per_shard:  # a shard has no more rows than that to send: the program is at fault
+            raise ExchangeOverflow(f"exchange on {list(key_cols)}: a bucket of {cap} slots overflowed")
+        cap = min(per_shard, cap * 4)
+        attempt += 1
+        _exchange_counter("capacity_retries").inc()
+
+
+def _key_lanes(spots, names, key_cols):
+    """(data lane, validity lane or None) of each key: a key routes by
+    its data with NULLs masked to zero, so every NULL routes alike,
+    whatever the column's nullability on the other side of a join."""
+    out = []
+    for k in key_cols:
+        i, v = spots[names.index(k)]
+        out.append((i, i + 1 if v else None))
+    return out
+
+
+def _exchange_sharded_once(st: ShardedTable, key_cols, capacity: int):
+    from ..utils.memory import MemoryBudgetExceeded, device_memory_budget, exchange_bytes_estimate
+
+    mesh, axis, n_parts = st.mesh, st.axis, st.n_parts
+    lanes, spots = _lanes_of(st.table)
+    lane_bytes = sum(int(np.dtype(a.dtype).itemsize) * int(np.prod(a.shape[1:], dtype=np.int64)) for a in lanes)
+    est = exchange_bytes_estimate(lane_bytes + 5, n_parts, capacity)  # a slot: its lanes, a 4-byte route index, a flag
+    if est > device_memory_budget():
+        raise MemoryBudgetExceeded(
+            f"exchange at capacity {capacity} needs ~{est} device bytes a chip "
+            f"(budget {device_memory_budget()}); split the batch or lower the capacity")
+    kpos = _key_lanes(spots, st.names, key_cols)
+
+    def exchange_program(pres, *payload):
+        ks = []
+        for dpos, vpos in kpos:
+            data = payload[dpos]
+            if jnp.issubdtype(data.dtype, jnp.integer):
+                # an integer routes by its VALUE, whatever its width (an INT32 would hash as one
+                # block, an INT64 as two): the two sides of a join then agree on the shard
+                data = data.astype(jnp.int64)
+            ks.append(data if vpos is None else jnp.where(payload[vpos], data, jnp.zeros((), data.dtype)))
+        src, filled, ovf = _route(_hash_dest_multi(ks, n_parts), pres, n_parts, capacity)
+        a2a = lambda x: lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
+        outs = [a2a(a[src]).reshape((-1,) + a.shape[1:]) for a in payload]
+        rows = lax.psum(jnp.sum(pres.astype(jnp.int32)), axis)
+        return tuple(outs) + (a2a(filled).reshape(-1), rows[None], ovf[None])
+
+    spec = P(axis)
+    f = cached_sm(
+        ("exchange_sharded", mesh, axis, int(capacity), tuple(str(a.dtype) + str(a.shape[1:]) for a in lanes),
+         tuple(kpos)),
+        lambda: jax.jit(shard_map(exchange_program, mesh=mesh, in_specs=(spec,) * (1 + len(lanes)),
+                                  out_specs=(spec,) * (len(lanes) + 3))),
+    )
+    *received, present, rows, ovf = f(st.present, *lanes)
+    rows, ovf = jax.device_get((rows, ovf))  # the one wait: an overflow has to be known before the rows are used
+    out = ShardedTable(_table_from(st.table, spots, received), present, mesh, axis, part=tuple(key_cols))
+    return out, int(rows[0]), lane_bytes, bool(ovf.any())
+
+
+_SHARDED_HOWS = ("sum", "count", "count_all", "min", "max", "mean")
+
+
+@op_boundary("groupby_sharded")
+def groupby_sharded(st: ShardedTable, key_cols: Sequence[str],
+                    aggs: Sequence[Tuple[Optional[str], str, str]]) -> ShardedTable:
+    """GROUP BY on a table already partitioned on (a subset of) the keys:
+    every group is whole on its shard, so each shard groups what it holds
+    and nothing moves. One output slot a group, at most as many as the
+    shard has slots, so no capacity can overflow. Aggregates come out as
+    the eager tier's do (``count`` INT64, FLOAT64 sums exact bits)."""
+    if not st.part or not set(st.part) <= set(key_cols):
+        raise ValueError(f"groupby_sharded on {list(key_cols)} needs a table partitioned on some of them, "
+                         f"not on {list(st.part)}")
+    for _s, how, _o in aggs:
+        if how not in _SHARDED_HOWS:
+            raise ValueError(f"unknown agg {how!r} (supported: {_SHARDED_HOWS})")
+    for k in key_cols:  # a FLOAT64's IEEE bits, a date's days and a dictionary code are integer lanes too
+        lane = st.column(k).data
+        if lane.ndim != 1 or not jnp.issubdtype(lane.dtype, jnp.integer):
+            raise ValueError(f"groupby_sharded sorts integer key lanes, {k!r} is {st.column(k).dtype!r}")
+    mesh, axis, n_parts = st.mesh, st.axis, st.n_parts
+    cap = st.num_rows // n_parts
+    key_lanes, key_nullable = [], []
+    for k in key_cols:
+        c = st.column(k)
+        key_nullable.append(c.validity is not None)
+        if c.validity is None:
+            key_lanes.append(c.data)
+        else:  # NULL keys form one group: the validity joins the key tuple
+            key_lanes += [jnp.where(c.validity, c.data, jnp.zeros((), c.data.dtype)), c.validity.astype(jnp.int32)]
+    val_lanes, val_valid, hows, f64_flags, srcs = [], [], [], [], []
+    for source, how, _o in aggs:
+        src = st.column(key_cols[0] if source is None else source)
+        srcs.append(src)
+        val_lanes.append(src.data)
+        val_valid.append(None if how == "count_all" else src.validity)
+        hows.append("count" if how == "count_all" else how)
+        f64_flags.append(src.dtype.id == TypeId.FLOAT64)
+    valid_lanes = [v for v in val_valid if v is not None]
+    n_keys, n_vals = len(key_lanes), len(val_lanes)
+
+    def groupby_program(pres, *arrs):
+        ks, vs, vps = list(arrs[:n_keys]), list(arrs[n_keys:n_keys + n_vals]), iter(arrs[n_keys + n_vals:])
+        vp_full = [None if v is None else next(vps) for v in val_valid]
+        # one UNSTABLE sort on the key lanes alone (the chip's compiler takes minutes over a stable
+        # sort with an occupancy lane in front): absent rows ride under the largest key and count
+        # in no group; a group that only they make is marked invalid
+        top = jnp.iinfo(ks[0].dtype).max
+        k0 = jnp.where(pres, ks[0], top)
+        order = lax.sort((k0, *ks[1:], jnp.arange(cap, dtype=jnp.int32)), num_keys=n_keys, is_stable=False)[-1]
+        sk, ps = [k0[order]] + [k[order] for k in ks[1:]], pres[order]
+        changed = jnp.zeros((cap - 1,), bool)
+        for k in sk:
+            changed = changed | (k[1:] != k[:-1])
+        group = jnp.cumsum(jnp.concatenate([jnp.ones((1,), bool), changed])).astype(jnp.int32) - 1
+        gv = jax.ops.segment_sum(ps.astype(jnp.int32), group, num_segments=cap) > 0
+        seg = jnp.where(ps, group, cap)
+        gas, gavs = _segment_aggs(vs, hows, vp_full, f64_flags, order, ps, seg, cap)
+        gks = [jnp.zeros((cap,), k.dtype).at[seg].set(kk, mode="drop") for k, kk in zip(ks, sk)]
+        gks[0] = jnp.where(gv, gks[0], top)  # the first key ascends over every slot, the empty ones too
+        return tuple(gks) + tuple(gas) + tuple(gavs) + (gv,)
+
+    spec = P(axis)
+    f = cached_sm(
+        ("groupby_sharded", mesh, axis, cap, tuple(hows), tuple(f64_flags),
+         tuple(v is not None for v in val_valid), tuple(str(a.dtype) for a in key_lanes + val_lanes)),
+        lambda: jax.jit(shard_map(groupby_program, mesh=mesh,
+                                  in_specs=(spec,) * (1 + n_keys + n_vals + len(valid_lanes)),
+                                  out_specs=(spec,) * (n_keys + 2 * n_vals + 1))),
+    )
+    with tracing.span("exchange.groupby", rows_in=st.num_rows, keys=list(key_cols), capacity=cap,
+                      parts=n_parts, attempt=0):
+        outs = f(st.present, *key_lanes, *val_lanes, *valid_lanes)
+    gks, gas, gavs, gv = outs[:n_keys], outs[n_keys:n_keys + n_vals], outs[n_keys + n_vals:-1], outs[-1]
+    cols, names, li = [], [], 0
+    for k, nullable in zip(key_cols, key_nullable):
+        validity = gks[li + 1].astype(bool) if nullable else None
+        cols.append(Column(st.column(k).dtype, data=gks[li], validity=validity))
+        names.append(k)
+        li += 2 if nullable else 1
+    for (_s, how, oname), src, g, gav in zip(aggs, srcs, gas, gavs):
+        if how in ("count", "count_all"):
+            cols.append(Column(dt.INT64, data=g))
+        elif src.dtype.id == TypeId.FLOAT64 or (how == "mean" and src.dtype.is_integral):
+            cols.append(Column(dt.FLOAT64, data=g, validity=gav))  # exact paths: ready-made IEEE bits
+        elif how == "mean":
+            cols.append(Column(dt.FLOAT64, data=bitutils.float_store(g, dt.FLOAT64), validity=gav))
+        elif how == "sum" and src.dtype.is_integral:
+            cols.append(Column(dt.UINT64 if g.dtype == jnp.uint64 else dt.INT64,
+                               data=g if g.dtype == jnp.uint64 else g.astype(jnp.int64), validity=gav))
+        else:
+            cols.append(Column(src.dtype, data=g, validity=gav))
+        names.append(oname)
+    ordered = key_cols[0] if len(key_cols) == 1 and not key_nullable[0] else None
+    return ShardedTable(Table(cols, names), gv, mesh, axis, part=st.part, ordered=ordered)
+
+
+@op_boundary("join_sharded")
+def join_sharded(left: ShardedTable, right, on: Tuple[str, str], how: str,
+                 payload: Sequence[str] = ()) -> ShardedTable:
+    """Join a sharded left side, slot for slot, against a right side that
+    is either a replicated Table (a broadcast join: every shard probes
+    the whole of it) or a ShardedTable partitioned compatibly (every
+    shard probes its own part). Nothing moves. ``how``: ``semi`` and
+    ``anti`` keep or drop left rows; ``inner`` also brings ``payload``
+    columns of the right side and takes the right key as UNIQUE (the
+    caller has checked: a dimension's primary key), so a left row
+    matches at most once and the output is the left's slots. One integer
+    key a side; a NULL key matches nothing."""
+    if how not in ("inner", "semi", "anti"):
+        raise ValueError(f"how={how!r} not supported (inner/semi/anti)")
+    mesh, axis, n_parts = left.mesh, left.axis, left.n_parts
+    lkey, rkey = left.column(on[0]), right.column(on[1])
+    if not (lkey.dtype.is_integral and rkey.dtype.is_integral):
+        raise ValueError("join_sharded takes one integer key a side")
+    if lkey.dtype.id != rkey.dtype.id and TypeId.UINT64 in (lkey.dtype.id, rkey.dtype.id):
+        raise ValueError(f"join_sharded: {lkey.dtype!r} and {rkey.dtype!r} keys do not meet in int64")
+    sharded_right = isinstance(right, ShardedTable)
+    if sharded_right:
+        if not left.part or left.part != (on[0],) or right.part != (on[1],):
+            raise ValueError(f"join_sharded: sides partitioned on {left.part} and {right.part}, joined on {on}")
+        r_present, r_rows = right.present, right.num_rows
+    else:
+        from .mesh import replicated
+
+        right = jax.device_put(right, replicated(mesh))
+        rkey = right.column(on[1])
+        r_present, r_rows = None, right.num_rows
+    pay = [right.column(p) for p in (payload if how == "inner" else ())]
+    flags = (lkey.validity is not None, rkey.validity is not None, r_present is not None,
+             tuple(c.validity is not None for c in pay))
+    in_order = sharded_right and right.ordered == on[1] and rkey.validity is None
+
+    def join_program(*arrs):
+        it = iter(arrs)
+        lk, lp = next(it).astype(jnp.int64), next(it)
+        if flags[0]:
+            lp = lp & next(it)
+        rk = next(it).astype(jnp.int64)
+        rp = jnp.ones(rk.shape, bool)
+        if flags[1]:
+            rp = rp & next(it)
+        if flags[2]:
+            rp = rp & next(it)
+        if rk.shape[0] == 0:
+            hit = jnp.zeros(lk.shape, bool)
+            row = jnp.zeros(lk.shape, jnp.int32)
+        elif in_order:
+            # a group-by's keys: they ascend over the slots and are distinct where present
+            at = jnp.minimum(jnp.searchsorted(rk, lk, side="left").astype(jnp.int32), rk.shape[0] - 1)
+            hit = lp & rp[at] & (rk[at] == lk)
+            row = at
+        elif how == "inner":
+            order = jnp.lexsort((rk, ~rp))  # absent last, so that the row found is a real one
+            n_valid = jnp.sum(rp.astype(jnp.int32))
+            probe = jnp.where(rp[order], rk[order], jnp.iinfo(jnp.int64).max)
+            pos = jnp.searchsorted(probe, lk, side="left").astype(jnp.int32)
+            at = jnp.minimum(pos, rk.shape[0] - 1)
+            hit = lp & (pos < n_valid) & (probe[at] == lk)
+            row = order[at]
+        else:
+            # membership alone: an unstable sort of the keys, the absent ones under the largest key;
+            # a probe for that very key finds them too, so it hits only if a real row has it
+            probe = jnp.sort(jnp.where(rp, rk, jnp.iinfo(jnp.int64).max), stable=False)
+            top = jnp.sum((rp & (rk == jnp.iinfo(jnp.int64).max)).astype(jnp.int32)) > 0
+            at = jnp.minimum(jnp.searchsorted(probe, lk, side="left").astype(jnp.int32), rk.shape[0] - 1)
+            hit = lp & (probe[at] == lk) & ((lk != jnp.iinfo(jnp.int64).max) | top)
+            row = at
+        if how == "anti":
+            return (lp & ~hit,)
+        outs = [hit]
+        for has_v in flags[3]:
+            data = next(it)
+            outs.append(data[row])
+            if has_v:
+                outs.append(next(it)[row] & hit)
+        return tuple(outs)
+
+    l_in = [lkey.data, left.present] + ([lkey.validity] if flags[0] else [])
+    r_in = [rkey.data] + ([rkey.validity] if flags[1] else []) + ([r_present] if flags[2] else [])
+    for c in pay:
+        r_in += [c.data] + ([c.validity] if c.validity is not None else [])
+    spec, rspec = P(axis), (P(axis) if sharded_right else P())
+    n_out = 1 + sum(1 + int(v) for v in flags[3]) if how != "anti" else 1
+    f = cached_sm(
+        ("join_sharded", mesh, axis, how, sharded_right, in_order, flags,
+         tuple(str(a.dtype) for a in l_in + r_in)),
+        lambda: jax.jit(shard_map(join_program, mesh=mesh, in_specs=(spec,) * len(l_in) + (rspec,) * len(r_in),
+                                  out_specs=(spec,) * n_out)),
+    )
+    with tracing.span("exchange.join", rows_in=left.num_rows, keys=list(on), capacity=r_rows, parts=n_parts,
+                      attempt=0, how=how, broadcast=not sharded_right):
+        outs = f(*l_in, *r_in)
+    cols, names = list(left.table.columns), list(left.names)
+    it = iter(outs[1:])
+    for p, c in zip(payload if how == "inner" else (), pay):
+        data = next(it)
+        cols.append(Column(c.dtype, data=data, validity=next(it) if c.validity is not None else None))
+        names.append(p)
+    return left.replace(table=Table(cols, names), present=outs[0])
+
+
+@op_boundary("gather_table")
+def gather_table(st: ShardedTable) -> Table:
+    """The rows of a ShardedTable as one compacted Table, a whole copy on
+    every chip: the way out of the sharded layer (a result, or the input
+    of a stage that is not keyed). Waits for the device to learn which
+    slots hold rows."""
+    from .mesh import replicated
+
+    with tracing.span("exchange.gather", slots=st.num_rows, parts=st.n_parts, cols=st.table.num_columns) as sp:
+        sel = np.flatnonzero(np.asarray(st.present))
+        sp.annotate(rows_out=int(sel.size))
+        idx, rep = jnp.asarray(sel), replicated(st.mesh)
+        cols = [Column(c.dtype, data=jax.device_put(c.data[idx], rep),
+                       validity=None if c.validity is None else jax.device_put(c.validity[idx], rep))
+                for c in st.table.columns]
+        return Table(cols, list(st.names))

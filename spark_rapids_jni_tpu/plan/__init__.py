@@ -29,6 +29,7 @@ Quick shape::
 
 from .compiler import CompiledPlan, compile_ir, lower_ir  # noqa: F401
 from .distribute import (  # noqa: F401
+    MeshBinding,
     exchange_context,
     insert_exchanges,
 )
@@ -95,5 +96,5 @@ __all__ = [
     "fingerprint", "ParamFingerprint", "parameterized_fingerprint",
     "rebind_literals",
     "PlanViolation", "verify_plan", "verify_obligations",
-    "verify_estimates", "insert_exchanges", "exchange_context",
+    "verify_estimates", "insert_exchanges", "exchange_context", "MeshBinding",
 ]

@@ -94,6 +94,7 @@ def _width(schema: Schema) -> int:
 def _table_nbytes(t: Table) -> int:
     import jax
 
+    t = getattr(t, "table", t)  # a ShardedTable: its global arrays (padding included)
     return sum(int(getattr(leaf, "nbytes", 0))
                for leaf in jax.tree_util.tree_leaves(t))
 
@@ -113,9 +114,16 @@ def _eval_expr(e: PExpr, table: Table, want: DType) -> Column:
         shape = (n_rows, 4) if want.id == TypeId.DECIMAL128 else (n_rows,)
         return Column(want, data=jnp.zeros(shape, want.jnp_dtype),
                       validity=jnp.zeros((n_rows,), bool))
-    c = e.lower().evaluate(table)
+    src = is_col(e)
+    if src is not None:
+        # a bare column reference is the column: a STRING has offsets and
+        # chars and no ``data`` for ``Expression.evaluate`` to read (the
+        # narrowing Projects that pruning inserts are all of this kind)
+        c = table.column(src)
+    else:
+        c = e.lower().evaluate(table)
     n = table.num_rows
-    if c.data.ndim == 0:
+    if c.data is not None and c.data.ndim == 0:
         data = jnp.broadcast_to(c.data, (n,))
         v = None if c.validity is None else jnp.broadcast_to(c.validity, (n,))
         c = Column(c.dtype, data=data, validity=v)
@@ -172,6 +180,12 @@ class _Exec:
     """One lowered stage: knows its schema, estimates, and inputs."""
 
     kind = "?"
+
+    # under a MeshBinding: does this stage hand on a ShardedTable (its
+    # rows laid out over the mesh), and on which columns is it known to
+    # be partitioned. Stages of a plan compiled without a mesh are local.
+    sharded = False
+    part: Tuple[str, ...] = ()
 
     # srjt-cache (ISSUE 17): set once at annotation time (before any
     # concurrent run) on stages whose subtree result is cacheable; the
@@ -339,6 +353,126 @@ class _ExchangeExec(_Exec):
             t, list(self.keys), binding.peers,
             epoch=binding.stage_epoch(id(self)), cluster=binding.cluster,
         )
+
+
+# ---------------------------------------------------------------------------
+# stages over tables that are laid out over a mesh (plan.distribute.MeshBinding)
+# ---------------------------------------------------------------------------
+#
+# Each hands on a ``parallel.table_ops.ShardedTable``: nothing is compacted
+# and nothing leaves its chip but through an Exchange stage, so the
+# partitioning one exchange establishes serves every keyed stage after it.
+
+
+class _MeshScanExec(_ScanExec):
+    sharded = True  # the bound table is a ShardedTable; selecting its columns is the same call
+
+
+class _MeshFilterExec(_FilterExec):
+    """A row that fails the predicate leaves ``present``; its slot stays."""
+
+    sharded = True
+
+    def __init__(self, node, schema, child, est_rows=None):
+        super().__init__(node, schema, child, est_rows=est_rows)
+        self.part = child.part
+
+    def _run(self, ctx):
+        st = self.inputs[0].run(ctx)
+        mask = self.pred.lower().evaluate(st.table)
+        keep = mask.data.astype(bool)
+        if mask.validity is not None:
+            keep = keep & mask.validity
+        return st.replace(present=st.present & keep)
+
+
+class _MeshProjectExec(_ProjectExec):
+    sharded = True
+
+    def __init__(self, node, schema, child):
+        super().__init__(node, schema, child)
+        kept = {name for name, e in node.exprs if is_col(e) == name}
+        self.part = child.part if set(child.part) <= kept else ()
+
+    def _run(self, ctx):
+        st = self.inputs[0].run(ctx)
+        cols = [_eval_expr(e, st.table, self.schema[name]) for name, e in self.exprs]
+        return st.replace(table=Table(cols, [name for name, _ in self.exprs]), part=self.part)
+
+
+class _MeshExchangeExec(_Exec):
+    """The Exchange stage on a mesh: one ``shard_map`` program, an ICI
+    all-to-all a lane (``table_ops.exchange_sharded``)."""
+
+    kind = "exchange"
+    sharded = True
+
+    def __init__(self, node: Exchange, schema: Schema, child: _Exec):
+        super().__init__(schema, child.est_rows, [child])
+        self.keys = self.part = tuple(node.keys)
+
+    def _run(self, ctx):
+        from ..parallel.table_ops import exchange_sharded
+
+        return exchange_sharded(self.inputs[0].run(ctx), self.keys)
+
+
+class _MeshAggExec(_Exec):
+    """A keyed aggregate over rows already partitioned on its keys:
+    every shard groups what it holds (``table_ops.groupby_sharded``)."""
+
+    kind = "aggregate"
+    sharded = True
+
+    def __init__(self, node: Aggregate, schema: Schema, child: _Exec, est_rows=None):
+        super().__init__(schema, child.est_rows if est_rows is None else est_rows, [child])
+        self.keys, self.aggs, self.part = node.keys, node.aggs, child.part
+
+    def _run(self, ctx):
+        from ..parallel.table_ops import groupby_sharded
+
+        st = groupby_sharded(self.inputs[0].run(ctx), self.keys,
+                             [(a.source, a.how, a.name) for a in self.aggs])
+        nk = len(self.keys)
+        cols = list(st.table.columns[:nk]) + [
+            _normalize_agg_column(c, a.how) for c, a in zip(st.table.columns[nk:], self.aggs)]
+        return st.replace(table=Table(cols, list(st.names)))
+
+
+class _MeshJoinExec(_Exec):
+    """A sharded left side against a whole right side (broadcast) or one
+    partitioned on the same key: slot for slot, nothing moves
+    (``table_ops.join_sharded``)."""
+
+    kind = "join"
+    sharded = True
+
+    def __init__(self, node: Join, schema: Schema, left: _Exec, right: _Exec, est_rows=None):
+        super().__init__(schema, left.est_rows if est_rows is None else est_rows, [left, right])
+        self.on, self.how, self.part = node.on[0], node.how, left.part
+        self.payload = [c for c in schema if c not in left.schema]
+
+    def _run(self, ctx):
+        from ..parallel.table_ops import join_sharded
+
+        out = join_sharded(self.inputs[0].run(ctx), self.inputs[1].run(ctx), self.on, self.how,
+                           payload=self.payload)
+        return out.select(list(self.schema.keys()))
+
+
+class _GatherExec(_Exec):
+    """The way out of the mesh stages: the rows that are present, as one
+    compacted Table whole on every chip (``table_ops.gather_table``)."""
+
+    kind = "gather"
+
+    def __init__(self, child: _Exec):
+        super().__init__(child.schema, child.est_rows, [child])
+
+    def _run(self, ctx):
+        from ..parallel.table_ops import gather_table
+
+        return gather_table(self.inputs[0].run(ctx))
 
 
 class _AggExec(_Exec):
@@ -737,14 +871,19 @@ class _Fuser:
 
 class _Lowerer:
     def __init__(self, tables: Dict[str, Table], catalog: Dict[str, Schema],
-                 est=None):
+                 est=None, mesh=None):
         self.tables = tables
         self.catalog = catalog
         # srjt-cbo (ISSUE 19): sketch-backed stats.Estimator, or None —
         # stages then keep the original hand-tuned row heuristics
         self.est = est
+        # plan.distribute.MeshBinding, or None: with it the stages over
+        # its sharded tables lower to the _Mesh*Exec family
+        self.mesh = mesh
         self._schemas: dict = {}
         self._execs: Dict[int, _Exec] = {}
+        self._gathers: Dict[int, _Exec] = {}
+        self._unique: Dict[Tuple[str, str], bool] = {}
         self.all_execs: List[_Exec] = []
 
     def schema_of(self, node: Node) -> Schema:
@@ -763,44 +902,148 @@ class _Lowerer:
             self.all_execs.append(ex)
         return ex
 
+    def local(self, node: Node) -> _Exec:
+        """The stage of ``node`` as a local one: a stage over a mesh
+        gets a gather on top (once, however many stages read it)."""
+        ex = self.lower(node)
+        if not ex.sharded:
+            return ex
+        if id(ex) not in self._gathers:
+            self._gathers[id(ex)] = _GatherExec(ex)
+            self.all_execs.append(self._gathers[id(ex)])
+        return self._gathers[id(ex)]
+
+    def _on_mesh(self, node: Node) -> bool:
+        """Does a table that the mesh binding shards lie under ``node``?"""
+        if isinstance(node, Scan):
+            return node.table in self.mesh.sharded
+        return any(self._on_mesh(i) for i in node.inputs())
+
+    def _unique_key(self, node: Node, key: str) -> bool:
+        """Is ``key`` provably unique in ``node``'s output: a column of a
+        bound whole table (checked once, on the host) under nothing but
+        filters and pass-through projections?"""
+        while isinstance(node, (Filter, Project)):
+            if isinstance(node, Project) and not any(n == key and is_col(e) == key for n, e in node.exprs):
+                return False
+            node = node.input
+        if not isinstance(node, Scan) or node.table in self.mesh.sharded:
+            return False
+        if (node.table, key) not in self._unique:
+            import numpy as np
+
+            c = self.tables[node.table].column(key)
+            vals = np.asarray(c.data)
+            if c.validity is not None:
+                vals = vals[np.asarray(c.validity)]
+            self._unique[(node.table, key)] = bool(c.dtype.is_integral and len(np.unique(vals)) == len(vals))
+        return self._unique[(node.table, key)]
+
+    def _lower_mesh(self, node: Node, schema: Schema) -> Optional[_Exec]:
+        """The stage over the mesh, where ``node`` reads sharded rows and
+        the sharded layer can run it; None sends it down the local path
+        (which gathers what it reads)."""
+        if isinstance(node, Scan):
+            return _MeshScanExec(node, schema, self.tables) if node.table in self.mesh.sharded else None
+        if isinstance(node, Join):
+            child = self.lower(node.left)
+        elif isinstance(node, (Filter, Project, Exchange, Aggregate)):
+            child = self.lower(node.input)
+        else:
+            return None
+        if not child.sharded:
+            return None
+        if isinstance(node, Filter):
+            rows = (self.est.filter_rows(child.est_rows, node.predicate)
+                    if self.est is not None else None)
+            return _MeshFilterExec(node, schema, child, est_rows=rows)
+        if isinstance(node, Project):
+            return _MeshProjectExec(node, schema, child)
+        if isinstance(node, Exchange):
+            if node.world != self.mesh.world:
+                raise PlanError(f"exchange stage placed for world {node.world} compiled for a "
+                                f"mesh of {self.mesh.world}")
+            return _MeshExchangeExec(node, schema, child)
+        if isinstance(node, Aggregate):
+            from ..parallel.table_ops import _SHARDED_HOWS
+
+            # the shard-local group-by sorts integer key lanes (absent rows under the largest
+            # value): any other key type groups on the local tier, behind a gather
+            if (node.keys and node.grouping_sets is None and child.part
+                    and set(child.part) <= set(node.keys)
+                    and all(child.schema[k].is_integral for k in node.keys)
+                    and all(a.how in _SHARDED_HOWS for a in node.aggs)):
+                rows = (self.est.agg_rows(child.est_rows, node.keys)
+                        if self.est is not None else None)
+                return _MeshAggExec(node, schema, child, est_rows=rows)
+            return None
+        # a Join with its left side on the mesh
+        if len(node.on) != 1 or node.how not in ("inner", "semi", "anti"):
+            return None
+        (lkey, rkey), right = node.on[0], self.lower(node.right)
+        ld, rd = child.schema[lkey], right.schema[rkey]
+        # the keys meet in int64 (an exchange routes an integer by its value, whatever its
+        # width): every integer type but a UINT64 against another
+        if not (ld.is_integral and rd.is_integral) or (
+                ld.id != rd.id and TypeId.UINT64 in (ld.id, rd.id)):
+            return None
+        if right.sharded:
+            ok = node.how != "inner" and child.part == (lkey,) and right.part == (rkey,)
+        else:
+            # an inner join brings columns over: fixed-width ones, and from
+            # a side whose key is unique, so that a row matches once
+            ok = node.how != "inner" or (self._unique_key(node.right, rkey) and all(
+                d.is_fixed_width for c, d in schema.items() if c not in child.schema))
+        if not ok:
+            return None
+        rows = (self.est.join_rows(node.how, child.est_rows, right.est_rows, node.on)
+                if self.est is not None else None)
+        return _MeshJoinExec(node, schema, child, right, est_rows=rows)
+
     def _lower(self, node: Node) -> _Exec:
         schema = self.schema_of(node)
+        if self.mesh is not None:
+            ex = self._lower_mesh(node, schema)
+            if ex is not None:
+                return ex
         if isinstance(node, Scan):
             return _ScanExec(node, schema, self.tables)
         if isinstance(node, Filter):
-            child = self.lower(node.input)
+            child = self.local(node.input)
             rows = (self.est.filter_rows(child.est_rows, node.predicate)
                     if self.est is not None else None)
             return _FilterExec(node, schema, child, est_rows=rows)
         if isinstance(node, Project):
-            return _ProjectExec(node, schema, self.lower(node.input))
+            return _ProjectExec(node, schema, self.local(node.input))
         if isinstance(node, Join):
-            left = self.lower(node.left)
-            right = self.lower(node.right)
+            left = self.local(node.left)
+            right = self.local(node.right)
             rows = (self.est.join_rows(node.how, left.est_rows,
                                        right.est_rows, node.on)
                     if self.est is not None else None)
             return _JoinExec(node, schema, left, right, est_rows=rows)
         if isinstance(node, Aggregate):
-            fused = _Fuser(self, node).try_build()
+            # the fused tier reads whole tables: not one that is sharded
+            fused = (None if self.mesh is not None and self._on_mesh(node)
+                     else _Fuser(self, node).try_build())
             if fused is not None:
                 self.all_execs.append(fused)
                 return fused
             _durable("plan.ops_stages").inc()
-            child = self.lower(node.input)
+            child = self.local(node.input)
             rows = (self.est.agg_rows(child.est_rows, node.keys)
                     if self.est is not None else None)
             return _AggExec(node, schema, child, est_rows=rows)
         if isinstance(node, Exchange):
-            return _ExchangeExec(node, schema, self.lower(node.input))
+            return _ExchangeExec(node, schema, self.local(node.input))
         if isinstance(node, Window):
-            return _WindowExec(node, schema, self.lower(node.input))
+            return _WindowExec(node, schema, self.local(node.input))
         if isinstance(node, Sort):
-            return _SortExec(node, schema, self.lower(node.input))
+            return _SortExec(node, schema, self.local(node.input))
         if isinstance(node, Limit):
-            return _LimitExec(node, schema, self.lower(node.input))
+            return _LimitExec(node, schema, self.local(node.input))
         if isinstance(node, UnionAll):
-            return _UnionExec(schema, [self.lower(b) for b in node.branches])
+            return _UnionExec(schema, [self.local(b) for b in node.branches])
         raise PlanError(
             f"cannot lower {type(node).__name__}: sugar nodes must be "
             "rewritten away before compilation")
@@ -837,7 +1080,7 @@ class CompiledPlan:
                  rewrites_fired: Dict[str, int], opt_plan: Node,
                  obligations: Optional[list] = None,
                  node_execs: Optional[Dict[int, _Exec]] = None,
-                 modeled: Optional[dict] = None):
+                 modeled: Optional[dict] = None, mesh=None):
         self.name = name
         self.schema = dict(root.schema)
         self.optimized = opt_plan
@@ -861,6 +1104,8 @@ class CompiledPlan:
         # "joins": n} when the search ran — the premerge modeled-cost
         # gate's source; None on the cache-hit / CBO-off paths
         self.modeled = dict(modeled) if modeled else None
+        # the plan.distribute.MeshBinding this plan was compiled for, or None
+        self.mesh = mesh
         self.estimated_memory_bytes = max(
             s.working_set_est() for s in stages
         )
@@ -948,11 +1193,23 @@ class CompiledPlan:
 
 
 def compile_ir(plan: Node, tables: Dict[str, Table],
-               name: str = "plan") -> CompiledPlan:
+               name: str = "plan", mesh=None) -> CompiledPlan:
     """Validate, rewrite, and lower a logical plan against bound tables.
     The returned ``CompiledPlan`` is a zero-argument callable producing
     the result Table; submit it to ``serve`` directly (the scheduler
-    derives ``memory_bytes=`` from its stage estimates)."""
+    derives ``memory_bytes=`` from its stage estimates).
+
+    ``mesh`` is a ``plan.distribute.MeshBinding``: the tables are placed
+    through it (its sharded tables row-sharded over the chips, the
+    others whole on each), the stages over sharded rows run as
+    ``shard_map`` programs, an Exchange stage as an all-to-all, and the
+    compiled plan carries the binding (``cp.mesh``). Without it every
+    Exchange stage is the identity, or the cross-process fabric's where
+    ``exchange_context`` binds one."""
+    placed = tables
+    if mesh is not None:
+        placed = mesh.place(tables)
+        tables = {t: getattr(p, "table", p) for t, p in placed.items()}
     catalog = {t: {n: c.dtype for n, c in zip(tbl.names, tbl.columns)}
                for t, tbl in tables.items()}
     raw_nodes = _count_nodes(plan)
@@ -978,12 +1235,12 @@ def compile_ir(plan: Node, tables: Dict[str, Table],
                    "joins": cres.join_count}
     for rule, n in fired.items():
         _durable(f"plan.rewrites.{rule}").inc(n)
-    low = _Lowerer(tables, catalog, est=est)
-    root = low.lower(opt_plan)
-    cp = CompiledPlan(name, root, tables, low.all_execs, raw_nodes,
+    low = _Lowerer(tables, catalog, est=est, mesh=mesh)
+    root = low.local(opt_plan)
+    cp = CompiledPlan(name, root, placed, low.all_execs, raw_nodes,
                       _count_nodes(opt_plan), fired, opt_plan,
                       obligations=obligations, node_execs=low._execs,
-                      modeled=modeled)
+                      modeled=modeled, mesh=mesh)
     # srjt-ooc (ISSUE 18): a plan whose peak exceeds the armed device
     # budget degrades to streamed partitioned execution instead of
     # split-retrying to failure; a no-op unless SRJT_OOC_ENABLED

@@ -644,7 +644,10 @@ class TestSqueeze:
         from spark_rapids_jni_tpu.parallel.table_ops import distributed_groupby_table
         from spark_rapids_jni_tpu.utils import memory as mem
 
-        monkeypatch.setenv("SRJT_DEVICE_MEMORY_BUDGET", "300000")
+        # admission takes the op's 131 KB; the skewed key's buckets grow to a
+        # whole shard (~180 KB a device) and exceed the budget, each half's
+        # (~90 KB) fit: test_table_ops' sizes
+        monkeypatch.setenv("SRJT_DEVICE_MEMORY_BUDGET", "160000")
         rng = np.random.default_rng(3)
         n = 4096
         keys = np.where(rng.integers(0, 10, n) < 9, 0, rng.integers(0, 50, n))
@@ -656,9 +659,9 @@ class TestSqueeze:
             ],
             ["k", "v"],
         )
-        # cold decoys: ~240 KB device-resident, so admissions must spill
+        # cold decoys: ~120 KB device-resident, so admissions must spill
         decoys = [
-            memgov.catalog().register(f"decoy{i}", jnp.zeros(15_000, jnp.float64))
+            memgov.catalog().register(f"decoy{i}", jnp.zeros(7_500, jnp.float64))
             for i in range(2)
         ]
         faultinj.configure_from_file(_MEMGOV_CHAOS)

@@ -499,14 +499,38 @@ def test_bytes_annotations_equal_the_frame_sizes(traced_convert):
 
 
 def test_the_request_is_covered_by_its_phases(traced_convert):
+    """By parentage and by the clock, not by ratios of what two processes
+    were given of a loaded machine: the client's phases are the request's
+    children, lie inside it one after the other and leave it no tenth of
+    its time; the worker's phases are its children in the other process,
+    all begun while the client waited, and they cover what the worker
+    did between its first and its last."""
     spans = traced_convert["spans"]
     request = _one(spans, "sidecar.request")
-    client = sum(_one(spans, n)["dur_us"] for n in CLIENT_SPANS)
-    assert client >= 0.9 * request["dur_us"]
-    wait = _one(spans, "sidecar.client.wait")["dur_us"]
-    worker = sum(s["dur_us"] for s in spans
-                 if s["parent"] == request["span"] and s["pid"] != request["pid"])
-    assert 0.8 * wait <= worker <= wait
+    tol = 2e-3  # a span's start is read on the wall clock, its length on the monotonic one
+
+    def end(s):
+        return s["ts"] + s["dur_us"] / 1e6
+
+    phases = [_one(spans, n) for n in CLIENT_SPANS]
+    assert all(s["parent"] == request["span"] and s["pid"] == request["pid"] for s in phases)
+    assert request["ts"] - tol <= phases[0]["ts"] and end(phases[-1]) <= end(request) + tol
+    assert all(end(a) <= b["ts"] + tol for a, b in zip(phases, phases[1:]))
+    # the three are timed back to back on one thread: what they leave out is a few lines of code
+    assert sum(s["dur_us"] for s in phases) >= 0.9 * request["dur_us"]
+
+    wait = _one(spans, "sidecar.client.wait")
+    worker = sorted((s for s in spans if s["parent"] == request["span"] and s["pid"] != request["pid"]),
+                    key=lambda s: s["ts"])
+    assert [s["name"] for s in worker if s["name"] != "integrity.crc"] == [
+        "sidecar.worker.payload_read", "sidecar.worker_op", "sidecar.worker.reply_write"]
+    assert all(wait["ts"] - tol <= s["ts"] <= end(wait) + tol for s in worker)
+    # the reply's write is closed after the client has the reply; every other phase ends inside the wait
+    assert all(end(s) <= end(wait) + tol for s in worker[:-1])
+    assert all(end(a) <= b["ts"] + tol for a, b in zip(worker, worker[1:]))
+    # the wake-up of the worker's thread belongs to no phase; from its first span on, four fifths are spanned
+    did = end(worker[-1]) - worker[0]["ts"]
+    assert sum(s["dur_us"] for s in worker) / 1e6 >= 0.8 * did
 
 
 def test_stats_carries_the_workers_device_and_memory(traced_convert):

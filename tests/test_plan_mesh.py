@@ -1,0 +1,325 @@
+"""A compiled plan over a mesh (``plan.compile_ir(..., mesh=P.MeshBinding)``):
+the stages over row-sharded tables run as ``shard_map`` programs, an
+Exchange stage as an all-to-all, and one exchange serves every keyed stage
+after it. TPC-DS q95 as the benchmark sends it (``bench/queries/tpcds_q95.py``
+over ``bench/data/tpcds_web.py``'s tables, at a small size) is the subject:
+on a mesh it equals the same plan at world 1 and the pandas reference.
+"""
+
+import importlib.util
+import os
+
+import jax
+import numpy as np
+import pandas as pd
+import pytest
+
+from spark_rapids_jni_tpu import plan as P
+from spark_rapids_jni_tpu import serve
+from spark_rapids_jni_tpu.columnar import Column, Table
+from spark_rapids_jni_tpu.columnar import dtype as dt
+from spark_rapids_jni_tpu.parallel import table_ops
+from spark_rapids_jni_tpu.parallel.mesh import make_mesh
+from spark_rapids_jni_tpu.plan import nodes as pn
+from spark_rapids_jni_tpu.utils import metrics, trace_sink, tracing
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench")
+SHARDED = ("web_sales", "web_returns")
+CONFIG = {"tables": {"date_dim": {"rows": 73049}, "customer_address": {"rows": 2000}, "web_site": {"rows": 42}}}
+ROWS = 120_000
+TYPES = {"web_sales": {"ws_order_number": dt.INT64, "ws_warehouse_sk": dt.INT32, "ws_ship_date_sk": dt.INT32,
+                       "ws_ship_addr_sk": dt.INT32, "ws_web_site_sk": dt.INT32,
+                       "ws_ext_ship_cost": dt.FLOAT64, "ws_net_profit": dt.FLOAT64},
+         "web_returns": {"wr_order_number": dt.INT64},
+         "date_dim": {"d_date_sk": dt.INT32, "d_date": dt.INT32},
+         "customer_address": {"ca_address_sk": dt.INT32, "ca_state": dt.STRING},
+         "web_site": {"web_site_sk": dt.INT32, "web_company_name": dt.STRING}}
+
+
+def _bench_module(kind, name):
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", os.path.join(BENCH, kind, f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+Q95 = _bench_module("queries", "tpcds_q95")
+WEB = _bench_module("data", "tpcds_web")
+
+
+def _tables(host):
+    def column(a, d):
+        a, valid = a if isinstance(a, tuple) else (a, None)
+        if d.id == dt.STRING.id:
+            return Column.from_pylist(list(a), dt.STRING)
+        return Column.from_numpy(np.ascontiguousarray(a), d, validity=valid)
+
+    return {n: Table([column(a, TYPES[n][c]) for c, a in cols.items()], list(cols)) for n, cols in host.items()}
+
+
+def _frames(host):
+    def series(a):
+        return pd.Series(a[0].astype(np.float64)).where(a[1]) if isinstance(a, tuple) else pd.Series(a)
+
+    return {n: pd.DataFrame({c: series(a) for c, a in cols.items()}) for n, cols in host.items()}
+
+
+def _answer(t):
+    return (int(np.asarray(t.column("order count").data)[0]),
+            float(np.asarray(t.column("total shipping cost").data).view(np.float64)[0]),
+            float(np.asarray(t.column("total net profit").data).view(np.float64)[0]))
+
+
+def _mesh(world):
+    return make_mesh({"data": world}, devices=jax.devices()[:world])
+
+
+def _run(cp):
+    sched = serve.Scheduler(max_concurrent=1, name="mesh-test")
+    try:
+        out = sched.submit(cp).result()
+        jax.block_until_ready([c.data for c in out.columns])
+        return out
+    finally:
+        sched.shutdown()
+
+
+def _exchanges(node, seen=None):
+    seen = {} if seen is None else seen
+    if isinstance(node, pn.Exchange):
+        seen[id(node)] = node
+    for i in node.inputs():
+        _exchanges(i, seen)
+    return list(seen.values())
+
+
+@pytest.mark.parametrize("world", [2, 4])
+@pytest.mark.parametrize("seed", [7, 2_300_000_011, 2_147_483_659])
+def test_q95_on_a_mesh_equals_one_chip_equals_pandas(seed, world):
+    host = WEB.host_tables(CONFIG, seed, ROWS)
+    tables = _tables(host)
+    want = Q95.reference(_frames(host), np.float64).iloc[0]
+    assert want["order count"] > 0  # an empty answer would prove nothing
+    plan = P.insert_exchanges(Q95.plan(P), world, sharded=SHARDED)
+    one = _answer(_run(P.compile_ir(plan, tables, name="q95-one")))  # no mesh: every Exchange is the identity
+    cp = P.compile_ir(plan, tables, name="q95-mesh", mesh=P.MeshBinding(_mesh(world), SHARDED))
+    assert cp.mesh.world == world
+    got = _answer(_run(cp))
+    assert got[0] == one[0] == int(want["order count"])
+    for g, o, w in zip(got[1:], one[1:], (want["total shipping cost"], want["total net profit"])):
+        assert abs(g - w) <= 1e-9 * abs(w) and abs(o - w) <= 1e-9 * abs(w)
+    # the rows stayed on the mesh from the scans to the per-order aggregate
+    kinds = [type(s).__name__ for s in cp.stages]
+    assert kinds.count("_MeshExchangeExec") == 3 and kinds.count("_GatherExec") == 1
+    assert kinds.count("_MeshJoinExec") == 6 and kinds.count("_MeshAggExec") == 2
+
+
+def test_insert_exchanges_shuffles_each_lineage_of_q95_once():
+    plan = P.insert_exchanges(Q95.plan(P), 4, sharded=SHARDED)
+    found = _exchanges(plan)
+    assert sorted(e.keys for e in found) == [("wr_order_number",), ("ws_order_number",), ("ws_order_number",)]
+    assert all(e.world == 4 for e in found)
+    # web_sales for the per-order warehouses; ws1 after the three dimension joins; web_returns
+    under = sorted(type(e.input).__name__ for e in found)
+    assert under == ["Join", "Scan", "Scan"]
+    # the co-keyed stages read rows that are in place: no exchange directly
+    # under the per-order aggregate, under the right side of a semi join
+    # (ws_wh, shared by both), or under the second semi join's left side
+    per_order = plan.input
+    assert isinstance(per_order, pn.Aggregate) and isinstance(per_order.input, pn.Join)
+    j2 = per_order.input
+    j1, returned = j2.left, j2.right
+    assert isinstance(j1, pn.Join) and isinstance(j1.left, pn.Exchange) and not isinstance(j1.right, pn.Exchange)
+    assert isinstance(returned.left, pn.Exchange) and returned.right is j1.right  # ws_wh: computed and shuffled once
+    # and none under a broadcast join: the three dimension joins sit under ws1's one exchange
+    chain = j1.left.input
+    for _ in range(3):
+        assert isinstance(chain, pn.Join) and not _exchanges(chain.right) and not isinstance(chain.left, pn.Exchange)
+        chain = chain.left
+    assert isinstance(chain, pn.Scan)
+    # without ``sharded`` (the cross-process fabric: every table but the fact replicated) every join
+    # is a broadcast join: one exchange under each of the two keyed aggregates, none under a join
+    legacy = _exchanges(P.insert_exchanges(Q95.plan(P), 4))
+    assert sorted(type(e.input).__name__ for e in legacy) == ["Join", "Scan"]
+
+
+def _skewed_tables(n=6000):
+    """One order holds a third of the rows: its bucket overflows the
+    first-try capacity of the exchange."""
+    rng = np.random.default_rng(5)
+    order = np.sort(np.where(np.arange(n) < n // 3, 17, rng.integers(100, 1100, n))).astype(np.int64)
+    wh = rng.integers(1, 11, n).astype(np.int32)
+    cost = rng.integers(0, 100_000, n) / 100.0
+    fact = Table([Column.from_numpy(order, dt.INT64), Column.from_numpy(wh, dt.INT32),
+                  Column.from_numpy(cost, dt.FLOAT64)], ["o", "w", "c"])
+    return fact, pd.DataFrame({"o": order, "w": wh, "c": cost})
+
+
+def test_a_skewed_key_overflows_is_retried_and_answers_right():
+    fact, df = _skewed_tables()
+    plan = pn.Aggregate(pn.Scan("fact"), keys=("o",), aggs=(pn.AggSpec("w", "max", "hi"), pn.AggSpec("c", "sum", "s"),
+                                                           pn.AggSpec(None, "count_all", "n")))
+    plan = P.insert_exchanges(pn.Sort(plan, (("o", True),)), 4, sharded=("fact",))
+    reg = metrics.registry()
+    before = {k: reg.value(f"exchange.{k}") for k in ("overflows", "capacity_retries", "programs", "rows_in")}
+    out = P.compile_ir(plan, {"fact": fact}, name="skew", mesh=P.MeshBinding(_mesh(4), ("fact",)))()
+    after = {k: reg.value(f"exchange.{k}") for k in before}
+    assert after["overflows"] - before["overflows"] >= 1
+    assert after["capacity_retries"] - before["capacity_retries"] >= 1
+    assert after["programs"] - before["programs"] >= 2  # the first try and the larger one
+    want = df.groupby("o").agg(hi=("w", "max"), s=("c", "sum"), n=("c", "size")).reset_index()
+    assert np.asarray(out.column("o").data).tolist() == want.o.tolist()  # no row lost, none doubled
+    assert np.asarray(out.column("n").data).tolist() == want.n.tolist()
+    np.testing.assert_allclose(np.asarray(out.column("s").data).view(np.float64), want.s.to_numpy(), rtol=1e-12)
+    np.testing.assert_array_equal(np.asarray(out.column("hi").data).view(np.float64), want.hi.to_numpy(float))
+
+
+@pytest.mark.parametrize("world", [None, 4])
+def test_a_string_predicate_and_a_string_projection_pass_a_plan(world):
+    """ROADMAP F2, the part q95 meets: a STRING column of a replicated
+    table goes through a Filter (LIKE without a wildcard) and through the
+    pass-through Project that pruning or the author puts around it."""
+    n = 40
+    dim = Table([Column.from_numpy(np.arange(n, dtype=np.int32), dt.INT32),
+                 Column.from_pylist([("IL" if i % 4 == 0 else "TX") for i in range(n)], dt.STRING)], ["k", "state"])
+    fact = Table([Column.from_numpy((np.arange(400) % n).astype(np.int32), dt.INT32),
+                  Column.from_numpy(np.arange(400, dtype=np.int64), dt.INT64)], ["fk", "v"])
+    picked = pn.Project(pn.Filter(pn.Project(pn.Scan("dim"), (("k", P.pcol("k")), ("state", P.pcol("state")))),
+                                  P.plike(P.pcol("state"), "IL")),
+                        (("state", P.pcol("state")), ("k", P.pcol("k"))))
+    plan = pn.Sort(pn.Join(pn.Scan("fact"), picked, on=(("fk", "k"),), how="inner"), (("v", True),))
+    plan = P.insert_exchanges(plan, world or 1, sharded=("fact",))
+    mesh = None if world is None else P.MeshBinding(_mesh(world), ("fact",))
+    out = P.compile_ir(plan, {"fact": fact, "dim": dim}, name="strings", mesh=mesh)()
+    assert np.asarray(out.column("v").data).tolist() == [v for v in range(400) if (v % n) % 4 == 0]
+    assert set(out.column("state").to_pylist()) == {"IL"}
+
+
+def _kinds(cp):
+    return [type(s).__name__ for s in cp.stages]
+
+
+@pytest.mark.parametrize("widths", [(np.int32, np.int64), (np.int64, np.int32), (np.int64, np.int64)])
+@pytest.mark.parametrize("how", ["semi", "anti"])
+def test_keys_of_two_widths_join_right_on_a_mesh(how, widths):
+    """Each side of a shuffled join is routed by a hash of its own key. An
+    INT32 hashes as one block where an INT64 hashes as two, so an exchange
+    widens an integer key before it hashes it: equal VALUES of two widths
+    share a chip, and the co-partitioned join loses no match."""
+    rng = np.random.default_rng(11)
+    lk, rk = rng.integers(-200, 500, 4000).astype(widths[0]), rng.integers(250, 750, 3000).astype(widths[1])
+    kinds = {np.int32: dt.INT32, np.int64: dt.INT64}
+    left = Table([Column.from_numpy(lk, kinds[widths[0]]), Column.from_numpy(np.arange(4000, dtype=np.int64), dt.INT64)],
+                 ["k", "v"])
+    right = Table([Column.from_numpy(rk, kinds[widths[1]])], ["rk"])
+    plan = pn.Sort(pn.Join(pn.Scan("l"), pn.Scan("r"), on=(("k", "rk"),), how=how), (("v", True),))
+    plan = P.insert_exchanges(plan, 4, sharded=("l", "r"))
+    assert sorted(e.keys for e in _exchanges(plan)) == [("k",), ("rk",)]
+    cp = P.compile_ir(plan, {"l": left, "r": right}, name="widths", mesh=P.MeshBinding(_mesh(4), ("l", "r")))
+    hit = np.isin(lk.astype(np.int64), rk.astype(np.int64))
+    assert 0 < hit.sum() < hit.size
+    assert np.asarray(cp().column("v").data).tolist() == np.flatnonzero(hit if how == "semi" else ~hit).tolist()
+    assert _kinds(cp).count("_MeshExchangeExec") == 2 and _kinds(cp).count("_MeshJoinExec") == 1
+    # the layer itself: a value lies on one shard whichever side and width it came in
+    sides = [table_ops.exchange_sharded(table_ops.shard_table(t, _mesh(4)), [k]) for t, k in ((left, "k"), (right, "rk"))]
+    shard_of = {}
+    for st, k in zip(sides, ("k", "rk")):
+        vals, there = np.asarray(st.column(k).data), np.asarray(st.present)
+        for shard, (v, p) in enumerate(zip(np.split(vals, 4), np.split(there, 4))):
+            for value in np.unique(v[p]).tolist():
+                assert shard_of.setdefault(value, shard) == shard, value
+
+
+def test_a_uint64_key_meets_no_signed_key_on_the_mesh():
+    """int64 holds every integer but a UINT64: 2**64 - 5 and -5 are one bit
+    pattern there. The layer refuses the pair, and the plan does not ask."""
+    big = Table([Column.from_numpy(np.array([2**64 - 5, 7], dtype=np.uint64), dt.UINT64)], ["k"])
+    neg = Table([Column.from_numpy(np.array([-5, 7], dtype=np.int64), dt.INT64)], ["rk"])
+    sides = [table_ops.exchange_sharded(table_ops.shard_table(t, _mesh(2)), [k]) for t, k in ((big, "k"), (neg, "rk"))]
+    with pytest.raises(ValueError, match="do not meet in int64"):
+        table_ops.join_sharded(sides[0], sides[1], ("k", "rk"), "semi")
+    for sharded in (("l", "r"), ("l",)):  # against a sharded and against a broadcast right side
+        plan = P.insert_exchanges(pn.Join(pn.Scan("l"), pn.Scan("r"), on=(("k", "rk"),), how="semi"), 2, sharded=sharded)
+        cp = P.compile_ir(plan, {"l": big, "r": neg}, name="u64", mesh=P.MeshBinding(_mesh(2), sharded))
+        assert "_MeshJoinExec" not in _kinds(cp)
+
+
+def test_a_key_that_is_no_integer_groups_behind_a_gather():
+    """The shard-local group-by sorts integer key lanes: a FLOAT64 key (or
+    one beside an integer key) goes to the local tier, and answers."""
+    rng = np.random.default_rng(3)
+    k, j, v = rng.integers(0, 50, 3000) / 4.0, rng.integers(0, 3, 3000).astype(np.int32), rng.integers(0, 1000, 3000)
+    fact = Table([Column.from_numpy(k, dt.FLOAT64), Column.from_numpy(j, dt.INT32), Column.from_numpy(v, dt.INT64)],
+                 ["k", "j", "v"])
+    df = pd.DataFrame({"k": k, "j": j, "v": v})
+    for keys in (("k",), ("j", "k")):
+        plan = pn.Aggregate(pn.Scan("fact"), keys=keys, aggs=(pn.AggSpec("v", "sum", "s"), pn.AggSpec(None, "count_all", "n")))
+        plan = P.insert_exchanges(pn.Sort(plan, tuple((c, True) for c in keys)), 4, sharded=("fact",))
+        cp = P.compile_ir(plan, {"fact": fact}, name="floatkey", mesh=P.MeshBinding(_mesh(4), ("fact",)))
+        out = cp()
+        want = df.groupby(list(keys)).agg(s=("v", "sum"), n=("v", "size")).reset_index()
+        np.testing.assert_array_equal(np.asarray(out.column("k").data).view(np.float64), want.k.to_numpy())
+        assert np.asarray(out.column("s").data).view(np.float64).tolist() == want.s.tolist()  # a plan's sums are FLOAT64
+        assert np.asarray(out.column("n").data).tolist() == want.n.tolist()
+        assert "_MeshAggExec" not in _kinds(cp) and "_GatherExec" in _kinds(cp)
+    # the layer itself takes a key whose lane is an integer array (a FLOAT64's stored bits, as the
+    # operator before it did), and refuses the others
+    real = Table([Column.from_numpy(k.astype(np.float32), dt.FLOAT32), fact.column("v")], ["k", "v"])
+    st = table_ops.exchange_sharded(table_ops.shard_table(real, _mesh(4)), ["k"])
+    with pytest.raises(ValueError, match="integer key lanes"):
+        table_ops.groupby_sharded(st, ["k"], [("v", "sum", "s")])
+
+
+def _spans_of_a_run(tmp_path, enabled):
+    fact, _ = _skewed_tables(1200)
+    plan = pn.Aggregate(pn.Scan("fact"), keys=("o",), aggs=(pn.AggSpec("c", "sum", "s"),))
+    plan = P.insert_exchanges(pn.Join(plan, pn.Scan("fact"), on=(("o", "o"),), how="semi"), 2, sharded=("fact",))
+    base = str(tmp_path / ("on" if enabled else "off"))
+    was = tracing.is_enabled()
+    tracing.set_enabled(enabled)
+    trace_sink.set_log_path(base)
+    try:
+        cp = P.compile_ir(plan, {"fact": fact}, name="spans", mesh=P.MeshBinding(_mesh(2), ("fact",)))
+        _run(cp)
+    finally:
+        trace_sink.close_log()
+        trace_sink.set_log_path(None)
+        tracing.set_enabled(was)
+    import glob
+    import json
+
+    return [json.loads(line) for path in glob.glob(base + ".*.jsonl") for line in open(path)
+            if '"kind": "span"' in line]
+
+
+def test_exchange_spans_and_counters_appear_and_vanish_with_tracing(tmp_path):
+    reg = metrics.registry()
+    before = {k: reg.value(f"exchange.{k}") for k in ("programs", "rows_in", "bytes_offered")}
+    spans = _spans_of_a_run(tmp_path, True)
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    for name in ("exchange.place", "exchange.table", "exchange.groupby", "exchange.join", "exchange.gather"):
+        assert name in by_name, sorted(by_name)
+    for name in ("exchange.table", "exchange.groupby", "exchange.join"):
+        for s in by_name[name]:
+            assert {"rows_in", "keys", "capacity", "parts", "attempt"} <= set(s["annotations"]), s
+            assert s["annotations"]["parts"] == 2
+    assert [s["annotations"]["attempt"] for s in by_name["exchange.table"]][:2] == [0, 1]  # the skew's retry
+    assert {s["annotations"]["how"] for s in by_name["exchange.place"]} == {"sharded"}
+    # they sit under operator spans, so a plan stage's self time does not count them
+    ids = {s["span"]: s["name"] for s in spans}
+    assert all(ids.get(s["parent"], "").startswith("op.") for n in by_name if n.startswith("exchange.")
+               for s in by_name[n])
+    # two exchanges (the aggregate's side carries o and c, the semi join's other side o alone),
+    # two tries each for the skew: 1,200 rows enter each of the four programs
+    after = {k: reg.value(f"exchange.{k}") for k in before}
+    assert after["programs"] - before["programs"] == 4
+    assert after["rows_in"] - before["rows_in"] == 4800
+    assert after["bytes_offered"] - before["bytes_offered"] == 2400 * 16 + 2400 * 8
+    from spark_rapids_jni_tpu import runtime
+
+    assert runtime.stats_report()["metrics"]["counters"]["exchange.programs"] == after["programs"]
+    # tracing off: no span; the counters (registry-direct, like the plan tier's) still count
+    assert _spans_of_a_run(tmp_path, False) == []
+    assert reg.value("exchange.programs") == after["programs"] + 4

@@ -239,9 +239,10 @@ def test_memory_budget_split_retry(mesh8, monkeypatch):
     (the reference's RMM retry / 2 GiB batching discipline)."""
     from spark_rapids_jni_tpu.utils import memory as mem
 
-    # sized so the first escalation (capacity=per_shard=512, ~393KB
-    # per-device) exceeds it but each half's escalation (~196KB) fits
-    monkeypatch.setenv("SRJT_DEVICE_MEMORY_BUDGET", "300000")
+    # nine rows in ten share a key, so the buckets grow to a whole shard's
+    # 512 slots: 22 bytes a slot (two lanes, a route index, a flag), ~180KB a
+    # device, exceeds the budget; each half's 256 slots (~90KB) fit
+    monkeypatch.setenv("SRJT_DEVICE_MEMORY_BUDGET", "160000")
     rng = np.random.default_rng(3)
     n = 4096
     keys = np.where(rng.integers(0, 10, n) < 9, 0, rng.integers(0, 50, n))
@@ -275,3 +276,13 @@ def test_exchange_over_budget_raises_retryable(mesh8, monkeypatch):
     with pytest.raises(MemoryBudgetExceeded) as ei:
         exchange_table(t, ["k"], mesh8)
     assert isinstance(ei.value, RetryableError)  # Spark task-retry class
+
+
+def test_an_empty_table_goes_through_the_mesh_and_comes_back_empty(mesh8):
+    """No row at all: every shard holds one absent slot, so no program is
+    traced over no slots, and the answer keeps its columns."""
+    t = Table([_int_col(np.zeros(0, np.int64), dt.INT64), _int_col(np.zeros(0, np.int64), dt.INT64)], ["k", "v"])
+    out, ovf = exchange_table(t, ["k"], mesh8)
+    assert not ovf and out.num_rows == 0 and list(out.names) == ["k", "v"]
+    out, ovf = distributed_groupby_table(t, ["k"], [("v", "sum", "s"), ("v", "count", "n")], mesh8)
+    assert not ovf and out.num_rows == 0 and list(out.names) == ["k", "s", "n"]
