@@ -8,7 +8,10 @@ the canonical accelerator formulation. Pipeline:
 2. group boundaries from neighbor inequality (nulls compare equal,
    SQL GROUP BY semantics),
 3. ``jax.ops.segment_*`` reductions with num_segments synced to host
-   once (the output-allocation sync every engine pays),
+   once (the output-allocation sync every engine pays); a FLOAT64 sum
+   or mean is ONE device program after that sync (``_f64_sum_mean``:
+   gather, exact accumulation, the mean's long division and the
+   rounding, compiled once per shape and group count and kept),
 4. group keys gathered from each segment's first row.
 
 Supported aggs: sum, count (valid), count_all, min, max, mean,
@@ -24,6 +27,7 @@ promotes float sums to double before they reach this tier).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -33,9 +37,9 @@ import numpy as np
 from ..columnar import Column, Table
 from ..columnar import dtype as dt
 from ..columnar.dtype import TypeId
-from ..utils import tracing
+from ..utils import metrics, tracing
 from ..utils.dispatch import op_boundary
-from . import bitutils
+from . import bitutils, f64acc
 from .copying import gather
 from .sort import sorted_order
 
@@ -67,10 +71,8 @@ def groupby_sum_bounded(
     if f64_bits:  # FLOAT64 bits: exact integer-limb path
         if vals.dtype != jnp.uint64:
             raise ValueError("f64_bits vals must be uint64 IEEE-bit storage")
-        from .f64acc import segment_sum_f64bits
-
         seg = jnp.where((keys >= 0) & (keys < num_keys), keys, num_keys).astype(jnp.int32)
-        sums = segment_sum_f64bits(vals, seg, num_keys + 1)[:num_keys]
+        sums = f64acc.segment_sum_f64bits(vals, seg, num_keys + 1)[:num_keys]
         counts = jax.ops.segment_sum(
             jnp.ones_like(seg, jnp.int64), seg, num_segments=num_keys + 1
         )[:num_keys]
@@ -137,8 +139,56 @@ def _segment_ids(keys: Table, order: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
     return seg, num
 
 
+def _static_groups(num: int) -> int:
+    """The group count ``_f64_sum_mean`` is compiled for: ``num`` rounded
+    up to three significant bits (17 -> 20, 1000 -> 1024; up to 8 as it
+    is), so a stream of batches whose count drifts meets at most eight
+    programs an octave. The segments added are empty, sum to +0.0 and
+    are sliced off."""
+    q = 1 << max(num.bit_length() - 3, 0)
+    return -(-num // q) * q
+
+
+@functools.partial(jax.jit, static_argnames=("num", "how"))
+def _f64_sum_mean(data, validity, order, seg, *, num: int, how: str):
+    """Exact FLOAT64 ``sum`` or ``mean`` of every group as ONE program:
+    the column's u64 lanes and validity gathered through ``order``, then
+    ops/f64acc's windowed integer accumulation, carry normalisation, the
+    long division of a mean and the rounding. Returns (IEEE bits [num],
+    any row valid [num]) — the lanes the un-jitted chain returns, on
+    every backend. ``f64acc``'s public functions stay plain (the fused
+    pipeline and the mesh programs trace them into their own programs);
+    the program boundary is here, at the eager op."""
+    bits = data[order]
+    valid = jnp.ones(order.shape, bool) if validity is None else validity[order]
+    if how == "sum":
+        out_bits = f64acc.segment_sum_f64bits(bits, seg, num, valid=valid)
+    else:
+        out_bits, _ = f64acc.segment_mean_f64bits(bits, seg, num, valid=valid)
+    any_valid = jax.ops.segment_max(valid.astype(jnp.int32), seg, num) > 0
+    return out_bits, any_valid
+
+
+def _is_f64_sum_mean(col: Column, how: str) -> bool:
+    return how in ("sum", "mean") and col.dtype.id == TypeId.FLOAT64
+
+
 def _agg_column(col: Column, order, seg, num, how: str) -> Column:
     d = col.dtype
+    if _is_f64_sum_mean(col, how):
+        # exact on all backends: windowed integer accumulation over
+        # the stored IEEE bits (ops/f64acc) — correctly rounded f64,
+        # bit-identical CPU vs TPU; matches the reference's real-f64
+        # device reduction semantics (cudf segment reduce, SURVEY §2.8)
+        metrics.registry().counter("groupby.agg.jitted").inc()
+        padded = _static_groups(num)
+        out_bits, any_valid = _f64_sum_mean(
+            col.data, col.validity, order, seg, num=padded, how=how
+        )
+        if padded != num:
+            out_bits, any_valid = out_bits[:num], any_valid[:num]
+        return Column(dt.FLOAT64, data=out_bits, validity=any_valid)
+    metrics.registry().counter("groupby.agg.eager").inc()
     sorted_valid = col.valid_mask()[order]
 
     if how == "count_all":
@@ -173,19 +223,6 @@ def _agg_column(col: Column, order, seg, num, how: str) -> Column:
         return Column(d, data=data, validity=any_valid)
 
     if how in ("sum", "mean"):
-        if d.id == TypeId.FLOAT64:
-            # exact on all backends: windowed integer accumulation over
-            # the stored IEEE bits (ops/f64acc) — correctly rounded f64,
-            # bit-identical CPU vs TPU; matches the reference's real-f64
-            # device reduction semantics (cudf segment reduce, SURVEY §2.8)
-            from . import f64acc
-
-            bits = col.data[order]
-            if how == "sum":
-                out_bits = f64acc.segment_sum_f64bits(bits, seg, num, valid=sorted_valid)
-            else:
-                out_bits, _ = f64acc.segment_mean_f64bits(bits, seg, num, valid=sorted_valid)
-            return Column(dt.FLOAT64, data=out_bits, validity=any_valid)
         if d.is_floating:  # FLOAT32
             vals = col.data[order]
             vals = jnp.where(sorted_valid, vals, 0)
@@ -263,8 +300,6 @@ def _var_std_column(col: Column, order, seg, num, how: str, sorted_valid) -> Col
     deviation is formed — var/std of int64 data beyond +-2^48 is
     approximate there, while the real-f64 backend branch keeps the
     full 53-bit f64 mantissa (ADVICE r5 low #5)."""
-    from . import f64acc
-
     d = col.dtype
     if bitutils.backend_has_f64():
         if d.id == TypeId.FLOAT64:
@@ -353,7 +388,10 @@ def groupby_aggregate(
     out_names: List[str] = list(out_keys.names)
     for col_name, how in aggs:
         col = values.column(col_name)
-        with tracing.span(f"groupby.agg.{how}", col=col_name, dtype=col.dtype.id.name):
+        with tracing.span(
+            f"groupby.agg.{how}", col=col_name, dtype=col.dtype.id.name,
+            jit=_is_f64_sum_mean(col, how),
+        ):
             if how == "nunique":
                 out_cols.append(_nunique_column(keys, col, num))
             else:

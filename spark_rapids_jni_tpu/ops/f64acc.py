@@ -206,12 +206,25 @@ def _accumulate_mxu(
         for j in range(8):
             t = t + (acc[:, 8 * k + j] << _I64(4 * j))
         limb_sums.append(t)
+    # The barrier is what keeps a JITTED caller exact on the TPU (ISSUE
+    # 29, ROADMAP F1): with the shift-and-add recombination above and
+    # the carry propagation that follows in one program, the TPU
+    # compiler folds the two and drops carries on some data — one lane
+    # of 16 of 348 q1-shaped sums off by a few units of one nibble
+    # plane, q6's sum by -1.0 and -2049.125 on F1's two seeds — while
+    # the contraction's own output is right. Behind the barrier the
+    # limb sums are opaque values, as they are when every step is its
+    # own program (0 of 348 wrong; benchmarks/calls/pr29_bisect.py).
     return _GroupSum(
-        jnp.stack(limb_sums, axis=-1),
-        emax,
-        acc[:, 8 * LIMBS] > 0,
-        acc[:, 8 * LIMBS + 1] > 0,
-        acc[:, 8 * LIMBS + 2] > 0,
+        *lax.optimization_barrier(
+            (
+                jnp.stack(limb_sums, axis=-1),
+                emax,
+                acc[:, 8 * LIMBS] > 0,
+                acc[:, 8 * LIMBS + 1] > 0,
+                acc[:, 8 * LIMBS + 2] > 0,
+            )
+        )
     )
 
 

@@ -11,6 +11,7 @@ addend, asserted as <= 1e-15 relative here.
 import math
 from fractions import Fraction
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -284,6 +285,18 @@ class TestMxuPathIdentity:
         mean_p, cnt_p = segment_mean_f64bits(b, seg, 9, valid=valid)
         assert np.array_equal(np.asarray(mean_m), np.asarray(mean_p))
         assert np.array_equal(np.asarray(cnt_m), np.asarray(cnt_p))
+
+    @pytest.mark.parametrize("budget,barriers", [(1 << 28, 1), (-1, 0)])
+    def test_mxu_branch_hands_its_limb_sums_on_behind_a_barrier(self, monkeypatch, budget, barriers):
+        # ROADMAP F1 / ISSUE 29: in ONE program the TPU compiler folds the
+        # nibble recombination into the carry propagation and drops
+        # carries; the barrier is the mend (the CPU cannot see its
+        # effect, so the trace is what is held). The payload branch,
+        # which the mesh programs take, has none and stays as it was.
+        monkeypatch.setattr(f64acc, "_MXU_ONEHOT_BUDGET", budget)
+        jaxpr = jax.make_jaxpr(lambda b, s: segment_sum_f64bits(b, s, 3))(
+            jnp.zeros((64,), jnp.uint64), jnp.zeros((64,), jnp.int32))
+        assert str(jaxpr).count("optimization_barrier") == barriers
 
     def test_mxu_chunking_exact(self, rng, monkeypatch):
         # force multi-chunk matmuls and check against the payload path

@@ -185,6 +185,40 @@ def test_groupby_phase_spans_are_children_of_the_operator(q1_spans):
                if n.startswith("groupby.agg.") for s in ss)
 
 
+def test_agg_spans_say_which_went_through_the_one_program(q1_spans):
+    """ISSUE 29: a FLOAT64 sum or mean is one jitted program, said by the
+    span's ``jit``; and nothing compiles in a request after the first
+    (``_limb_divide``'s scan body was a new closure a call before)."""
+    jit = sorted((s["name"], s["annotations"]["col"], s["annotations"]["jit"])
+                 for s in q1_spans if s["name"].startswith("groupby.agg."))
+    assert jit == [
+        ("groupby.agg.count_all", "flag", False),
+        ("groupby.agg.mean", "qty", True),
+        ("groupby.agg.sum", "disc_price", True),
+        ("groupby.agg.sum", "qty", True),
+    ]
+    assert [s for s in q1_spans if s["name"] == "xla.compile"] == []
+
+
+def test_agg_counters_tell_the_one_program_from_the_eager_branches():
+    from spark_rapids_jni_tpu import runtime
+
+    assert not tracing.is_enabled()  # registry-direct: counted with tracing off
+    keys = Table([Column.from_numpy(np.array([1, 1, 2], np.int32), dt.INT32)], ["k"])
+    vals = Table([Column.from_numpy(np.array([1.5, 2.25, 3.0]), dt.FLOAT64),
+                  Column.from_numpy(np.array([1, 2, 3], np.int64), dt.INT64),
+                  Column.from_numpy(np.array([1, 2, 3], np.float32), dt.FLOAT32)], ["f", "i", "g"])
+    jitted, eager = _counter("groupby.agg.jitted"), _counter("groupby.agg.eager")
+    out = groupby_aggregate(keys, vals, [("f", "sum"), ("f", "mean"), ("f", "min"), ("i", "sum"),
+                                         ("g", "mean"), ("f", "count"), ("f", "std")])
+    assert np.asarray(out.column("f_sum").data).view(np.float64).tolist() == [3.75, 3.0]
+    assert np.asarray(out.column("f_mean").data).view(np.float64).tolist() == [1.875, 3.0]
+    assert _counter("groupby.agg.jitted") == jitted + 2
+    assert _counter("groupby.agg.eager") == eager + 5
+    counters = runtime.stats_report()["metrics"]["counters"]
+    assert counters["groupby.agg.jitted"] >= 2 and counters["groupby.agg.eager"] >= 5
+
+
 @pytest.mark.parametrize("whole,parts", [
     ("op.groupby_aggregate", "groupby."),
     ("serve.run", "plan."),

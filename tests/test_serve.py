@@ -387,6 +387,7 @@ class TestOverload:
         from spark_rapids_jni_tpu import sidecar
 
         br = sidecar.breaker()
+        was = br.snapshot()
         br.configure(threshold=1, cooldown_s=60)
         try:
             br.record_failure("test: pool dark")
@@ -398,7 +399,11 @@ class TestOverload:
             assert sched.submit(lambda: "host ok", tenant="u").result(10) \
                 == "host ok"
         finally:
-            br.configure()  # restore env-default knobs + CLOSED
+            # configure() with no values keeps the knobs it has: a leaked
+            # threshold=1 opens the process-global breaker on the first
+            # failure of whatever supervision test this worker runs next
+            # (tests/test_chaos.py then never reaches its worker)
+            br.configure(threshold=was["threshold"], cooldown_s=was["cooldown_s"])
 
 
 # ---------------------------------------------------------------------------
