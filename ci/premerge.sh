@@ -4,6 +4,9 @@
 # check, and a bench smoke. Run from the repo root.
 set -euo pipefail
 
+# built here for the JNI harness below; every pytest tier builds (or
+# refreshes) native/build/libsrjt.so itself before it collects
+# (tests/conftest.py), so no test count depends on this line
 cmake -S native -B native/build -G Ninja
 ninja -C native/build
 

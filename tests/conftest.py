@@ -22,13 +22,222 @@ if os.environ.get("SRJT_TEST_TPU", "0") != "1":  # srjt-lint: allow-environ(boot
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_compilation_cache", False)
 
+import fcntl  # noqa: E402
+import shutil  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
 def rng():
     return np.random.default_rng(12345)
+
+
+# ---------------------------------------------------------------------------
+# the native library: built before any test file is imported
+# ---------------------------------------------------------------------------
+# runtime.native_lib() keeps its first answer for the life of the process
+# and every xdist worker imports every test file at collection, so "is
+# libsrjt.so there" has to be settled before the workers start: the
+# controller builds it in pytest_configure and the `native` fixture below
+# is the one gate the tests go through.
+
+_NATIVE_ABSENT = "native library absent"  # the gate's skip reason starts with this
+_native_status = "not looked for"
+
+
+def _build_native():
+    """Bring native/build/libsrjt.so up to date and return the header's
+    line. No toolchain is a supported platform (the native tests skip); a
+    toolchain whose build fails, or whose library does not load, is a
+    usage error."""
+    build = os.path.join(REPO, "native", "build")
+    so = os.path.join(build, "libsrjt.so")
+    missing = " or ".join(t for t in ("cmake", "ninja") if shutil.which(t) is None)
+    if missing and not os.path.exists(so):
+        return f"{_NATIVE_ABSENT}: no {missing} and no prebuilt {so}"
+    if missing:
+        how = f"prebuilt, no {missing} to refresh it"
+    else:
+        os.makedirs(build, exist_ok=True)
+        with open(os.path.join(build, ".pytest-build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)  # two pytest runs in one tree do not build at once
+            was = os.path.getmtime(so) if os.path.exists(so) else None
+            cmds = [["ninja", "-C", build]]  # every run: a no-op when fresh, and no stale .so survives
+            if not os.path.exists(os.path.join(build, "build.ninja")):
+                cmds.insert(0, ["cmake", "-S", os.path.join(REPO, "native"), "-B", build, "-G", "Ninja"])
+            for cmd in cmds:
+                done = subprocess.run(cmd, capture_output=True, text=True)
+                if done.returncode != 0:
+                    out = done.stdout + done.stderr
+                    at = max(out.find("FAILED:"), 0)  # ninja's first failure, not the jobs that ended after it
+                    raise pytest.UsageError(
+                        f"building the native library failed ({' '.join(cmd)}):\n{out[at:at + 4000]}"
+                    )
+        how = "found up to date" if was == os.path.getmtime(so) else "built now"
+    from spark_rapids_jni_tpu import runtime
+
+    if runtime.native_available():
+        return f"native library {how}: {so}"
+    if missing:
+        return f"{_NATIVE_ABSENT}: the prebuilt {so} does not load and there is no {missing}"
+    raise pytest.UsageError(f"{so} was built but does not load (runtime.native_lib())")
+
+
+def pytest_configure(config):
+    global _native_status
+    if not hasattr(config, "workerinput"):  # xdist starts its workers after this
+        _native_status = _build_native()
+
+
+def pytest_report_header(config):
+    return _native_status
+
+
+def pytest_terminal_summary(terminalreporter):
+    if _native_status.startswith(_NATIVE_ABSENT):
+        cost = sum(
+            _NATIVE_ABSENT in str(rep.longrepr)
+            for rep in terminalreporter.stats.get("skipped", [])
+        )
+        terminalreporter.write_line(f"{_native_status}: {cost} tests skipped for it")
+
+
+@pytest.fixture(scope="session")
+def native():
+    """The one gate of the tests that need libsrjt.so: the bound runtime
+    module, or a skip where the platform has no toolchain."""
+    from spark_rapids_jni_tpu import runtime
+
+    if not runtime.native_available():
+        pytest.skip(f"{_NATIVE_ABSENT} (the run's header says why)")
+    return runtime
+
+
+# ---------------------------------------------------------------------------
+# process-global state: what "pristine" is, written once
+# ---------------------------------------------------------------------------
+
+
+def _scrub_worker_namespace(metrics):
+    """The in-process worker (tests/_inproc.py) runs ``_handle_conn`` in
+    THIS process, so its always-on request COUNTERS share the registry
+    with the ``sidecar.worker.*`` GAUGES other files fold remote
+    snapshots into — a type clash the two-process deployment can never
+    hit. Dropping the namespace serves both orders."""
+    reg = metrics.registry()
+    with reg._lock:
+        for name in [n for n in reg._metrics if n.startswith("sidecar.worker.")]:
+            del reg._metrics[name]
+
+
+def reset_process_state():
+    """Put every process-global switch of the package back where a fresh
+    process has it (the chaos switches off, whatever the environment
+    armed: the tiers of ci/premerge.sh that arm them read the knobs
+    themselves) and return what was found out of place, one phrase each.
+    Singletons that are caches (memgov's catalog, the plan and subresult
+    caches, the sketches, the journal) are dropped and not reported,
+    except an out-of-core partition entry nobody released. Lazy
+    sys.modules look-ups: a file that never touched a subsystem does not
+    import it here."""
+
+    def mod(name):
+        return sys.modules.get("spark_rapids_jni_tpu." + name)
+
+    dirty = []
+
+    def put_back(what, now, pristine, restore):
+        if now != pristine:
+            dirty.append(f"{what} {now!r} (a fresh process has {pristine!r})")
+        restore()
+
+    knobs = mod("utils.knobs")
+    if (m := mod("utils.faultinj")) is not None:
+        put_back("fault injection enabled", m.is_enabled(), False, m.disable)
+    if (m := mod("utils.retry")) is not None:
+        put_back("retry enabled", m.is_enabled(), False, m.disable)
+        m.reset_stats()
+    if (m := mod("utils.deadline")) is not None:
+        put_back("default deadline budget", m.default_budget(), None,
+                 lambda: m.set_default_budget(None))
+    if (m := mod("sidecar")) is not None and m._BREAKER is not None:
+        br, keys = m._BREAKER, ("state", "threshold", "cooldown_s")
+        # a fresh one reads the knobs, as breaker() did
+        fresh = mod("utils.deadline").CircuitBreaker(br.name).snapshot()
+        now = br.snapshot()
+        put_back("sidecar breaker", {k: now[k] for k in keys}, {k: fresh[k] for k in keys},
+                 lambda: br.configure(threshold=fresh["threshold"], cooldown_s=fresh["cooldown_s"]))
+    if (m := mod("parallel.shuffle")) is not None:
+        br = m.exchange_breaker()
+        opened = sorted(a for a, snap in br.snapshot().items() if snap["state"] != "closed")
+        put_back("exchange breakers not closed:", opened, [], br.reset)
+    if (m := mod("utils.tracing")) is not None:
+        env = knobs.get_bool("SRJT_TRACE_ENABLED")
+        put_back("tracing enabled", m.is_enabled(), env, lambda: m.set_enabled(env))
+    if (m := mod("utils.metrics")) is not None:
+        env = knobs.get_bool("SRJT_METRICS_ENABLED")
+        put_back("metrics enabled", m.is_enabled(), env, m.enable if env else m.disable)
+        _scrub_worker_namespace(m)
+    if (m := mod("memgov")) is not None:
+        # every path of an out-of-core plan releases its partition entries
+        # (OutOfCorePlan._release): a survivor is leaked spill bytes plus a
+        # stale checkpoint a later run could wrongly resume from
+        left = m._catalog.kind_stats("partition") if m._catalog is not None else (0, 0)
+        put_back("out-of-core partition catalog (entries, bytes)", left, (0, 0), m.reset)
+        m._enabled = m._env_enabled()
+    for name in ("cache", "plan.stats", "serve.journal"):
+        if (m := mod(name)) is not None:
+            m.reset()
+    return dirty
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _file_leaves_process_state_pristine(request):
+    """What makes a worker's next file independent of its last one,
+    whatever --dist dealt: pristine state before a file's first test, and
+    after its last one a tripwire in the style of the session ones below
+    — what the file left out of place is put back and then fails the
+    file's teardown by name. Mend a finding in the test that made it
+    (the ``clean_state`` fixture, or try/finally)."""
+    reset_process_state()
+    yield
+    dirty = reset_process_state()
+    assert not dirty, f"{request.module.__name__} left process-global state behind: " + "; ".join(dirty)
+
+
+@pytest.fixture
+def clean_state():
+    """Pristine process state around ONE test; the files whose tests arm
+    fault injection, retry, budgets or breakers opt in with
+    ``pytestmark = pytest.mark.usefixtures("clean_state")``."""
+    reset_process_state()
+    yield
+    reset_process_state()
+
+
+@pytest.fixture
+def own_span_log(clean_state, tmp_path):
+    """Tracing off (the premerge trace tier arms it process-wide; a test
+    scopes it with ``tracing.enabled()``), a fresh flight recorder and a
+    span log of the test's own under tmp_path. The env-configured base
+    (that tier's artifacts path) is put back afterwards, so the real-pool
+    acceptance — which uses the env path on purpose — still archives its
+    spans; ``clean_state`` puts the arming back."""
+    from spark_rapids_jni_tpu.utils import trace_sink, tracing
+
+    prev_base = trace_sink.log_path()
+    tracing.set_enabled(False)
+    trace_sink.reset_for_tests()
+    trace_sink.set_log_path(str(tmp_path / "spans.jsonl"))
+    yield
+    trace_sink.reset_for_tests()
+    trace_sink.set_log_path(prev_base)
 
 
 @pytest.fixture(autouse=True, scope="session")
@@ -112,27 +321,6 @@ def _assert_no_spill_file_leak():
         f"{len(leaked)} spill file(s) leaked past session teardown: "
         f"{sorted(leaked)[:10]}"
     )
-
-
-@pytest.fixture(autouse=True, scope="session")
-def _assert_no_partition_entry_leak():
-    """ISSUE 18 leak tripwire (mirrors the slab/scheduler checks): every
-    out-of-core partition catalog entry (kind="partition") registered
-    during the session must be unregistered by session end — success,
-    failure, deadline expiry, and chaos paths all release them
-    (OutOfCorePlan._release). A surviving entry is leaked spill bytes
-    plus a stale checkpoint a later run could wrongly resume from. Lazy
-    sys.modules lookup: runs only when the suite touched memgov."""
-    yield
-    import sys as _sys
-
-    memgov_mod = _sys.modules.get("spark_rapids_jni_tpu.memgov")
-    if memgov_mod is not None and memgov_mod._catalog is not None:
-        entries, nbytes = memgov_mod._catalog.kind_stats("partition")
-        assert (entries, nbytes) == (0, 0), (
-            f"{entries} out-of-core partition catalog entrie(s) "
-            f"({nbytes} bytes) leaked past session teardown"
-        )
 
 
 # ---------------------------------------------------------------------------

@@ -45,17 +45,12 @@ def _delta(before, after):
 
 
 @pytest.fixture(autouse=True)
-def _clean_cache(monkeypatch):
+def _caches_off(monkeypatch, clean_state):
     # every test starts from the shipped OFF posture, even when the CI
     # tier exports the cache knobs process-wide (the premerge cache
     # tier does) — tests that want the caches armed say so via `armed`
     monkeypatch.delenv("SRJT_PLAN_CACHE", raising=False)
     monkeypatch.delenv("SRJT_SUBRESULT_CACHE", raising=False)
-    cache.reset()
-    faultinj.disable()
-    yield
-    cache.reset()
-    faultinj.disable()
 
 
 @pytest.fixture

@@ -37,15 +37,7 @@ _STORM_PATH = os.path.join(
 )
 
 
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 @pytest.fixture(scope="module")

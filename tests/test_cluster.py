@@ -55,16 +55,7 @@ def _counter(name):
     return metrics.registry().value(name)
 
 
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    shuffle.exchange_breaker().reset()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 def _probe_err():

@@ -45,29 +45,13 @@ from spark_rapids_jni_tpu.utils import (
 )
 from spark_rapids_jni_tpu.utils.errors import DataCorruption, RetryableError
 
-from test_sidecar_pool import (  # the in-proc worker/scrub harness
-    _InProcWorker,
-    _groupby_payload,
-    _inproc_spawn,
-    _scrub_worker_namespace,
-)
+from _inproc import InProcWorker, groupby_payload, inproc_spawn
+
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 def _counter(name):
     return metrics.registry().value(name)
-
-
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +186,7 @@ class TestFrameRoundtrip:
 
 class TestFramedWire:
     def test_worker_echoes_request_table_format(self):
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             client = sidecar.SupervisedClient(
                 w.sock_path, deadline_s=20, heartbeat_s=1e9
@@ -342,11 +326,11 @@ class TestPoolConcurrency:
         single-buffer arena serialized all pool traffic on one lock, so
         this barrier would time out by construction."""
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn,
+            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn,
             slab_bytes=1 << 20,
         )
         try:
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             faultinj.configure(
                 {"faults": {"sidecar.worker.GROUPBY_SUM_F32": {
@@ -396,11 +380,11 @@ class TestPoolConcurrency:
         worker (the client rewrites and re-sends), never with foreign
         bytes."""
         pool = sidecar_pool.SidecarPool(
-            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn,
+            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn,
             slab_bytes=1 << 20,
         )
         try:
-            payload = _groupby_payload()
+            payload = groupby_payload()
             region = pool.lease(len(payload))
             region.write(payload)
             # corrupt the in-slab header's generation behind the pool
@@ -429,11 +413,11 @@ class TestPoolConcurrency:
         answer through the stream and leave the slab untouched —
         writing would clobber the retry attempt's bytes."""
         pool = sidecar_pool.SidecarPool(
-            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn,
+            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn,
             slab_bytes=1 << 20,
         )
         try:
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             region = pool.lease(len(payload))
             region.write(payload)

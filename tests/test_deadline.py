@@ -40,22 +40,7 @@ _HANG_PATH = os.path.join(
 )
 
 
-@pytest.fixture(autouse=True)
-def _clean_state():
-    # configure() resets state AND restores the default knobs — tests
-    # here re-tune threshold/cooldown, and a leaked threshold=1 would
-    # trip the global breaker under other files' supervision tests
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    deadline.set_default_budget(None)
-    sidecar.breaker().configure(threshold=5, cooldown_s=30.0)
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    deadline.set_default_budget(None)
-    sidecar.breaker().configure(threshold=5, cooldown_s=30.0)
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 # ---------------------------------------------------------------------------

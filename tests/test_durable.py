@@ -48,15 +48,10 @@ def _delta(before, after):
 
 
 @pytest.fixture(autouse=True)
-def _clean(monkeypatch):
+def _durability_off(monkeypatch, clean_state):
     monkeypatch.delenv("SRJT_JOURNAL_DIR", raising=False)
     monkeypatch.delenv("SRJT_SPILL_MANIFESTS", raising=False)
     monkeypatch.delenv("SRJT_OOC_DURABLE_CHECKPOINTS", raising=False)
-    JM.reset()
-    faultinj.disable()
-    yield
-    JM.reset()
-    faultinj.disable()
 
 
 def _tables(rows=96):

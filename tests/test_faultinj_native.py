@@ -20,8 +20,7 @@ from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.columnar import dtype as dt
 from spark_rapids_jni_tpu.utils.errors import FatalDeviceError, RetryableError
 
-if not runtime.native_available():  # pragma: no cover
-    pytest.skip("native runtime not built", allow_module_level=True)
+pytestmark = pytest.mark.usefixtures("native")
 
 
 def _zorder_table():

@@ -13,9 +13,7 @@ import pytest
 import spark_rapids_jni_tpu  # noqa: F401
 from spark_rapids_jni_tpu import runtime
 
-pytestmark = pytest.mark.skipif(
-    not runtime.native_available(), reason="native library not built"
-)
+pytestmark = pytest.mark.usefixtures("native")
 
 EOF_MARKER = bytes([0x11, 0x00, 0x00])
 

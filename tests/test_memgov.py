@@ -35,19 +35,7 @@ _MEMGOV_CHAOS = os.path.join(
 )
 
 
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    memgov.reset()
-    memgov._enabled = memgov._env_enabled()  # gate back to the env posture
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    memgov.reset()
-    memgov._enabled = memgov._env_enabled()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 @pytest.fixture(scope="module")

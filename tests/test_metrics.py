@@ -22,21 +22,13 @@ from spark_rapids_jni_tpu.utils.errors import FatalDeviceError, RetryableError
 
 
 @pytest.fixture(autouse=True)
-def _clean_state():
-    """Metrics may arrive armed from the environment (the premerge
-    observability tier runs this file with SRJT_METRICS_ENABLED=1);
-    every test pins its own arming and leaves a zeroed registry."""
-    prev = metrics.is_enabled()
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
+def _zeroed_registry(clean_state):
+    """Every test pins its own arming (``clean_state`` puts back what the
+    environment says: the premerge observability tier runs this file with
+    SRJT_METRICS_ENABLED=1) and leaves a zeroed registry."""
     metrics.reset()
     yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
     metrics.reset()
-    (metrics.enable if prev else metrics.disable)()
 
 
 # ---------------------------------------------------------------------------

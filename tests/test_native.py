@@ -3,43 +3,15 @@
 row kernels, row_conversion.cpp:43-60, applied across languages), plus
 handle/leak accounting and host buffers.
 
-Builds native/build/libsrjt.so on demand if a toolchain is present;
-skips otherwise.
+tests/conftest.py builds native/build/libsrjt.so before collection; its
+``native`` fixture skips these where there is no toolchain.
 """
 
 import io
-import os
-import shutil
-import struct
-import subprocess
 
 import pyarrow as pa
 import pyarrow.parquet as pq
 import pytest
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-@pytest.fixture(scope="session")
-def native():
-    so = os.path.join(REPO, "native", "build", "libsrjt.so")
-    if not os.path.exists(so):
-        if shutil.which("cmake") is None or shutil.which("ninja") is None:
-            pytest.skip("no native toolchain and no prebuilt libsrjt.so")
-        subprocess.run(
-            ["cmake", "-S", os.path.join(REPO, "native"), "-B",
-             os.path.join(REPO, "native", "build"), "-G", "Ninja"],
-            check=True, capture_output=True,
-        )
-        subprocess.run(
-            ["ninja", "-C", os.path.join(REPO, "native", "build")],
-            check=True, capture_output=True,
-        )
-    from spark_rapids_jni_tpu import runtime
-
-    if not runtime.native_available():
-        pytest.skip("libsrjt.so failed to load")
-    return runtime
 
 
 def make_parquet(table: pa.Table, row_group_size=None) -> bytes:

@@ -24,9 +24,7 @@ from spark_rapids_jni_tpu.ops import zorder as zo
 from spark_rapids_jni_tpu.ops.cast_decimal import string_to_decimal
 from spark_rapids_jni_tpu.ops.cast_string import string_to_integer
 
-pytestmark = pytest.mark.skipif(
-    not runtime.native_available(), reason="native library not built"
-)
+pytestmark = pytest.mark.usefixtures("native")
 
 
 def col_from(vals, d):

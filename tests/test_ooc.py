@@ -28,7 +28,7 @@ import spark_rapids_jni_tpu  # noqa: F401
 from spark_rapids_jni_tpu import memgov
 from spark_rapids_jni_tpu import plan as P
 from spark_rapids_jni_tpu.models.tpch import gen_lineitem
-from spark_rapids_jni_tpu.utils import deadline, faultinj, metrics, retry
+from spark_rapids_jni_tpu.utils import deadline, faultinj, metrics
 from spark_rapids_jni_tpu.utils.errors import DeadlineExceeded, RetryableError
 
 _OOC_CHAOS = os.path.join(
@@ -37,19 +37,7 @@ _OOC_CHAOS = os.path.join(
 )
 
 
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    memgov.reset()
-    memgov._enabled = memgov._env_enabled()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    memgov.reset()
-    memgov._enabled = memgov._env_enabled()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 @pytest.fixture

@@ -155,11 +155,7 @@ def test_nested_supported():
     assert read_table(write(t)).column("l").to_pylist() == [[1, 2]]
 
 
-def test_lz4_codec_native():
-    from spark_rapids_jni_tpu import runtime
-
-    if not runtime.native_available():
-        pytest.skip("native runtime not built")
+def test_lz4_codec_native(native):
     check_roundtrip(BASIC, compression="lz4")
 
 

@@ -229,13 +229,9 @@ def test_lz4_hadoop_framing():
     assert _lz4_hadoop(frame, len(plain)) is None
 
 
-def test_zstd_decodes_through_native_tier(monkeypatch):
+def test_zstd_decodes_through_native_tier(native, monkeypatch):
     """The zstd path must run on the native codec (nvcomp analog), not
     the pyarrow fallback."""
-    from spark_rapids_jni_tpu import runtime
-
-    if not runtime.native_available():
-        pytest.skip("native runtime not built")
     import pyarrow as pa_mod
 
     def _boom(*a, **k):

@@ -32,18 +32,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(REPO, "bench")
 
 
-@pytest.fixture(autouse=True)
-def _own_span_log(tmp_path):
-    """Tracing off and a span log of the test's own, as tests/test_tracing.py
-    has it; whatever the process had is put back."""
-    prev_base, prev_enabled = trace_sink.log_path(), tracing.is_enabled()
-    tracing.set_enabled(False)
-    trace_sink.reset_for_tests()
-    trace_sink.set_log_path(str(tmp_path / "spans.jsonl"))
-    yield
-    trace_sink.reset_for_tests()
-    trace_sink.set_log_path(prev_base)
-    tracing.set_enabled(prev_enabled)
+pytestmark = pytest.mark.usefixtures("own_span_log")
 
 
 def _read_logs(pattern):
@@ -457,19 +446,20 @@ def traced_convert(tmp_path_factory):
         tracing.set_enabled(False)
         trace_sink.close_log()
         stats = pool.worker_stats(fold=False)["w0"]
+        # the worker's handler thread closes its last span (the reply's write)
+        # and writes its log AFTER the client has the reply: wait for it while
+        # the worker lives (shutdown's SIGTERM takes an unwritten log with it)
+        end = time.monotonic() + 10.0
+        while True:
+            spans = [r for r in _read_logs(base + ".*.jsonl") if r.get("kind") == "span"]
+            if any(s["name"] == "sidecar.worker.reply_write" for s in spans) or time.monotonic() > end:
+                break
+            time.sleep(0.02)
     finally:
         tracing.set_enabled(prev_enabled)
         trace_sink.set_log_path(prev_base)
         pool.shutdown()
     assert reply == want
-    # the worker's handler thread closes its last span (the reply's write)
-    # and writes its log AFTER the client has the reply: wait for it
-    end = time.monotonic() + 10.0
-    while True:
-        spans = [r for r in _read_logs(base + ".*.jsonl") if r.get("kind") == "span"]
-        if any(s["name"] == "sidecar.worker.reply_write" for s in spans) or time.monotonic() > end:
-            break
-        time.sleep(0.02)
     return {"spans": spans, "request_bytes": len(payload), "reply_bytes": len(want),
             "stats": stats, "cols": 3}
 
@@ -537,8 +527,8 @@ def test_the_request_is_covered_by_its_phases(traced_convert):
     were given of a loaded machine: the client's phases are the request's
     children, lie inside it one after the other and leave it no tenth of
     its time; the worker's phases are its children in the other process,
-    all begun while the client waited, and they cover what the worker
-    did between its first and its last."""
+    all begun between the client's send and the end of its wait, and they
+    cover what the worker did between its first and its last."""
     spans = traced_convert["spans"]
     request = _one(spans, "sidecar.request")
     tol = 2e-3  # a span's start is read on the wall clock, its length on the monotonic one
@@ -558,7 +548,9 @@ def test_the_request_is_covered_by_its_phases(traced_convert):
                     key=lambda s: s["ts"])
     assert [s["name"] for s in worker if s["name"] != "integrity.crc"] == [
         "sidecar.worker.payload_read", "sidecar.worker_op", "sidecar.worker.reply_write"]
-    assert all(wait["ts"] - tol <= s["ts"] <= end(wait) + tol for s in worker)
+    # the worker has the request once sendall has returned: on a loaded machine that is before
+    # the client's thread gets to open its wait, so the worker's first span is held to the send
+    assert all(phases[0]["ts"] - tol <= s["ts"] <= end(wait) + tol for s in worker)
     # the reply's write is closed after the client has the reply; every other phase ends inside the wait
     assert all(end(s) <= end(wait) + tol for s in worker[:-1])
     assert all(end(a) <= b["ts"] + tol for a, b in zip(worker, worker[1:]))

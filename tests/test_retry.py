@@ -19,15 +19,7 @@ from spark_rapids_jni_tpu.utils import errors, faultinj, retry
 from spark_rapids_jni_tpu.utils.memory import MemoryBudgetExceeded
 
 
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 def _policy(**kw):

@@ -364,46 +364,35 @@ class TestOverload:
         # the dispatch slot survived user code calling sys.exit
         assert sched.submit(lambda: "alive", tenant="u").result(10) == "alive"
 
-    def test_injected_reject_sheds_deterministically(self, sched):
+    def test_injected_reject_sheds_deterministically(self, sched, clean_state):
         """Satellite: faultinj's `reject` kind keyed serve.admit forces
         shed decisions without real overload."""
         before = metrics.registry().value("serve.shed.injected")
         faultinj.configure({"faults": {"serve.admit": {
             "type": "reject", "percent": 100, "delayMs": 125,
             "interceptionCount": 2}}})
-        try:
-            for _ in range(2):
-                with pytest.raises(Overloaded) as ei:
-                    sched.submit(lambda: 1, tenant="u")
-                assert ei.value.cause == "injected"
-                assert ei.value.retry_after_s == pytest.approx(0.125)
-            # budget exhausted: the third submission admits
-            assert sched.submit(lambda: 3, tenant="u").result(10) == 3
-        finally:
-            faultinj.disable()
+        for _ in range(2):
+            with pytest.raises(Overloaded) as ei:
+                sched.submit(lambda: 1, tenant="u")
+            assert ei.value.cause == "injected"
+            assert ei.value.retry_after_s == pytest.approx(0.125)
+        # budget exhausted: the third submission admits
+        assert sched.submit(lambda: 3, tenant="u").result(10) == 3
         assert metrics.registry().value("serve.shed.injected") == before + 2
 
-    def test_breaker_dark_pool_sheds_device_only_work(self, sched):
+    def test_breaker_dark_pool_sheds_device_only_work(self, sched, clean_state):
         from spark_rapids_jni_tpu import sidecar
 
         br = sidecar.breaker()
-        was = br.snapshot()
         br.configure(threshold=1, cooldown_s=60)
-        try:
-            br.record_failure("test: pool dark")
-            assert br.state() == "open"
-            with pytest.raises(Overloaded) as ei:
-                sched.submit(lambda: 1, tenant="u", host_eligible=False)
-            assert ei.value.cause == "breaker"
-            # host-engine-eligible work keeps flowing while dark
-            assert sched.submit(lambda: "host ok", tenant="u").result(10) \
-                == "host ok"
-        finally:
-            # configure() with no values keeps the knobs it has: a leaked
-            # threshold=1 opens the process-global breaker on the first
-            # failure of whatever supervision test this worker runs next
-            # (tests/test_chaos.py then never reaches its worker)
-            br.configure(threshold=was["threshold"], cooldown_s=was["cooldown_s"])
+        br.record_failure("test: pool dark")
+        assert br.state() == "open"
+        with pytest.raises(Overloaded) as ei:
+            sched.submit(lambda: 1, tenant="u", host_eligible=False)
+        assert ei.value.cause == "breaker"
+        # host-engine-eligible work keeps flowing while dark
+        assert sched.submit(lambda: "host ok", tenant="u").result(10) \
+            == "host ok"
 
 
 # ---------------------------------------------------------------------------

@@ -16,8 +16,7 @@ import pytest
 import spark_rapids_jni_tpu  # noqa: F401
 from spark_rapids_jni_tpu import runtime
 
-if not runtime.native_available():  # pragma: no cover
-    pytest.skip("native runtime not built", allow_module_level=True)
+pytestmark = pytest.mark.usefixtures("native")
 
 
 @pytest.fixture(scope="module")

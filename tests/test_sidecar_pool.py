@@ -43,124 +43,23 @@ from spark_rapids_jni_tpu.columnar import dtype as dt
 from spark_rapids_jni_tpu.utils import errors, faultinj, integrity, metrics, retry
 from spark_rapids_jni_tpu.utils.errors import DataCorruption, RetryableError
 
+from _inproc import InProcWorker, groupby_payload, inproc_spawn
+
 
 def _counter(name):
     return metrics.registry().value(name)
 
 
-def _scrub_worker_namespace():
-    """The in-process worker trick below runs ``_handle_conn`` in THIS
-    process, so its always-on request COUNTERS share the registry with
-    the ``sidecar.worker.*`` GAUGES other suite files fold remote
-    snapshots into — a type clash the two-process deployment can never
-    hit. Scrub the namespace both ways (before: earlier folds must not
-    break the in-proc worker; after: the in-proc counters must not
-    break a later fold under randomized test ordering)."""
-    reg = metrics.registry()
-    with reg._lock:
-        for name in list(reg._metrics):
-            if name.startswith("sidecar.worker."):
-                del reg._metrics[name]
-
-
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
-
-
-# ---------------------------------------------------------------------------
-# in-process worker: the real protocol loop without a subprocess
-# ---------------------------------------------------------------------------
-
-
-class _InProcWorker:
-    """Duck-types the Popen surface SidecarPool supervises, but serves
-    ``sidecar._handle_conn`` from threads in THIS process. ``kill()``
-    models kill -9: the listener and every live connection drop
-    mid-frame, exactly what a client of a SIGKILLed worker observes."""
-
-    def __init__(self):
-        self.sock_path = tempfile.mktemp(prefix="srjt-inproc-") + ".sock"
-        self.pid = os.getpid()
-        self.returncode = None
-        self._conns = []
-        self._srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        self._srv.bind(self.sock_path)
-        self._srv.listen(8)
-        self._t = threading.Thread(target=self._accept_loop, daemon=True)
-        self._t.start()
-
-    def _accept_loop(self):
-        while True:
-            try:
-                conn, _ = self._srv.accept()
-            except OSError:
-                return  # killed
-            self._conns.append(conn)
-
-            def _serve(c=conn):
-                try:
-                    sidecar._handle_conn(c, "cpu", lambda: None)
-                except OSError:
-                    pass  # kill() closed the socket under the handler
-
-            threading.Thread(target=_serve, daemon=True).start()
-
-    # Popen surface the pool touches
-    def poll(self):
-        return self.returncode
-
-    def wait(self, timeout=None):
-        return self.returncode if self.returncode is not None else 0
-
-    def terminate(self):
-        self.kill()
-
-    def kill(self):
-        if self.returncode is None:
-            self.returncode = -signal.SIGKILL
-        try:
-            self._srv.close()
-        except OSError:
-            pass
-        for c in self._conns:
-            try:
-                c.close()
-            except OSError:
-                pass
-        try:
-            os.unlink(self.sock_path)
-        except OSError:
-            pass
-
-
-def _inproc_spawn(startup_timeout_s=None, env=None):
-    w = _InProcWorker()
-    return w, w.sock_path
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 @pytest.fixture
 def inproc_pool():
     pool = sidecar_pool.SidecarPool(
-        size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+        size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
     )
     yield pool
     pool.shutdown()
-
-
-def _groupby_payload(n=600, k=16, seed=3):
-    rng = np.random.default_rng(seed)
-    keys = rng.integers(0, k, n).astype(np.int64)
-    vals = rng.standard_normal(n).astype(np.float32)
-    return struct.pack("<IQ", k, n) + keys.tobytes() + vals.tobytes()
 
 
 # ---------------------------------------------------------------------------
@@ -197,11 +96,11 @@ class TestIntegrityHelpers:
 
 class TestFrameIntegrity:
     def test_crc_framed_request_roundtrip(self):
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             client = sidecar.SupervisedClient(w.sock_path, deadline_s=20, heartbeat_s=1e9)
             with client:
-                payload = _groupby_payload()
+                payload = groupby_payload()
                 before = _counter("sidecar.integrity.frames_checked")
                 resp = client.request(sidecar.OP_GROUPBY_SUM_F32, payload)
                 assert resp == sidecar._dispatch(
@@ -217,11 +116,11 @@ class TestFrameIntegrity:
         """A frame whose trailer doesn't match its payload must answer
         status 1 with the DataCorruption taxonomy prefix — and the
         worker must keep serving."""
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             conn = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
             conn.connect(w.sock_path)
-            payload = _groupby_payload()
+            payload = groupby_payload()
             bad_crc = integrity.pack_crc(integrity.checksum(payload) ^ 0xFFFF)
             conn.sendall(
                 struct.pack(
@@ -250,10 +149,10 @@ class TestFrameIntegrity:
         worker checksums: the client's CRC check must convert it into
         DataCorruption — and with the retry orchestrator armed the op
         heals once the fault budget is spent."""
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             client = sidecar.SupervisedClient(w.sock_path, deadline_s=20, heartbeat_s=1e9)
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             faultinj.configure(
                 {"seed": 11, "faults": {"sidecar.worker.GROUPBY_SUM_F32": {
@@ -270,10 +169,10 @@ class TestFrameIntegrity:
             w.kill()
 
     def test_corrupt_fault_with_retry_orchestrator_heals(self):
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             client = sidecar.SupervisedClient(w.sock_path, deadline_s=20, heartbeat_s=1e9)
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             faultinj.configure(
                 {"seed": 11, "faults": {"sidecar.worker.GROUPBY_SUM_F32": {
@@ -295,10 +194,10 @@ class TestFrameIntegrity:
         no verification — and an injected corruption therefore flows
         through silently (the counterfactual that justifies the
         layer's existence)."""
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             client = sidecar.SupervisedClient(w.sock_path, deadline_s=20, heartbeat_s=1e9)
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             with client, integrity.disabled():
                 assert client.request(sidecar.OP_GROUPBY_SUM_F32, payload) == want
@@ -537,7 +436,7 @@ def test_set_arena_reregistration_keeps_gauges_flat():
     REPLACES the catalog entry (unregister-then-register) — the
     memgov.arena* gauges must track exactly one arena at the latest
     size, never accumulate."""
-    w = _InProcWorker()
+    w = InProcWorker()
     try:
         base = memgov.catalog().snapshot()
         conn = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
@@ -567,7 +466,7 @@ def test_set_arena_reregistration_keeps_gauges_flat():
 
 class TestPoolFailover:
     def test_round_robin_routing(self, inproc_pool):
-        payload = _groupby_payload()
+        payload = groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         with retry.enabled(max_attempts=4, base_delay_ms=1):
             for _ in range(4):
@@ -579,7 +478,7 @@ class TestPoolFailover:
     def test_kill_one_worker_exactly_one_failover_zero_breaker_trips(
         self, inproc_pool
     ):
-        payload = _groupby_payload()
+        payload = groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         failovers0 = _counter("sidecar.pool.failovers")
         opened0 = _counter("sidecar.breaker.opened_total")
@@ -598,10 +497,10 @@ class TestPoolFailover:
 
     def test_whole_pool_dark_degrades_to_host_and_counts_breaker(self):
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=5, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=5, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             fallbacks0 = _counter("sidecar.pool.host_fallbacks")
             # stop the respawner from resurrecting anyone, then kill all
@@ -618,7 +517,7 @@ class TestPoolFailover:
             sidecar.breaker().reset()
 
     def test_arena_rehydration_on_respawn(self, inproc_pool):
-        payload = _groupby_payload()
+        payload = groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         inproc_pool.set_arena(1 << 20)
         rehydr0 = _counter("sidecar.pool.rehydrations")
@@ -648,7 +547,7 @@ class TestPoolFailover:
         arena (that opportunism is what serialized the whole pool):
         stream requests keep streaming after the slab exists, and the
         responses arrive promptly on the socket."""
-        payload = _groupby_payload()
+        payload = groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         inproc_pool.ensure_slab()
         t0 = time.monotonic()
@@ -662,7 +561,7 @@ class TestPoolFailover:
         (timeout, desync close) silently drops it, so the pool must
         replay SET_ARENA on the fresh connection — a region op after a
         reconnect stays on the device path, never a host fallback."""
-        payload = _groupby_payload()
+        payload = groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         inproc_pool.ensure_slab()
         rehydr0 = _counter("sidecar.pool.rehydrations")
@@ -726,7 +625,7 @@ class TestPoolFailover:
             if len(spawned) >= 2:  # a RESPAWN, not an initial spawn
                 entered.set()
                 release.wait(20)
-            w = _InProcWorker()
+            w = InProcWorker()
             spawned.append(w)
             return w, w.sock_path
 
@@ -756,13 +655,13 @@ class TestPoolFailover:
 
     def test_pool_size_env_default(self, monkeypatch):
         monkeypatch.delenv("SRJT_SIDECAR_POOL_SIZE", raising=False)
-        pool = sidecar_pool.SidecarPool(spawn_fn=_inproc_spawn)
+        pool = sidecar_pool.SidecarPool(spawn_fn=inproc_spawn)
         try:
             assert pool.size == 1  # today's behavior
         finally:
             pool.shutdown()
         monkeypatch.setenv("SRJT_SIDECAR_POOL_SIZE", "3")
-        pool = sidecar_pool.SidecarPool(spawn_fn=_inproc_spawn)
+        pool = sidecar_pool.SidecarPool(spawn_fn=inproc_spawn)
         try:
             assert pool.size == 3
         finally:
@@ -776,7 +675,7 @@ class TestPoolFailover:
 
 class TestPoolStats:
     def test_worker_stats_keyed_per_worker_and_folded(self, inproc_pool):
-        payload = _groupby_payload()
+        payload = groupby_payload()
         with retry.enabled(max_attempts=4, base_delay_ms=1):
             for _ in range(2):
                 inproc_pool.call(sidecar.OP_GROUPBY_SUM_F32, payload)
@@ -797,7 +696,7 @@ class TestPoolStats:
         from spark_rapids_jni_tpu import runtime
 
         pool = sidecar_pool.connect_pool(
-            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             stats = runtime.device_stats(fold=True)
@@ -914,7 +813,7 @@ class TestRealWorkerPool:
             env={"SRJT_FAULTINJ_CONFIG": cfg},
         )
         try:
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want_g = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             tbl = Table(
                 [Column(dt.INT32, data=jnp.arange(128, dtype=jnp.int32))], ["a"]
@@ -966,7 +865,7 @@ class TestOneChipOwner:
 
         def spawn(**kw):
             spawned.append(kw)
-            return _inproc_spawn()
+            return inproc_spawn()
 
         with pytest.raises(errors.FatalDeviceError, match="holds the chip"):
             sidecar_pool.SidecarPool(size=1, spawn_fn=spawn, env=override)
@@ -978,12 +877,12 @@ class TestOneChipOwner:
     ])
     def test_cpu_workers_are_no_conflict(self, monkeypatch, holds_tpu, inherited, override):
         monkeypatch.setenv("JAX_PLATFORMS", inherited)
-        with sidecar_pool.SidecarPool(size=1, spawn_fn=_inproc_spawn, env=override) as pool:
+        with sidecar_pool.SidecarPool(size=1, spawn_fn=inproc_spawn, env=override) as pool:
             assert pool.live_count() == 1
 
     def test_parent_off_the_chip_starts_the_pool(self, monkeypatch):
         monkeypatch.delenv("JAX_PLATFORMS", raising=False)  # the conftest pin is on the live config
-        with sidecar_pool.SidecarPool(size=1, spawn_fn=_inproc_spawn) as pool:
+        with sidecar_pool.SidecarPool(size=1, spawn_fn=inproc_spawn) as pool:
             assert pool.live_count() == 1
 
 
@@ -1172,7 +1071,7 @@ TRANSPORTS = ["stream", "arena", "region"]
 
 @pytest.fixture
 def inproc_worker():
-    w = _InProcWorker()
+    w = InProcWorker()
     yield w
     w.kill()
 
@@ -1223,7 +1122,7 @@ class TestGatheredReply:
     def test_corrupt_fault_on_a_gathered_reply_fails_verify_and_heals(self, via):
         op, request, want = _wire_case("to_rows")
         pool = sidecar_pool.SidecarPool(
-            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         region = None
         try:
@@ -1262,8 +1161,8 @@ class TestKeptRequestBuffer:
         """The buffer grows once, to the largest payload, and is reused
         after; every answer is right, and the first, held by the caller,
         is what it was after the buffer has been overwritten twice."""
-        large, small = _groupby_payload(n=5000, seed=1), _groupby_payload(n=40, seed=2)
-        large2 = _groupby_payload(n=5000, seed=3)
+        large, small = groupby_payload(n=5000, seed=1), groupby_payload(n=40, seed=2)
+        large2 = groupby_payload(n=5000, seed=3)
         wants = [
             sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, p, "cpu")
             for p in (large, small, large2)
@@ -1317,7 +1216,7 @@ class TestKeptRequestBuffer:
                 assert client.host_fallbacks == 1
             else:
                 pool = sidecar_pool.SidecarPool(
-                    size=1, deadline_s=5, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+                    size=1, deadline_s=5, heartbeat_s=1e9, spawn_fn=inproc_spawn
                 )
                 try:
                     pool._respawn_max = 0

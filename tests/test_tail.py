@@ -22,124 +22,29 @@ ci/premerge.sh's gray tier (bench_serve --gray against 3 spawned
 workers).
 """
 
-import os
-import signal
-import socket
-import struct
-import tempfile
 import threading
 import time
 
-import numpy as np
 import pytest
 
 import spark_rapids_jni_tpu  # noqa: F401
 from spark_rapids_jni_tpu import serve, sidecar, sidecar_pool
 from spark_rapids_jni_tpu.utils import deadline as deadline_mod
-from spark_rapids_jni_tpu.utils import faultinj, knobs, metrics, retry
+from spark_rapids_jni_tpu.utils import faultinj, knobs, metrics
 from spark_rapids_jni_tpu.utils.errors import (
     FatalDeviceError,
     Overloaded,
     RetryableError,
 )
 
+from _inproc import InProcWorker, groupby_payload, inproc_spawn
+
 
 def _counter(name):
     return metrics.registry().value(name)
 
 
-def _scrub_worker_namespace():
-    """Same two-way scrub as test_sidecar_pool: the in-proc worker's
-    always-on counters must not type-clash with sidecar.worker.* gauges
-    folded by other suite files (and vice versa)."""
-    reg = metrics.registry()
-    with reg._lock:
-        for name in list(reg._metrics):
-            if name.startswith("sidecar.worker."):
-                del reg._metrics[name]
-
-
-@pytest.fixture(autouse=True)
-def _clean_state():
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
-
-
-class _InProcWorker:
-    """Minimal Popen-shaped in-process worker (the test_sidecar_pool
-    trick): sidecar._handle_conn served from threads in this process."""
-
-    def __init__(self):
-        self.sock_path = tempfile.mktemp(prefix="srjt-tail-") + ".sock"
-        self.pid = os.getpid()
-        self.returncode = None
-        self._conns = []
-        self._srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        self._srv.bind(self.sock_path)
-        self._srv.listen(8)
-        self._t = threading.Thread(target=self._accept_loop, daemon=True)
-        self._t.start()
-
-    def _accept_loop(self):
-        while True:
-            try:
-                conn, _ = self._srv.accept()
-            except OSError:
-                return
-            self._conns.append(conn)
-
-            def _serve(c=conn):
-                try:
-                    sidecar._handle_conn(c, "cpu", lambda: None)
-                except OSError:
-                    pass
-
-            threading.Thread(target=_serve, daemon=True).start()
-
-    def poll(self):
-        return self.returncode
-
-    def wait(self, timeout=None):
-        return self.returncode if self.returncode is not None else 0
-
-    def terminate(self):
-        self.kill()
-
-    def kill(self):
-        if self.returncode is None:
-            self.returncode = -signal.SIGKILL
-        try:
-            self._srv.close()
-        except OSError:
-            pass
-        for c in self._conns:
-            try:
-                c.close()
-            except OSError:
-                pass
-        try:
-            os.unlink(self.sock_path)
-        except OSError:
-            pass
-
-
-def _inproc_spawn(startup_timeout_s=None, env=None):
-    w = _InProcWorker()
-    return w, w.sock_path
-
-
-def _groupby_payload(n=600, k=16, seed=3):
-    rng = np.random.default_rng(seed)
-    keys = rng.integers(0, k, n).astype(np.int64)
-    vals = rng.standard_normal(n).astype(np.float32)
-    return struct.pack("<IQ", k, n) + keys.tobytes() + vals.tobytes()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 def _seed_hist(name, values_us):
@@ -342,7 +247,7 @@ class TestAdaptiveTimeout:
         whatever the quantiles say (the old clamp, unchanged)."""
         monkeypatch.setenv("SRJT_ADAPTIVE_TIMEOUT_MIN_SAMPLES", "1")
         monkeypatch.setenv("SRJT_ADAPTIVE_TIMEOUT_FLOOR_S", "50.0")
-        w = _InProcWorker()
+        w = InProcWorker()
         try:
             c = sidecar.SupervisedClient(w.sock_path, deadline_s=600.0,
                                          heartbeat_s=1e9)
@@ -429,7 +334,7 @@ class TestFaultinjWorkerKeys:
         seen = {}
 
         def spawn_fn(startup_timeout_s=None, env=None):
-            w = _InProcWorker()
+            w = InProcWorker()
             seen[len(seen)] = dict(env or {})
             return w, w.sock_path
 
@@ -461,7 +366,7 @@ class TestQuarantine:
         monkeypatch.setenv("SRJT_QUARANTINE_PROBE_INTERVAL_S", "1.0")
         monkeypatch.setenv("SRJT_QUARANTINE_STRIKES", "3")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             name = f"sidecar.op_lat_us.{sidecar.op_name(sidecar.OP_PING)}"
@@ -499,7 +404,7 @@ class TestQuarantine:
         # dirty, the clean run never starts
         monkeypatch.setenv("SRJT_QUARANTINE_PROBE_SLOW_S", "0.000000001")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             name = f"sidecar.op_lat_us.{sidecar.op_name(sidecar.OP_PING)}"
@@ -526,7 +431,7 @@ class TestQuarantine:
         monkeypatch.setenv("SRJT_QUARANTINE_STRIKES", "2")
         monkeypatch.setenv("SRJT_QUARANTINE_PROBE_INTERVAL_S", "5")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -541,7 +446,7 @@ class TestQuarantine:
     def test_clean_samples_pay_strikes_back(self, monkeypatch):
         monkeypatch.setenv("SRJT_QUARANTINE_STRIKES", "3")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             name = f"sidecar.op_lat_us.{sidecar.op_name(sidecar.OP_PING)}"
@@ -562,7 +467,7 @@ class TestQuarantine:
         gray, _pick falls back (counted) and calls still complete."""
         monkeypatch.setenv("SRJT_QUARANTINE_PROBE_INTERVAL_S", "60")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -581,7 +486,7 @@ class TestQuarantine:
     def test_death_clears_quarantine_state(self, monkeypatch):
         monkeypatch.setenv("SRJT_QUARANTINE_PROBE_INTERVAL_S", "60")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -610,11 +515,11 @@ class TestHedgedDispatch:
         reconcile (one launched, at most one won, one cancelled)."""
         monkeypatch.setenv("SRJT_HEDGE_BUDGET_PCT", "100")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             # both in-proc workers serve the op ~50 ms slow, so both
             # legs are in flight when the race settles
@@ -653,11 +558,11 @@ class TestHedgedDispatch:
         monkeypatch.setenv("SRJT_HEDGE_BUDGET_PCT", "100")
         monkeypatch.setenv("SRJT_FAULTINJ_WORKER", "w9")  # inert tag
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
-            payload = _groupby_payload()
+            payload = groupby_payload()
             want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
             # the first GROUPBY dispatch hangs 2 s (the in-proc workers
             # share this process's injector, so the budget of 1 means
@@ -695,7 +600,7 @@ class TestHedgedDispatch:
     def test_budget_arithmetic(self, monkeypatch):
         monkeypatch.setenv("SRJT_HEDGE_BUDGET_PCT", "10")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -714,7 +619,7 @@ class TestHedgedDispatch:
         counter, with the trigger conditions otherwise satisfied."""
         monkeypatch.setenv("SRJT_HEDGE_MIN_SAMPLES", "10")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -741,7 +646,7 @@ class TestHedgedDispatch:
         monkeypatch.setenv("SRJT_HEDGE_MIN_SAMPLES", "10")
         monkeypatch.setenv("SRJT_HEDGE_SHED_WINDOW_S", "5.0")
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -760,7 +665,7 @@ class TestHedgedDispatch:
     def test_cold_class_and_single_worker_never_hedge(self, monkeypatch):
         monkeypatch.setenv("SRJT_HEDGE_MIN_SAMPLES", "10")
         pool = sidecar_pool.SidecarPool(
-            size=1, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=1, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()
@@ -772,7 +677,7 @@ class TestHedgedDispatch:
         finally:
             pool.shutdown()
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         try:
             metrics.reset()  # cold class: no samples at all
@@ -791,7 +696,7 @@ class TestServeQuarantineRouting:
     def test_all_gray_pool_sheds_device_only_work(self, monkeypatch):
         monkeypatch.setenv("SRJT_QUARANTINE_PROBE_INTERVAL_S", "60")
         pool = sidecar_pool.connect_pool(
-            size=1, deadline_s=10, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=1, deadline_s=10, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         sched = serve.Scheduler(max_concurrent=1, name="tail-test")
         try:
@@ -826,7 +731,6 @@ class TestServeQuarantineRouting:
             assert stamp is not None
             assert time.monotonic() - stamp < 10.0
         finally:
-            faultinj.disable()
             sched.shutdown(drain=False, timeout_s=10)
 
 

@@ -20,10 +20,7 @@ gates the archived artifacts.
 
 import json
 import os
-import signal
-import socket
 import struct
-import tempfile
 import threading
 import time
 
@@ -43,44 +40,10 @@ from spark_rapids_jni_tpu.utils import (
 )
 from spark_rapids_jni_tpu.utils.errors import Overloaded, RetryableError
 
-
-def _scrub_worker_namespace():
-    """In-proc workers count registry-direct sidecar.worker.* COUNTERS
-    in this process, which clash with the GAUGES other suites fold
-    remote snapshots into (the test_sidecar_pool discipline)."""
-    reg = metrics.registry()
-    with reg._lock:
-        for name in list(reg._metrics):
-            if name.startswith("sidecar.worker."):
-                del reg._metrics[name]
+from _inproc import InProcWorker, inproc_spawn
 
 
-@pytest.fixture(autouse=True)
-def _clean_state(tmp_path):
-    """Every test gets a fresh recorder and its own span log under
-    tmp_path; the env-configured base (the CI trace tier's artifacts
-    path) is restored afterwards so the real-pool acceptance — which
-    deliberately uses the env path — still archives its spans."""
-    prev_base = trace_sink.log_path()
-    prev_enabled = tracing.is_enabled()
-    # the premerge trace tier arms SRJT_TRACE_ENABLED=1 process-wide;
-    # tests own the gate explicitly (tracing.enabled() scopes), so the
-    # default inside this suite is OFF either way
-    tracing.set_enabled(False)
-    trace_sink.reset_for_tests()
-    trace_sink.set_log_path(str(tmp_path / "spans.jsonl"))
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    _scrub_worker_namespace()
-    yield
-    faultinj.disable()
-    retry.disable()
-    retry.reset_stats()
-    trace_sink.reset_for_tests()
-    trace_sink.set_log_path(prev_base)
-    tracing.set_enabled(prev_enabled)
-    _scrub_worker_namespace()
+pytestmark = pytest.mark.usefixtures("own_span_log")
 
 
 def _log_spans():
@@ -665,7 +628,6 @@ class TestSchedulerTracing:
                 with pytest.raises(Overloaded):
                     sched.submit(lambda: 1, tenant="x")
             finally:
-                faultinj.disable()
                 sched.shutdown()
         sheds = [r for r in trace_sink.recorder().last(10)
                  if r["status"] == "shed"]
@@ -720,71 +682,9 @@ def _groupby_payload(n=200, k=8, seed=3):
     return struct.pack("<IQ", k, n) + keys.tobytes() + vals.tobytes()
 
 
-class _InProcWorker:
-    """Serves sidecar._handle_conn from threads in THIS process (the
-    test_sidecar_pool pattern) — the real protocol loop, no subprocess."""
-
-    def __init__(self):
-        self.sock_path = tempfile.mktemp(prefix="srjt-trace-") + ".sock"
-        self.pid = os.getpid()
-        self.returncode = None
-        self._conns = []
-        self._srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        self._srv.bind(self.sock_path)
-        self._srv.listen(8)
-        threading.Thread(target=self._accept_loop, daemon=True).start()
-
-    def _accept_loop(self):
-        while True:
-            try:
-                conn, _ = self._srv.accept()
-            except OSError:
-                return
-            self._conns.append(conn)
-
-            def _serve(c=conn):
-                try:
-                    sidecar._handle_conn(c, "cpu", lambda: None)
-                except OSError:
-                    pass
-
-            threading.Thread(target=_serve, daemon=True).start()
-
-    def poll(self):
-        return self.returncode
-
-    def wait(self, timeout=None):
-        return self.returncode if self.returncode is not None else 0
-
-    def terminate(self):
-        self.kill()
-
-    def kill(self):
-        if self.returncode is None:
-            self.returncode = -signal.SIGKILL
-        try:
-            self._srv.close()
-        except OSError:
-            pass
-        for c in self._conns:
-            try:
-                c.close()
-            except OSError:
-                pass
-        try:
-            os.unlink(self.sock_path)
-        except OSError:
-            pass
-
-
-def _inproc_spawn(startup_timeout_s=None, env=None):
-    w = _InProcWorker()
-    return w, w.sock_path
-
-
 class TestSidecarWirePropagation:
     def test_worker_span_parents_to_client_request_span(self):
-        w = _InProcWorker()
+        w = InProcWorker()
         payload = _groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         c = sidecar.SupervisedClient(w.sock_path, deadline_s=20,
@@ -807,7 +707,7 @@ class TestSidecarWirePropagation:
             w.kill()
 
     def test_untraced_request_keeps_legacy_framing(self):
-        w = _InProcWorker()
+        w = InProcWorker()
         payload = _groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
         c = sidecar.SupervisedClient(w.sock_path, deadline_s=20,
@@ -827,7 +727,7 @@ class TestSidecarWirePropagation:
 
     def test_pool_failover_retry_is_child_of_the_op_span(self):
         pool = sidecar_pool.SidecarPool(
-            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=2, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         payload = _groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")
@@ -919,7 +819,7 @@ class TestFullChain:
         nests serve.run -> op span -> memgov admission AND the pool's
         wire spans, connected by parent links end to end."""
         pool = sidecar_pool.SidecarPool(
-            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=_inproc_spawn
+            size=1, deadline_s=20, heartbeat_s=1e9, spawn_fn=inproc_spawn
         )
         payload = _groupby_payload()
         want = sidecar._dispatch(sidecar.OP_GROUPBY_SUM_F32, payload, "cpu")

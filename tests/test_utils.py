@@ -16,11 +16,7 @@ from spark_rapids_jni_tpu.ops.aggregate import groupby_aggregate
 from spark_rapids_jni_tpu.utils import dispatch, errors, faultinj, tracing
 
 
-@pytest.fixture(autouse=True)
-def clean_faults():
-    faultinj.disable()
-    yield
-    faultinj.disable()
+pytestmark = pytest.mark.usefixtures("clean_state")
 
 
 def _small_table():
