@@ -34,7 +34,8 @@ rec = {"side": side, "cell": cell, "seed": int(seed), "trace": int(trace), "rc":
 if os.path.exists(saved):  # keep the spans, drop the device events (tens of MB in q1)
     t = json.load(open(saved))
     rec["requests"], rec["spans"], rec["window_ns"] = t["requests"], t["spans"], t["window_ns"]
-    os.remove(saved)
+    if not os.environ.get("KEEP_TRACE"):  # PR 31: a call that reduces the device events itself removes the file
+        os.remove(saved)
 print(json.dumps(rec))
 PY
   tail -c 1500 "$OUT/$tag.out" | tail -1 | cut -c1-1200

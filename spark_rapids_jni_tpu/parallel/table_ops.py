@@ -18,11 +18,15 @@ Design:
   on a 64-bit chained hash of the key tuple and VERIFIES every
   candidate pair on the raw lanes, so hash collisions cost only output
   slots, never correctness.
-- **Skew-aware capacity default** (VERDICT r1 weak #4): the per-
-  destination bucket default is ``max(4 * per_shard / n_parts, 64)``
-  (expected occupancy x4 headroom, floored for tiny shards), capped at
-  ``per_shard`` — O(N/P) receive buffers per shard instead of O(N),
-  with the existing overflow flag as the resize signal.
+- **Counted capacity.** The sharded layer's exchange counts the rows
+  bound for each destination before it moves any (``exchange_sharded``:
+  one small program, one host read) and sizes its buckets from the
+  fullest, on a ladder of powers of two: a skewed key is sized on the
+  first try, a filtered input's empty slots buy nothing, and the stages
+  behind work on slots in proportion to the rows. ``distributed_join_table``
+  keeps its own all-to-all with a guessed capacity
+  (``default_capacity``: ``max(4 * per_shard / n_parts, 64)``, capped at
+  ``per_shard``) and the overflow flag as its resize signal.
 - Null semantics follow Spark: null keys form one group (they exchange
   with a validity lane joined into the key tuple); aggregates skip null
   values; joins never match null keys.
@@ -224,8 +228,8 @@ def exchange_table(table: Table, key_cols: Sequence[str], mesh: Mesh, axis: str 
     the received rows back as one compacted Table: the sharded layer below
     from end to end (``shard_table`` -> ``exchange_sharded`` ->
     ``gather_table``). Rows of equal key tuples met on one shard. The flag
-    is always False and stays for the callers that unpack it: a bucket
-    that overflows makes the exchange run again larger, inside."""
+    is always False and stays for the callers that unpack it: the buckets
+    are sized from the counted rows, so none overflows."""
     enc, dicts = _encoded(table)
     out = gather_table(exchange_sharded(shard_table(enc, mesh, axis), key_cols))
     return _decoded(out, dicts), False
@@ -336,10 +340,11 @@ def distributed_groupby_table(
     sharded layer below from end to end (``shard_table`` ->
     ``exchange_sharded`` -> ``groupby_sharded`` -> ``gather_table``).
     String keys group via dictionary codes and decode on the way out. An
-    exchange that would not fit the device budget, at its first capacity
-    or at the one a skewed key escalates to, splits the batch instead
-    (``_groupby_split_retry``: the reference's 2 GiB batching discipline).
-    The flag is always False and stays for the callers that unpack it."""
+    exchange that would not fit the device budget at the capacity it
+    counted (a skewed key's is a whole shard's rows) splits the batch
+    instead (``_groupby_split_retry``: the reference's 2 GiB batching
+    discipline). The flag is always False and stays for the callers that
+    unpack it."""
     from ..utils.memory import MemoryBudgetExceeded
 
     for v, how, _o in aggs:
@@ -716,8 +721,8 @@ def _join_once(
 
 
 class ExchangeOverflow(RuntimeError):
-    """A destination bucket overflowed at the largest capacity there is:
-    raised, never answered with rows missing."""
+    """A destination bucket overflowed the capacity its exchange had
+    counted for it: raised, never answered with rows missing."""
 
 
 class ShardedTable:
@@ -728,15 +733,18 @@ class ShardedTable:
     shard (``()``: rows lie where the file order or a filter left them);
     ``ordered`` a column whose values ascend over the slots of every shard,
     absent slots included, and are distinct where present (a group-by's
-    key: a probe of it needs no sort). STRING columns do not ride here:
-    they stay on replicated tables."""
+    key: a probe of it needs no sort). ``overflow`` holds, for every
+    exchange these rows came through (a join's right side's too), its
+    keys, its capacity and its overflow flag, still on the device:
+    ``gather_table`` reads them. STRING columns do not ride here: they
+    stay on replicated tables."""
 
-    __slots__ = ("table", "present", "mesh", "axis", "part", "ordered")
+    __slots__ = ("table", "present", "mesh", "axis", "part", "ordered", "overflow")
 
     def __init__(self, table: Table, present, mesh: Mesh, axis: str = "data",
-                 part: Tuple[str, ...] = (), ordered: Optional[str] = None):
+                 part: Tuple[str, ...] = (), ordered: Optional[str] = None, overflow: tuple = ()):
         self.table, self.present, self.mesh, self.axis = table, present, mesh, axis
-        self.part, self.ordered = tuple(part), ordered
+        self.part, self.ordered, self.overflow = tuple(part), ordered, tuple(overflow)
 
     @property
     def names(self) -> List[str]:
@@ -758,9 +766,9 @@ class ShardedTable:
         names = list(names)
         return ShardedTable(self.table.select(names), self.present, self.mesh, self.axis,
                             self.part if set(self.part) <= set(names) else (),
-                            self.ordered if self.ordered in names else None)
+                            self.ordered if self.ordered in names else None, self.overflow)
 
-    def replace(self, table: Table = None, present=None, part=None) -> "ShardedTable":
+    def replace(self, table: Table = None, present=None, part=None, overflow=None) -> "ShardedTable":
         """Other columns over the same slots, or fewer rows present: the
         slots keep their order, so what is known of it holds for every
         column that is carried over as it was."""
@@ -770,7 +778,8 @@ class ShardedTable:
             ordered = ordered if kept else None
         return ShardedTable(self.table if table is None else table,
                             self.present if present is None else present,
-                            self.mesh, self.axis, self.part if part is None else part, ordered)
+                            self.mesh, self.axis, self.part if part is None else part, ordered,
+                            self.overflow if overflow is None else overflow)
 
 
 @op_boundary("shard_table")
@@ -805,12 +814,17 @@ def replicate_table(table: Table, mesh: Mesh) -> Table:
         return jax.device_put(table, replicated(mesh))
 
 
-def _tight_capacity(per_shard: int, n_parts: int) -> int:
-    """First-try bucket capacity of the sharded exchange: half again the
-    even share. A hash spreads keys to within a fraction of a percent at
-    these sizes; a skewed key overflows, and the exchange runs again
-    larger (up to ``per_shard``, which cannot overflow)."""
-    return min(per_shard, max(3 * ((per_shard + n_parts - 1) // n_parts) // 2, 64))
+_CAPACITY_FLOOR = 1024
+
+
+def _counted_capacity(max_bucket: int, per_shard: int) -> int:
+    """Bucket capacity of the sharded exchange from the rows it counted:
+    the power of two at or above the fullest bucket, no smaller than a
+    floor under which every sparse input shares one program, no larger
+    than ``per_shard`` (a shard has no more rows than that to send). A
+    capacity is a SHAPE: coarse steps keep inputs that hold the same keys
+    in another order, or a few rows more, on one compiled program."""
+    return min(per_shard, max(_CAPACITY_FLOOR, 1 << max(max_bucket - 1, 0).bit_length()))
 
 
 def _lanes_of(table: Table):
@@ -831,15 +845,14 @@ def _table_from(like: Table, spots, lanes) -> Table:
                   for c, (i, v) in zip(like.columns, spots)], list(like.names))
 
 
-def _route(dest, present, n_parts: int, capacity: int):
+def _route(dest, n_parts: int, capacity: int):
     """Where each bucket slot reads from: rows sorted by destination
-    (absent rows last, bound for nowhere), bucket p slot s <- the s-th
-    row of run p. Gathers only. Returns (src[n_parts, capacity],
+    (``n_parts``, an absent row's, last), bucket p slot s <- the s-th row
+    of run p. Gathers only. Returns (src[n_parts, capacity],
     filled[n_parts, capacity], overflow)."""
     n = dest.shape[0]
-    d = jnp.where(present, dest.astype(jnp.int32), jnp.int32(n_parts))
-    order = jnp.argsort(d, stable=False)  # which row of a run lands in which slot of its bucket is free
-    start = jnp.searchsorted(d[order], jnp.arange(n_parts + 1, dtype=jnp.int32), side="left").astype(jnp.int32)
+    order = jnp.argsort(dest, stable=False)  # which row of a run lands in which slot of its bucket is free
+    start = jnp.searchsorted(dest[order], jnp.arange(n_parts + 1, dtype=jnp.int32), side="left").astype(jnp.int32)
     count = start[1:] - start[:-1]
     slot = jnp.arange(capacity, dtype=jnp.int32)[None, :]
     src = order[jnp.clip(start[:-1, None] + slot, 0, n - 1)]
@@ -850,80 +863,75 @@ def _route(dest, present, n_parts: int, capacity: int):
 def exchange_sharded(st: ShardedTable, key_cols: Sequence[str]) -> ShardedTable:
     """Hash-repartition on ``key_cols`` with one all-to-all a lane: rows
     of equal keys end on one shard, and the result says so (``part``).
-    A bucket that overflows its first-try capacity makes the whole
-    exchange run again at four times the capacity (counted in
-    ``exchange.capacity_retries``), up to ``per_shard``, which no bucket
-    can overflow: a row is never dropped."""
-    n_parts = st.n_parts
-    per_shard = st.num_rows // n_parts
-    cap = _tight_capacity(per_shard, n_parts)
-    attempt = 0
-    while True:
-        with tracing.span("exchange.table", keys=list(key_cols), capacity=cap, parts=n_parts,
-                          attempt=attempt) as sp:
-            out, rows_in, lane_bytes, ovf = _exchange_sharded_once(st, key_cols, cap)
-            sp.annotate(rows_in=rows_in)
-        _count_exchange(rows_in, lane_bytes)
-        if not ovf:
-            return out
-        _exchange_counter("overflows").inc()
-        if cap >= per_shard:  # a shard has no more rows than that to send: the program is at fault
-            raise ExchangeOverflow(f"exchange on {list(key_cols)}: a bucket of {cap} slots overflowed")
-        cap = min(per_shard, cap * 4)
-        attempt += 1
-        _exchange_counter("capacity_retries").inc()
-
-
-def _key_lanes(spots, names, key_cols):
-    """(data lane, validity lane or None) of each key: a key routes by
-    its data with NULLs masked to zero, so every NULL routes alike,
-    whatever the column's nullability on the other side of a join."""
-    out = []
-    for k in key_cols:
-        i, v = spots[names.index(k)]
-        out.append((i, i + 1 if v else None))
-    return out
-
-
-def _exchange_sharded_once(st: ShardedTable, key_cols, capacity: int):
+    Count, then size: a first small program routes every present row and
+    counts the rows each shard sends to each destination; the host reads
+    the counts (the exchange's one wait) and the all-to-all program runs
+    once, at ``_counted_capacity`` of the fullest bucket. Slots that hold
+    no row (a filter's, a join's) buy no capacity, and no bucket can
+    overflow. The all-to-all program computes its overflow flag all the
+    same; the flag rides on the result unread (``overflow``) until
+    ``gather_table``'s transfer, which raises ``ExchangeOverflow`` if it
+    is set: a row is never dropped silently."""
     from ..utils.memory import MemoryBudgetExceeded, device_memory_budget, exchange_bytes_estimate
 
     mesh, axis, n_parts = st.mesh, st.axis, st.n_parts
+    per_shard = st.num_rows // n_parts
     lanes, spots = _lanes_of(st.table)
     lane_bytes = sum(int(np.dtype(a.dtype).itemsize) * int(np.prod(a.shape[1:], dtype=np.int64)) for a in lanes)
-    est = exchange_bytes_estimate(lane_bytes + 5, n_parts, capacity)  # a slot: its lanes, a 4-byte route index, a flag
-    if est > device_memory_budget():
-        raise MemoryBudgetExceeded(
-            f"exchange at capacity {capacity} needs ~{est} device bytes a chip "
-            f"(budget {device_memory_budget()}); split the batch or lower the capacity")
-    kpos = _key_lanes(spots, st.names, key_cols)
+    keys = [st.column(k) for k in key_cols]
+    nullable = tuple(c.validity is not None for c in keys)
+    key_in = [a for c in keys for a in (c.data, c.validity) if a is not None]
 
-    def exchange_program(pres, *payload):
-        ks = []
-        for dpos, vpos in kpos:
-            data = payload[dpos]
+    def count_program(pres, *key_arrs):
+        it, ks = iter(key_arrs), []
+        for has_v in nullable:
+            data = next(it)
             if jnp.issubdtype(data.dtype, jnp.integer):
                 # an integer routes by its VALUE, whatever its width (an INT32 would hash as one
                 # block, an INT64 as two): the two sides of a join then agree on the shard
                 data = data.astype(jnp.int64)
-            ks.append(data if vpos is None else jnp.where(payload[vpos], data, jnp.zeros((), data.dtype)))
-        src, filled, ovf = _route(_hash_dest_multi(ks, n_parts), pres, n_parts, capacity)
-        a2a = lambda x: lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
-        outs = [a2a(a[src]).reshape((-1,) + a.shape[1:]) for a in payload]
-        rows = lax.psum(jnp.sum(pres.astype(jnp.int32)), axis)
-        return tuple(outs) + (a2a(filled).reshape(-1), rows[None], ovf[None])
+            # NULLs masked to zero: every NULL routes alike, whatever the column's nullability on
+            # the other side of a join
+            ks.append(jnp.where(next(it), data, jnp.zeros((), data.dtype)) if has_v else data)
+        dest = jnp.where(pres, _hash_dest_multi(ks, n_parts), jnp.int32(n_parts))  # an absent row: bound for nowhere
+        sent = jnp.sum(dest[None, :] == jnp.arange(n_parts, dtype=jnp.int32)[:, None], axis=1, dtype=jnp.int32)
+        return dest, sent
 
     spec = P(axis)
-    f = cached_sm(
-        ("exchange_sharded", mesh, axis, int(capacity), tuple(str(a.dtype) + str(a.shape[1:]) for a in lanes),
-         tuple(kpos)),
-        lambda: jax.jit(shard_map(exchange_program, mesh=mesh, in_specs=(spec,) * (1 + len(lanes)),
-                                  out_specs=(spec,) * (len(lanes) + 3))),
+    count = cached_sm(
+        ("exchange_count", mesh, axis, nullable, tuple(str(a.dtype) for a in key_in)),
+        lambda: jax.jit(shard_map(count_program, mesh=mesh, in_specs=(spec,) * (1 + len(key_in)),
+                                  out_specs=(spec, spec))),
     )
-    *received, present, rows, ovf = f(st.present, *lanes)
-    rows, ovf = jax.device_get((rows, ovf))  # the one wait: an overflow has to be known before the rows are used
-    out = ShardedTable(_table_from(st.table, spots, received), present, mesh, axis, part=tuple(key_cols))
-    return out, int(rows[0]), lane_bytes, bool(ovf.any())
+    with tracing.span("exchange.table", keys=list(key_cols), parts=n_parts) as sp:
+        dest, sent = count(st.present, *key_in)
+        sent = np.asarray(sent)  # the one wait: n_parts x n_parts counts; the rows that entered are their sum
+        rows_in, max_bucket = int(sent.sum()), int(sent.max())
+        capacity = _counted_capacity(max_bucket, per_shard)
+        slots_out = n_parts * n_parts * capacity
+        sp.annotate(rows_in=rows_in, capacity=capacity, max_bucket=max_bucket, fill=rows_in / slots_out)
+        est = exchange_bytes_estimate(lane_bytes + 5, n_parts, capacity)  # a slot: its lanes, a 4-byte route index, a flag
+        if est > device_memory_budget():
+            raise MemoryBudgetExceeded(
+                f"exchange at capacity {capacity} needs ~{est} device bytes a chip "
+                f"(budget {device_memory_budget()}); split the batch")
+
+        def exchange_program(dest, *payload):
+            src, filled, ovf = _route(dest, n_parts, capacity)
+            a2a = lambda x: lax.all_to_all(x, axis, split_axis=0, concat_axis=0, tiled=True)
+            outs = [a2a(a[src]).reshape((-1,) + a.shape[1:]) for a in payload]
+            return tuple(outs) + (a2a(filled).reshape(-1), ovf[None])
+
+        f = cached_sm(
+            ("exchange_sharded", mesh, axis, capacity, tuple(str(a.dtype) + str(a.shape[1:]) for a in lanes)),
+            lambda: jax.jit(shard_map(exchange_program, mesh=mesh, in_specs=(spec,) * (1 + len(lanes)),
+                                      out_specs=(spec,) * (len(lanes) + 2))),
+        )
+        *received, present, ovf = f(dest, *lanes)
+    _count_exchange(rows_in, lane_bytes)
+    _exchange_counter("slots_out").inc(slots_out)
+    return ShardedTable(_table_from(st.table, spots, received), present, mesh, axis, part=tuple(key_cols),
+                        overflow=st.overflow + ((tuple(key_cols), capacity, ovf),))
 
 
 _SHARDED_HOWS = ("sum", "count", "count_all", "min", "max", "mean")
@@ -997,8 +1005,7 @@ def groupby_sharded(st: ShardedTable, key_cols: Sequence[str],
                                   in_specs=(spec,) * (1 + n_keys + n_vals + len(valid_lanes)),
                                   out_specs=(spec,) * (n_keys + 2 * n_vals + 1))),
     )
-    with tracing.span("exchange.groupby", rows_in=st.num_rows, keys=list(key_cols), capacity=cap,
-                      parts=n_parts, attempt=0):
+    with tracing.span("exchange.groupby", rows_in=st.num_rows, keys=list(key_cols), capacity=cap, parts=n_parts):
         outs = f(st.present, *key_lanes, *val_lanes, *valid_lanes)
     gks, gas, gavs, gv = outs[:n_keys], outs[n_keys:n_keys + n_vals], outs[n_keys + n_vals:-1], outs[-1]
     cols, names, li = [], [], 0
@@ -1021,7 +1028,7 @@ def groupby_sharded(st: ShardedTable, key_cols: Sequence[str],
             cols.append(Column(src.dtype, data=g, validity=gav))
         names.append(oname)
     ordered = key_cols[0] if len(key_cols) == 1 and not key_nullable[0] else None
-    return ShardedTable(Table(cols, names), gv, mesh, axis, part=st.part, ordered=ordered)
+    return ShardedTable(Table(cols, names), gv, mesh, axis, part=st.part, ordered=ordered, overflow=st.overflow)
 
 
 @op_boundary("join_sharded")
@@ -1118,7 +1125,7 @@ def join_sharded(left: ShardedTable, right, on: Tuple[str, str], how: str,
                                   out_specs=(spec,) * n_out)),
     )
     with tracing.span("exchange.join", rows_in=left.num_rows, keys=list(on), capacity=r_rows, parts=n_parts,
-                      attempt=0, how=how, broadcast=not sharded_right):
+                      how=how, broadcast=not sharded_right):
         outs = f(*l_in, *r_in)
     cols, names = list(left.table.columns), list(left.names)
     it = iter(outs[1:])
@@ -1126,7 +1133,8 @@ def join_sharded(left: ShardedTable, right, on: Tuple[str, str], how: str,
         data = next(it)
         cols.append(Column(c.dtype, data=data, validity=next(it) if c.validity is not None else None))
         names.append(p)
-    return left.replace(table=Table(cols, names), present=outs[0])
+    return left.replace(table=Table(cols, names), present=outs[0],
+                        overflow=left.overflow + (right.overflow if sharded_right else ()))
 
 
 @op_boundary("gather_table")
@@ -1138,7 +1146,12 @@ def gather_table(st: ShardedTable) -> Table:
     from .mesh import replicated
 
     with tracing.span("exchange.gather", slots=st.num_rows, parts=st.n_parts, cols=st.table.num_columns) as sp:
-        sel = np.flatnonzero(np.asarray(st.present))
+        present, flags = jax.device_get((st.present, [flag for _keys, _cap, flag in st.overflow]))
+        for (keys, cap, _flag), flag in zip(st.overflow, flags):
+            if flag.any():
+                _exchange_counter("overflows").inc()
+                raise ExchangeOverflow(f"exchange on {list(keys)}: a bucket of {cap} slots overflowed")
+        sel = np.flatnonzero(present)
         sp.annotate(rows_out=int(sel.size))
         idx, rep = jnp.asarray(sel), replicated(st.mesh)
         cols = [Column(c.dtype, data=jax.device_put(c.data[idx], rep),
