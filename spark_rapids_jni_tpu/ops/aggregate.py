@@ -41,7 +41,7 @@ from ..utils import metrics, tracing
 from ..utils.dispatch import op_boundary
 from . import bitutils, f64acc
 from .copying import gather
-from .sort import sorted_order
+from .sort import sorted_order, string_key_lanes
 
 __all__ = ["groupby_aggregate", "groupby_sum_bounded"]
 
@@ -107,15 +107,10 @@ def _keys_equal_neighbor(col: Column, order: jnp.ndarray) -> jnp.ndarray:
     v = col.valid_mask()[order]
     same_valid = v[1:] == v[:-1]
     if col.dtype.id == TypeId.STRING:
-        offs = col.offsets
-        lens = (offs[1:] - offs[:-1])[order]
-        same_len = lens[1:] == lens[:-1]
-        # compare up to 16-byte prefix lanes (sort key resolution)
-        from .sort import _string_prefix_keys
-
-        k1, k2 = _string_prefix_keys(Column(col.dtype, offsets=col.offsets, chars=col.chars))
-        same_data = (k1[order][1:] == k1[order][:-1]) & (k2[order][1:] == k2[order][:-1])
-        same = same_len & same_data
+        same = jnp.ones((max(order.shape[0] - 1, 0),), bool)
+        for lane in string_key_lanes(col):  # every byte and the length
+            k = lane[order]
+            same = same & (k[1:] == k[:-1])
     elif col.dtype.id == TypeId.DECIMAL128:
         d = col.data[order]
         same = jnp.all(d[1:] == d[:-1], axis=1)

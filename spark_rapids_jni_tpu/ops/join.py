@@ -32,6 +32,14 @@ fallback). An exception from the kernel propagates — a Mosaic refusal
 must be seen, not answered from another path. The serving tier lands on
 the op span and the ``dispatch.tier.*`` counters
 (utils/dispatch.note_tier).
+
+PHASE SPANS (srjt-trace): under each ``op.*_join`` span, side by side
+and never nested, ``join.factorize`` (dense ids of both sides' keys, or
+the paged table's build), ``join.probe`` (each probe row's match range:
+dispatch only), ``join.expand`` (the output-size wait and the gather
+maps) and ``join.gather`` (the output columns). Each times what the HOST
+did. Counters ``join.calls`` / ``join.rows_probed`` / ``join.rows_out``
+count with tracing off.
 """
 
 from __future__ import annotations
@@ -43,6 +51,7 @@ import numpy as np
 
 from ..columnar import Column, Table
 from ..columnar.dtype import TypeId
+from ..utils import metrics, tracing
 from ..utils.dispatch import note_tier, op_boundary
 from .aggregate import _segment_ids
 from .copying import concatenate, gather, gather_column
@@ -59,14 +68,32 @@ __all__ = [
 ]
 
 
+def _factorize_span(left_keys: Table, right_keys: Table):
+    return tracing.span(
+        "join.factorize",
+        rows_left=left_keys.num_rows,
+        rows_right=right_keys.num_rows,
+        keys=left_keys.num_columns,
+        string_keys=sum(c.dtype.id == TypeId.STRING for c in left_keys.columns),
+    )
+
+
 def _factorize(left_keys: Table, right_keys: Table) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dense group ids for each row of both sides (equal keys <-> equal id)."""
     nl, nr = left_keys.num_rows, right_keys.num_rows
-    both = concatenate([left_keys, right_keys])
-    order = sorted_order(both)
-    seg, _num = _segment_ids(both, order)
-    ids = jnp.zeros((nl + nr,), jnp.int32).at[order].set(seg)
+    with _factorize_span(left_keys, right_keys):
+        both = concatenate([left_keys, right_keys])
+        order = sorted_order(both)
+        seg, _num = _segment_ids(both, order)
+        ids = jnp.zeros((nl + nr,), jnp.int32).at[order].set(seg)
     return ids[:nl], ids[nl:]
+
+
+def _count_join(rows_probed: int, rows_out: int) -> None:
+    reg = metrics.registry()
+    reg.counter("join.calls").inc()
+    reg.counter("join.rows_probed").inc(rows_probed)
+    reg.counter("join.rows_out").inc(rows_out)
 
 
 def _any_null(keys: Table) -> Optional[jnp.ndarray]:
@@ -118,22 +145,27 @@ def _pallas_join_maps(
         return None  # degenerate shapes: the XLA path's early returns apply
     rcol = right_keys.columns[0]
     lcol = left_keys.columns[0]
-    table = build_paged_table(rcol.data, rcol.validity)
+    with _factorize_span(left_keys, right_keys):
+        table = build_paged_table(rcol.data, rcol.validity)
     if table is None:
         return None
-    lo, eq = pallas_probe_paged(lcol.data, lcol.validity, table, interpret)
+    with tracing.span("join.probe", rows_probed=nl, tier="pallas"):
+        lo, eq = pallas_probe_paged(lcol.data, lcol.validity, table, interpret)
 
-    counts = eq if how == "inner" else jnp.maximum(eq, 1)
-    lrow, within, _cum = _expand_rows(counts)
-    if lrow.shape[0] == 0:
-        return lrow, within
-    matched = eq[lrow] > 0
-    rpos = jnp.where(matched, lo[lrow] + within, jnp.int32(-1))
-    rrow = jnp.where(
-        rpos >= 0,
-        table.r_order[jnp.clip(rpos, 0, table.nm - 1)],
-        jnp.int32(-1),
-    )
+    with tracing.span("join.expand") as sp:
+        counts = eq if how == "inner" else jnp.maximum(eq, 1)
+        lrow, within, _cum = _expand_rows(counts)
+        sp.annotate(rows_out=int(lrow.shape[0]))
+        _count_join(nl, int(lrow.shape[0]))
+        if lrow.shape[0] == 0:
+            return lrow, within
+        matched = eq[lrow] > 0
+        rpos = jnp.where(matched, lo[lrow] + within, jnp.int32(-1))
+        rrow = jnp.where(
+            rpos >= 0,
+            table.r_order[jnp.clip(rpos, 0, table.nm - 1)],
+            jnp.int32(-1),
+        )
     return lrow, rrow
 
 
@@ -170,26 +202,37 @@ def join_gather_maps(
             note_tier("pallas", "join_gather_maps")
             return maps
     note_tier("xla", "join_gather_maps")
-    nl, nr = left_keys.num_rows, right_keys.num_rows
+    nl = left_keys.num_rows
     lid, rid = _factorize(left_keys, right_keys)
 
-    lnull = _any_null(left_keys)
-    rnull = _any_null(right_keys)
-    if rnull is not None:
-        # null right keys can never match: pull them out of the probe set
-        rid = jnp.where(rnull, jnp.int32(-1), rid)
+    with tracing.span("join.probe", rows_probed=nl, tier="xla"):
+        lnull = _any_null(left_keys)
+        rnull = _any_null(right_keys)
+        if rnull is not None:
+            # null right keys can never match: pull them out of the probe set
+            rid = jnp.where(rnull, jnp.int32(-1), rid)
 
-    r_order = jnp.argsort(rid).astype(jnp.int32)
-    rid_sorted = rid[r_order]
+        r_order = jnp.argsort(rid).astype(jnp.int32)
+        rid_sorted = rid[r_order]
 
-    probe_id = lid if lnull is None else jnp.where(lnull, jnp.int32(-2), lid)
-    lo = jnp.searchsorted(rid_sorted, probe_id, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(rid_sorted, probe_id, side="right").astype(jnp.int32)
+        probe_id = lid if lnull is None else jnp.where(lnull, jnp.int32(-2), lid)
+        lo = jnp.searchsorted(rid_sorted, probe_id, side="left").astype(jnp.int32)
+        hi = jnp.searchsorted(rid_sorted, probe_id, side="right").astype(jnp.int32)
+
+    with tracing.span("join.expand") as sp:
+        lrow, rrow = _expand_maps(how, lo, hi, r_order, probe_id, rid, rnull)
+        sp.annotate(rows_out=int(lrow.shape[0]))
+    _count_join(nl, int(lrow.shape[0]))
+    return lrow, rrow
+
+
+def _expand_maps(how, lo, hi, r_order, probe_id, rid, rnull):
+    """Match ranges [lo, hi) over the right side's sorted order ->
+    (left_idx, right_idx), after the output-size wait."""
+    nr = r_order.shape[0]
     counts = hi - lo
-
     if how in ("left", "full"):
         counts = jnp.maximum(counts, 1)
-
     lrow, within, _cum = _expand_rows(counts)
     if lrow.shape[0] == 0 and how != "full":
         return lrow, within
@@ -228,17 +271,22 @@ def semi_anti_gather_map(
     if how not in ("semi", "anti"):
         raise ValueError(f"unsupported semi/anti type {how!r}")
     lid, rid = _factorize(left_keys, right_keys)
-    lnull = _any_null(left_keys)
-    rnull = _any_null(right_keys)
-    if rnull is not None:
-        rid = jnp.where(rnull, jnp.int32(-1), rid)
-    rid_sorted = jnp.sort(rid)
-    probe_id = lid if lnull is None else jnp.where(lnull, jnp.int32(-2), lid)
-    lo = jnp.searchsorted(rid_sorted, probe_id, side="left")
-    hi = jnp.searchsorted(rid_sorted, probe_id, side="right")
-    keep = (hi > lo) if how == "semi" else (hi == lo)
-    total = int(jnp.sum(keep))  # host sync: output size
-    return jnp.nonzero(keep, size=total)[0].astype(jnp.int32)
+    with tracing.span("join.probe", rows_probed=left_keys.num_rows, tier="xla"):
+        lnull = _any_null(left_keys)
+        rnull = _any_null(right_keys)
+        if rnull is not None:
+            rid = jnp.where(rnull, jnp.int32(-1), rid)
+        rid_sorted = jnp.sort(rid)
+        probe_id = lid if lnull is None else jnp.where(lnull, jnp.int32(-2), lid)
+        lo = jnp.searchsorted(rid_sorted, probe_id, side="left")
+        hi = jnp.searchsorted(rid_sorted, probe_id, side="right")
+        keep = (hi > lo) if how == "semi" else (hi == lo)
+    with tracing.span("join.expand") as sp:
+        total = int(jnp.sum(keep))  # host sync: output size
+        sp.annotate(rows_out=total)
+        out = jnp.nonzero(keep, size=total)[0].astype(jnp.int32)
+    _count_join(left_keys.num_rows, total)
+    return out
 
 
 def _joined_table(
@@ -246,14 +294,16 @@ def _joined_table(
 ) -> Table:
     cols: List[Column] = []
     names: List[str] = []
-    for name, col in zip(left.names, left.columns):
-        cols.append(gather_column(col, lmap))
-        names.append(name)
-    for name, col in zip(right.names, right.columns):
-        if not keep_right_on and name in on:
-            continue
-        cols.append(gather_column(col, rmap, check_bounds=True))
-        names.append(name)
+    with tracing.span("join.gather") as sp:
+        for name, col in zip(left.names, left.columns):
+            cols.append(gather_column(col, lmap))
+            names.append(name)
+        for name, col in zip(right.names, right.columns):
+            if not keep_right_on and name in on:
+                continue
+            cols.append(gather_column(col, rmap, check_bounds=True))
+            names.append(name)
+        sp.annotate(cols=len(cols))
     return Table(cols, names)
 
 
@@ -304,33 +354,40 @@ def full_join(left: Table, right: Table, on: Sequence[str]) -> Table:
     unmatched right row (null-extended left, key columns coalesced from
     the right side) — cudf full_join surface."""
     lmap, rmap = join_gather_maps(left.select(on), right.select(on), "full")
-    use_left = lmap >= 0
     cols: List[Column] = []
     names: List[str] = []
-    for name, col in zip(left.names, left.columns):
-        g = gather_column(col, lmap, check_bounds=True)
-        if name in on:
-            rg = gather_column(right.column(name), rmap, check_bounds=True)
-            g = _coalesce_fixed(g, rg, use_left)
-        cols.append(g)
-        names.append(name)
-    for name, col in zip(right.names, right.columns):
-        if name in on:
-            continue
-        cols.append(gather_column(col, rmap, check_bounds=True))
-        names.append(name)
+    with tracing.span("join.gather") as sp:
+        use_left = lmap >= 0
+        for name, col in zip(left.names, left.columns):
+            g = gather_column(col, lmap, check_bounds=True)
+            if name in on:
+                rg = gather_column(right.column(name), rmap, check_bounds=True)
+                g = _coalesce_fixed(g, rg, use_left)
+            cols.append(g)
+            names.append(name)
+        for name, col in zip(right.names, right.columns):
+            if name in on:
+                continue
+            cols.append(gather_column(col, rmap, check_bounds=True))
+            names.append(name)
+        sp.annotate(cols=len(cols))
     return Table(cols, names)
+
+
+def _gathered(left: Table, lmap) -> Table:
+    with tracing.span("join.gather", cols=left.num_columns):
+        return gather(left, lmap)
 
 
 @op_boundary("left_semi_join")
 def left_semi_join(left: Table, right: Table, on: Sequence[str]) -> Table:
     """Left rows with at least one right match (Spark IN-subquery plan)."""
     lmap = semi_anti_gather_map(left.select(on), right.select(on), "semi")
-    return gather(left, lmap)
+    return _gathered(left, lmap)
 
 
 @op_boundary("left_anti_join")
 def left_anti_join(left: Table, right: Table, on: Sequence[str]) -> Table:
     """Left rows with no right match (Spark NOT EXISTS plan)."""
     lmap = semi_anti_gather_map(left.select(on), right.select(on), "anti")
-    return gather(left, lmap)
+    return _gathered(left, lmap)

@@ -158,7 +158,9 @@ def test_groupby_phase_spans_are_children_of_the_operator(q1_spans):
     for phase in ("groupby.sort", "groupby.segments", "groupby.keys"):
         assert _one(q1_spans, phase)["parent"] == op
     assert _one(q1_spans, "groupby.sort")["annotations"] == {"rows": _one(
-        q1_spans, "plan.project")["annotations"]["rows_out"], "keys": 2}
+        q1_spans, "plan.project")["annotations"]["rows_out"], "keys": 2,
+        # two int8 keys: a null rank and one lane each (PR 32), no STRING
+        "key_lanes": 4, "string_keys": 0}
     assert _one(q1_spans, "groupby.segments")["annotations"] == {"groups": 6}
     aggs = sorted(
         (s["name"], s["annotations"]["col"], s["annotations"]["dtype"])
@@ -206,6 +208,89 @@ def test_agg_counters_tell_the_one_program_from_the_eager_branches():
     assert _counter("groupby.agg.eager") == eager + 5
     counters = runtime.stats_report()["metrics"]["counters"]
     assert counters["groupby.agg.jitted"] >= 2 and counters["groupby.agg.eager"] >= 5
+
+
+# ---------------------------------------------------------------------------
+# execution: join.* under op.*_join, and the key lanes of a STRING key (ISSUE 32)
+# ---------------------------------------------------------------------------
+
+N_FACT, N_DIM = 3000, 40
+STAR_COUNTERS = ("join.calls", "join.rows_probed", "join.rows_out", "keys.string.columns", "keys.string.lanes")
+
+
+def _star_tables(rng):
+    brands = [f"exportischolar #{i}" for i in range(1, N_DIM + 1)]  # 17 or 18 bytes, alike in the first 16
+    return {
+        "fact": Table([Column.from_numpy(rng.integers(1, N_DIM + 11, N_FACT).astype(np.int32), dt.INT32),
+                       Column.from_numpy(rng.integers(1, 100, N_FACT).astype(np.int64), dt.INT64)], ["f_dim", "f_v"]),
+        "dim": Table([Column.from_numpy(np.arange(1, N_DIM + 1, dtype=np.int32), dt.INT32),
+                      Column.from_pylist(brands, dt.STRING)], ["d_sk", "d_brand"]),
+    }
+
+
+def _star_plan():
+    x = P.Join(P.Scan("fact"), P.Scan("dim"), on=(("f_dim", "d_sk"),), bounded=None)
+    agg = P.Aggregate(x, keys=("d_brand",), aggs=(P.AggSpec("f_v", "sum", "total"),))
+    return P.Sort(agg, (("d_brand", False),))
+
+
+@pytest.fixture(scope="module")
+def star_spans():
+    """(spans, counters moved, answer) of one traced star request: the
+    second run, and the five counters over an UNTRACED third."""
+    tables = _star_tables(np.random.default_rng(32))
+    cp = P.compile_ir(_star_plan(), tables, name="star_shaped")
+    sched = serve.Scheduler(max_concurrent=1, name="phase-spans-star")
+    prev = tracing.is_enabled()
+    try:
+        sched.submit(cp).result()
+        trace_sink.reset_for_tests()
+        tracing.set_enabled(True)
+        out = sched.submit(cp).result()
+        jax.block_until_ready([c.data for c in out.columns if c.data is not None])
+        rec = trace_sink.recorder().last(1)[0]
+        tracing.set_enabled(False)
+        before = {k: _counter(k) for k in STAR_COUNTERS}
+        sched.submit(cp).result()
+        moved = {k: _counter(k) - v for k, v in before.items()}
+    finally:
+        tracing.set_enabled(prev)
+        sched.shutdown()
+    assert rec["name"] == "serve.query" and rec["dropped_spans"] == 0
+    return rec["spans"], moved, out
+
+
+def test_join_phase_spans_lie_side_by_side_under_the_operator(star_spans):
+    spans, _, out = star_spans
+    op = _one(spans, "op.inner_join")
+    phases = [s for s in spans if s["name"].startswith("join.")]
+    assert sorted(s["name"] for s in phases) == ["join.expand", "join.factorize", "join.gather", "join.probe"]
+    assert all(s["parent"] == op["span"] for s in phases)  # none nests in another
+    joined = _one(spans, "join.expand")["annotations"]["rows_out"]
+    assert 0 < joined < N_FACT  # ten of the fact's fifty keys meet no dimension row
+    assert _one(spans, "join.factorize")["annotations"] == {
+        "rows_left": N_FACT, "rows_right": N_DIM, "keys": 1, "string_keys": 0, "key_lanes": 2}
+    assert _one(spans, "join.probe")["annotations"] == {"rows_probed": N_FACT, "tier": "xla"}
+    assert _one(spans, "join.gather")["annotations"] == {"cols": 3}
+    assert sum(s["dur_us"] for s in phases) <= op["dur_us"]
+    assert out.column("d_brand").to_pylist() == sorted((f"exportischolar #{i}" for i in range(1, N_DIM + 1)),
+                                                       reverse=True)
+
+
+def test_sort_spans_say_their_key_lanes(star_spans):
+    spans, _, _ = star_spans
+    joined = _one(spans, "join.expand")["annotations"]["rows_out"]
+    # an 18-byte brand: a null rank, three 8-byte lanes and the length
+    assert _one(spans, "groupby.sort")["annotations"] == {"rows": joined, "keys": 1, "key_lanes": 5, "string_keys": 1}
+    assert _one(spans, "op.sort_by_key")["annotations"] == {"key_lanes": 5, "string_keys": 1}
+
+
+def test_join_and_string_key_counters_count_with_tracing_off(star_spans):
+    spans, moved, _ = star_spans
+    joined = _one(spans, "join.expand")["annotations"]["rows_out"]
+    # the brand's lanes are made three times a request: the group-by's sort, its boundaries, the Sort
+    assert moved == {"join.calls": 1, "join.rows_probed": N_FACT, "join.rows_out": joined,
+                     "keys.string.columns": 3, "keys.string.lanes": 12}
 
 
 @pytest.mark.parametrize("whole,parts", [
