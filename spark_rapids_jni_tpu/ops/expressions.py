@@ -7,6 +7,22 @@ three-valued-logic). The TPU shape: every node is a pure jnp map over
 [N] arrays, so an entire predicate/projection tree fuses into one XLA
 kernel at jit time.
 
+Where the jit boundary is: ``evaluate`` itself is plain (every ``jnp``
+call of a tree is a launch of its own when it is called eagerly: a
+FLOAT64 operand on a backend without float64 is ~300 of them, a FLOAT64
+result ~225), so that callers trace whole trees into THEIR program. Two
+do: the fused pipeline (``pipeline.py``: filter and projections inside the
+one ``CompiledPipeline`` program) and, since ISSUE 33, every Filter and
+Project stage of a compiled plan, local or over a mesh
+(``plan/compiler.py::_StageProgram``: all of a stage's trees in one
+program, built when the stage is lowered). What stays eager there, by what
+the tree reads: a tree that names a column which is not fixed width. The
+nodes that read STRING lanes are not in this module (``plan/exprs.py``'s
+``_RegexEval`` and ``_PartHashEval`` over a STRING key): they read offsets
+and chars and wait on the host for the longest string, which no trace
+can. A direct ``Expression.evaluate(table)`` outside those two is eager,
+as before.
+
 Example::
 
     e = (col("qty") * col("price")).alias("revenue")
@@ -61,6 +77,35 @@ def _to_value(col_: Column) -> _Value:
     if d.id == TypeId.BOOL8:
         return _Value(col_.data.astype(bool), col_.validity, d)
     return _Value(col_.data, col_.validity, d)
+
+
+def _is_host_scalar(x) -> bool:
+    return isinstance(x, (int, float)) and not isinstance(x, bool)
+
+
+def _tie(lit, peer):
+    """A literal operand of an arithmetic node as lanes of its peer:
+    ``select(peer == peer, lit, NaN)`` (a NaN lane of the peer makes the
+    result NaN either way). Traced into a program, a bare literal is a
+    compile-time constant, and XLA's algebraic simplifier rewrites
+    arithmetic on constants in ways that round otherwise than the same
+    tree launched one call at a time: ``(c + b) - c`` to ``b + (c - c)``
+    inside 2Sum (the dd pair's error term is then 0: q1's
+    ``1 - l_discount`` read 0.99000001 for 0.99 under jit), ``(x + c1) - c2``
+    to ``x + (c1 - c2)``, ``x / c`` to ``x * (1 / c)``. Tied to its peer
+    the literal is no constant, and the program computes what the eager
+    evaluator does, lane for lane. Integer arithmetic is exact under
+    those rewrites and is left alone (a weak Python int keeps the
+    column's dtype)."""
+    if _is_dd(peer):
+        from .f64acc import DD, dd_from_any
+
+        c, ok, nan = dd_from_any(lit), peer.hi == peer.hi, jnp.float32(jnp.nan)
+        return DD(jnp.where(ok, c.hi, nan), jnp.where(ok, c.lo, nan))
+    peer = jnp.asarray(peer)
+    if jnp.issubdtype(peer.dtype, jnp.floating):
+        return jnp.where(peer == peer, jnp.asarray(lit, peer.dtype), jnp.asarray(jnp.nan, peer.dtype))
+    return lit
 
 
 def _both_valid(a, b):
@@ -188,6 +233,11 @@ class _BinOp(Expression):
             from .f64acc import dd_from_any
 
             da, db = dd_from_any(da), dd_from_any(db)
+        if not self.bool_out:  # arithmetic: a literal operand is no compile-time constant
+            if _is_host_scalar(va.data) and not _is_host_scalar(vb.data):
+                da = _tie(da, db)
+            elif _is_host_scalar(vb.data) and not _is_host_scalar(va.data):
+                db = _tie(db, da)
         data = self.fn(da, db)
         d = None if self.bool_out else (va.dtype if va.dtype is not None else vb.dtype)
         if d is not None and not d.is_fixed_width:
@@ -207,16 +257,24 @@ class _Div(Expression):
 
     def _eval(self, table):
         va, vb = self.a._eval(table), self.b._eval(table)
+        lit_a, lit_b = _is_host_scalar(va.data), _is_host_scalar(vb.data)
         if bitutils.backend_has_f64():
+            num = va.data if lit_a else jnp.asarray(va.data).astype(jnp.float64)
             denom = jnp.asarray(vb.data).astype(jnp.float64)
-            zero = jnp.asarray(vb.data) == 0
-            data = va.data / jnp.where(zero, 1, denom)
+            if lit_b and not lit_a:
+                denom = _tie(vb.data, num)
+            zero = denom == 0
+            data = num / jnp.where(zero, 1, denom)
         else:
             # dd division on the f64-emulating tier (~2^-48 relative)
             from .f64acc import DD, dd_from_any
 
             num = dd_from_any(va.data)
             den = dd_from_any(vb.data)
+            if lit_b and not lit_a:
+                den = _tie(den, num)
+            elif lit_a and not lit_b:
+                num = _tie(num, den)
             zero = (den.hi == 0) & (den.lo == 0)
             safe = DD(jnp.where(zero, jnp.float32(1), den.hi), jnp.where(zero, jnp.float32(0), den.lo))
             data = num / safe

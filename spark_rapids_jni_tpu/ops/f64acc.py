@@ -618,6 +618,12 @@ def div_f64bits_by_int(bits: jnp.ndarray, cnt: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def _as_computed(x):
+    """``x`` as the float32 it was rounded to, behind a select on itself
+    that no algebraic rewrite looks through (a NaN stays a NaN)."""
+    return jnp.where(x == x, x, jnp.float32(jnp.nan))
+
+
 def _two_sum(a, b):
     """Knuth 2Sum: s + e == a + b exactly (IEEE f32 add only)."""
     s = a + b
@@ -626,18 +632,39 @@ def _two_sum(a, b):
     return s, e
 
 
+def _rounded(x):
+    """A float32 product as it is ROUNDED, and nothing wider, for the add
+    or subtract it feeds. Inside one fused program a compiler may contract
+    a multiply into the add behind it (an FMA keeps the product exact),
+    which the error-free transformations below and ``DD``'s cross terms do
+    not survive: a contracted ``hi * o.lo + ...`` rounds once where the
+    chain launched one ``jnp`` call at a time rounds twice. XLA:CPU always
+    allows the contraction (with the dd branch forced onto the CPU, 48% of
+    a traced product's low halves differed from the eager ones); the TPU
+    compiler did not contract (ISSUE 33, ``benchmarks/calls/pr33_bits.py``:
+    0 of 1 M lanes with this fence taken out), so on the chip it is a
+    guard, not a mend. The product passes a select on itself (no compiler
+    contracts through it; a NaN stays a NaN: what mends the CPU) and an
+    ``optimization_barrier`` (a fusion boundary where the compiler honours
+    it, as the TPU's does; XLA:CPU drops it before it fuses), so that a
+    traced chain computes what the eager one does, lane for lane."""
+    return lax.optimization_barrier(_as_computed(x))
+
+
 def _split(a):
     """Dekker split: a == hi + lo with 12-bit halves (f32: 2^12+1)."""
-    c = jnp.float32(4097.0) * a
+    c = _rounded(jnp.float32(4097.0) * a)
     hi = c - (c - a)
     return hi, a - hi
 
 
 def _two_prod(a, b):
     """p + e == a * b exactly, via Dekker splitting (no FMA dependence)."""
-    p = a * b
+    p = _rounded(a * b)
     ah, al = _split(a)
     bh, bl = _split(b)
+    # the four partial products are exact in float32 (12-bit halves):
+    # contracted or not, they round the same
     e = ((ah * bh - p) + ah * bl + al * bh) + al * bl
     return p, e
 
@@ -675,7 +702,7 @@ class DD(NamedTuple):
     def __mul__(self, o):
         o = dd_from_any(o)
         p, e = _two_prod(self.hi, o.hi)
-        e = e + self.hi * o.lo + self.lo * o.hi
+        e = e + _rounded(self.hi * o.lo) + _rounded(self.lo * o.hi)
         hi, lo = _two_sum(p, e)
         return DD(hi, lo)
 
@@ -686,7 +713,7 @@ class DD(NamedTuple):
         q1 = self.hi / o.hi
         # r = self - q1 * o, evaluated in dd
         p, e = _two_prod(q1, o.hi)
-        r = self + DD(-p, -e - q1 * o.lo)
+        r = self + DD(-p, -e - _rounded(q1 * o.lo))
         q2 = (r.hi + r.lo) / o.hi
         hi, lo = _two_sum(q1, q2)
         return DD(hi, lo)

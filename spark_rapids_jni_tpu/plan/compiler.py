@@ -13,7 +13,11 @@ projections, and bounded group-key domains are scanned host-side from
 the bound tables exactly as the hand-built queries did. Everything the
 fused grammar cannot express — fact-fact set ops, post-aggregate joins,
 windows, sorts, unions — lowers to the tested ``ops/`` operators over
-the (small) intermediate tables.
+the (small) intermediate tables. On that operator tier a Filter's and a
+Project's expressions are still ONE device program a stage
+(``_StageProgram``, ISSUE 33), and so is the float64 normalisation of an
+aggregate's outputs (``_normalize_agg_columns``); what follows a Filter's
+mask (the compaction) and the operators themselves launch as ``ops/`` does.
 
 Estimates (Theseus, arxiv 2508.05029: the plan is where data-movement /
 memory decisions belong): every stage carries ``rows``/``bytes``
@@ -38,6 +42,7 @@ import json
 import math
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..columnar import Column, Table
@@ -92,43 +97,35 @@ def _width(schema: Schema) -> int:
 
 
 def _table_nbytes(t: Table) -> int:
-    import jax
-
     t = getattr(t, "table", t)  # a ShardedTable: its global arrays (padding included)
     return sum(int(getattr(leaf, "nbytes", 0))
                for leaf in jax.tree_util.tree_leaves(t))
 
 
-def _eval_expr(e: PExpr, table: Table, want: DType) -> Column:
-    """Evaluate a lowered plan expression, broadcasting a scalar result
-    (bare literal projection) to the table's row count and pinning the
-    inferred dtype for typed null literals."""
-    n_rows = table.num_rows
-    if is_null_lit(e):
+def _materialize(low, table: Table, want: DType, rows: int) -> Column:
+    """One lowered expression tree over ``table`` as the column the plan's
+    schema declares (``want``), ``rows`` long: a scalar result (a bare
+    literal projection) is broadcast, an integral or BOOL8 result is pinned
+    to the inferred dtype. ``low`` None is the typed SQL NULL. Pure ``jnp``
+    over the columns' arrays: the same call serves inside a stage's one
+    program and for the trees that stay eager."""
+    if low is None:
         # typed SQL NULL: materialize at the DECLARED dtype — the
         # runtime literal tier evaluates NULL as INT32 lanes, which
         # would silently contradict the inferred schema for FLOAT64
         # (or any non-int) rolled keys in a grouping-set union
         if not want.is_fixed_width:
             raise PlanError(f"cannot materialize a NULL literal as {want!r}")
-        shape = (n_rows, 4) if want.id == TypeId.DECIMAL128 else (n_rows,)
+        shape = (rows, 4) if want.id == TypeId.DECIMAL128 else (rows,)
         return Column(want, data=jnp.zeros(shape, want.jnp_dtype),
-                      validity=jnp.zeros((n_rows,), bool))
-    src = is_col(e)
-    if src is not None:
-        # a bare column reference is the column: a STRING has offsets and
-        # chars and no ``data`` for ``Expression.evaluate`` to read (the
-        # narrowing Projects that pruning inserts are all of this kind)
-        c = table.column(src)
-    else:
-        c = e.lower().evaluate(table)
-    n = table.num_rows
+                      validity=jnp.zeros((rows,), bool))
+    c = low.evaluate(table)
     if c.data is not None and c.data.ndim == 0:
-        data = jnp.broadcast_to(c.data, (n,))
-        v = None if c.validity is None else jnp.broadcast_to(c.validity, (n,))
+        data = jnp.broadcast_to(c.data, (rows,))
+        v = None if c.validity is None else jnp.broadcast_to(c.validity, (rows,))
         c = Column(c.dtype, data=data, validity=v)
-    elif len(c) != n:
-        raise PlanError(f"projection produced {len(c)} rows for {n}")
+    elif len(c) != rows:
+        raise PlanError(f"projection produced {len(c)} rows for {rows}")
     if c.dtype.id != want.id and c.dtype.is_integral and want.is_integral:
         c = Column(want, data=c.data.astype(want.jnp_dtype), validity=c.validity)
     elif c.dtype.id != want.id and want.id == TypeId.BOOL8:
@@ -136,14 +133,123 @@ def _eval_expr(e: PExpr, table: Table, want: DType) -> Column:
     return c
 
 
-def _normalize_agg_column(col: Column, how: str) -> Column:
-    """Bring an operator-tier aggregate column onto the fused tier's
+def _keep(mask: Column, present=None):
+    """A predicate's BOOL8 column as the rows that pass: true and not NULL
+    (and, over a mesh, in a slot that holds a row)."""
+    keep = mask.data.astype(bool)
+    if mask.validity is not None:
+        keep = keep & mask.validity
+    return keep if present is None else present & keep
+
+
+class _StageProgram:
+    """The jit boundary of a Filter or Project stage, local or mesh: ALL of
+    the stage's expression trees as ONE device program (ISSUE 33).
+
+    Built once, when the stage is lowered: every ``PExpr`` is lowered to its
+    ``rt.Expression`` tree here (not on every run), and the trees go three
+    ways, by what the tree and the input schema show and by nothing else:
+
+    - a bare column reference is handed on as the column it is, no launch
+      (a STRING has offsets and chars and no ``data`` for the evaluator to
+      read; a FLOAT64 passed through keeps its u64 lanes, no double-f32
+      round trip: the narrowing Projects that pruning inserts are all of
+      this kind);
+    - a tree that reads fixed-width columns only (or none: a literal, the
+      typed NULL) goes into the program. The program takes the columns the
+      trees name (``PExpr.refs()``) and nothing else, evaluates every tree
+      and ``_materialize``'s post-steps, and returns the output columns, so
+      the compiler shares what the trees share (q1's ``price * (1 - disc)``
+      inside ``charge``; one ``dd_from_f64bits`` a column) where the eager
+      evaluator launched every ``jnp`` call of every tree by itself: ~300
+      launches a FLOAT64 operand and ~225 a FLOAT64 result on a chip
+      without a float64 datapath. jit's own cache keys on shapes, dtypes,
+      validity present or absent and sharding; nothing of a run is kept
+      here, so one ``CompiledPlan`` may run on several serve slots at once;
+    - a tree that reads a column that is not fixed width (``LIKE`` /
+      ``RLIKE``'s matcher and ``part_hash`` over a STRING key read offsets
+      and chars and wait for the longest string on the host) is evaluated
+      eagerly, whole, as before.
+
+    ``fold`` makes the stage a Filter's: the one tree is its predicate and
+    the result is the array of rows that pass (``_keep``, validity and the
+    mesh's ``present`` folded in inside the program). ``sharding`` names
+    the row layout of a mesh stage's arrays: the outputs are constrained to
+    it, so a literal-only column comes back laid out as the inputs are.
+
+    Counted where it engages: ``plan.expr.jitted`` a run that launched the
+    program, ``plan.expr.eager`` a run that evaluated a tree eagerly, and
+    on the stage's span ``jit`` (every computed tree went into the program)
+    and ``exprs`` (how many did)."""
+
+    def __init__(self, exprs, in_schema: Schema, sharding=None, fold: bool = False):
+        self.sharding, self.fold = sharding, fold
+        self.slots: list = []   # an output each: ("col", name) | ("jit", i) | ("eager", low, want)
+        self.trees: list = []   # the program's (lowered tree, want)
+        refs = set()
+        for e, want in exprs:
+            src = is_col(e)
+            if src is not None and not fold:
+                self.slots.append(("col", src))
+                continue
+            low = None if is_null_lit(e) else e.lower()
+            if all(in_schema[r].is_fixed_width for r in e.refs()):
+                self.slots.append(("jit", len(self.trees)))
+                self.trees.append((low, want))
+                refs |= e.refs()
+            else:
+                self.slots.append(("eager", low, want))
+        self.refs = tuple(sorted(refs))
+        self.n_eager = sum(1 for s in self.slots if s[0] == "eager")
+        self._program = jax.jit(self._body, static_argnums=0)
+
+    def _body(self, rows: int, cols, present):
+        if cols:
+            table = Table(list(cols), list(self.refs))
+        else:  # literals only: a table that knows its row count
+            table = Table([Column(dt.BOOL8, data=jnp.zeros((rows,), jnp.uint8))], ["__rows"])
+        out = [_materialize(low, table, want, rows) for low, want in self.trees]
+        if self.fold:
+            out = _keep(out[0], present)
+        if self.sharding is not None:
+            out = jax.lax.with_sharding_constraint(out, self.sharding)
+        return out
+
+    def __call__(self, table: Table, rows: int, present=None):
+        """The stage's outputs over ``table``: a list of Columns, or the
+        array of rows that pass where the stage is a Filter's."""
+        ran = None
+        if self.trees:
+            _durable("plan.expr.jitted").inc()
+            ran = self._program(rows, tuple(table.column(r) for r in self.refs), present)
+        if self.n_eager:
+            _durable("plan.expr.eager").inc()
+        tracing.annotate(jit=bool(self.trees) and not self.n_eager, exprs=len(self.trees))
+        if self.fold:
+            if ran is not None:
+                return ran
+            _, low, want = self.slots[0]
+            return _keep(_materialize(low, table, want, rows), present)
+        out = []
+        for slot in self.slots:
+            if slot[0] == "col":
+                out.append(table.column(slot[1]))
+            elif slot[0] == "jit":
+                out.append(ran[slot[1]])
+            else:
+                out.append(_materialize(slot[1], table, slot[2], rows))
+        return out
+
+
+def _agg_needs_float64(col: Column, how: str) -> bool:
+    """Is an operator-tier aggregate column off the fused tier's
     materialization contract (counts INT64, everything else FLOAT64
-    bit-lanes) so schema inference holds regardless of tier."""
-    if how in ("count", "count_all", "nunique"):
-        return col
-    if col.dtype.id == TypeId.FLOAT64:
-        return col
+    bit-lanes)? A count and a FLOAT64 sum are on it as they are."""
+    return how not in ("count", "count_all", "nunique") and col.dtype.id != TypeId.FLOAT64
+
+
+def _to_float64(col: Column) -> Column:
+    """An integer or FLOAT32 aggregate column as FLOAT64 bit-lanes (exact)."""
     from ..ops import bitutils
     from ..ops.f64acc import i64_to_f64bits
 
@@ -154,7 +260,34 @@ def _normalize_agg_column(col: Column, how: str) -> Column:
         x = col.data.astype(jnp.float64) if bitutils.backend_has_f64() else col.data
         return Column(dt.FLOAT64, data=bitutils.float_store(x, dt.FLOAT64),
                       validity=col.validity)
-    raise PlanError(f"cannot normalize {how} over {col.dtype!r}")
+    raise PlanError(f"cannot normalize an aggregate over {col.dtype!r}")
+
+
+@jax.jit
+def _to_float64_program(cols):
+    """``_to_float64`` of every column of one aggregate stage, as one program."""
+    return tuple(_to_float64(c) for c in cols)
+
+
+def _normalize_agg_columns(cols: List[Column], hows: List[str]) -> List[Column]:
+    """Bring an aggregate stage's operator-tier outputs onto the fused
+    tier's materialization contract so schema inference holds regardless
+    of tier: the columns that need the conversion (an integer min or max:
+    q95's ``wh_lo`` and ``wh_hi``) go through ONE jitted program together
+    (``i64_to_f64bits`` is ~100 ``jnp`` calls a column when launched one by
+    one); those that need none (counts, FLOAT64 sums: every aggregate of
+    q1 and of the store star) are handed on untouched and cost no launch.
+    Elementwise, so over a mesh the outputs lie as the inputs do."""
+    need = [i for i, (c, how) in enumerate(zip(cols, hows)) if _agg_needs_float64(c, how)]
+    tracing.annotate(jit=bool(need), exprs=len(need))
+    if not need:
+        return cols
+    _durable("plan.expr.jitted").inc()
+    done = _to_float64_program(tuple(cols[i] for i in need))
+    cols = list(cols)
+    for i, c in zip(need, done):
+        cols[i] = c
+    return cols
 
 
 class _RunContext:
@@ -257,31 +390,35 @@ class _FilterExec(_Exec):
     kind = "filter"
 
     def __init__(self, node: Filter, schema: Schema, child: _Exec,
-                 est_rows: Optional[int] = None):
+                 est_rows: Optional[int] = None, sharding=None):
         if est_rows is None:
             est_rows = math.ceil(child.est_rows * _FILTER_SELECTIVITY)
         super().__init__(schema, min(est_rows, child.est_rows), [child])
         self.pred = node.predicate
+        self.program = _StageProgram([(self.pred, dt.BOOL8)], child.schema,
+                                     sharding=sharding, fold=True)
 
     def _run(self, ctx):
         from ..ops import copying
 
         t = self.inputs[0].run(ctx)
-        mask = self.pred.lower().evaluate(t)
-        return copying.apply_boolean_mask(t, mask)
+        # the mask is one program; the compaction behind it (``jnp.nonzero``
+        # and a gather a column) is still the eager op's
+        return copying.apply_boolean_mask(t, self.program(t, t.num_rows))
 
 
 class _ProjectExec(_Exec):
     kind = "project"
 
-    def __init__(self, node: Project, schema: Schema, child: _Exec):
+    def __init__(self, node: Project, schema: Schema, child: _Exec, sharding=None):
         super().__init__(schema, child.est_rows, [child])
         self.exprs = node.exprs
+        self.program = _StageProgram([(e, schema[name]) for name, e in self.exprs],
+                                     child.schema, sharding=sharding)
 
     def _run(self, ctx):
         t = self.inputs[0].run(ctx)
-        cols = [_eval_expr(e, t, self.schema[name]) for name, e in self.exprs]
-        return Table(cols, [name for name, _ in self.exprs])
+        return Table(self.program(t, t.num_rows), [name for name, _ in self.exprs])
 
 
 class _JoinExec(_Exec):
@@ -362,6 +499,9 @@ class _ExchangeExec(_Exec):
 # Each hands on a ``parallel.table_ops.ShardedTable``: nothing is compacted
 # and nothing leaves its chip but through an Exchange stage, so the
 # partitioning one exchange establishes serves every keyed stage after it.
+# The Filter and the Project run the local stages' ``_StageProgram`` over the
+# row-sharded arrays, with the rows' sharding named for its outputs: one
+# launch a stage over the four chips, elementwise, no collective.
 
 
 class _MeshScanExec(_ScanExec):
@@ -373,30 +513,27 @@ class _MeshFilterExec(_FilterExec):
 
     sharded = True
 
-    def __init__(self, node, schema, child, est_rows=None):
-        super().__init__(node, schema, child, est_rows=est_rows)
+    def __init__(self, node, schema, child, sharding, est_rows=None):
+        super().__init__(node, schema, child, est_rows=est_rows, sharding=sharding)
         self.part = child.part
 
     def _run(self, ctx):
         st = self.inputs[0].run(ctx)
-        mask = self.pred.lower().evaluate(st.table)
-        keep = mask.data.astype(bool)
-        if mask.validity is not None:
-            keep = keep & mask.validity
-        return st.replace(present=st.present & keep)
+        # one launch: the predicate, its validity and ``present`` in one program
+        return st.replace(present=self.program(st.table, st.num_rows, st.present))
 
 
 class _MeshProjectExec(_ProjectExec):
     sharded = True
 
-    def __init__(self, node, schema, child):
-        super().__init__(node, schema, child)
+    def __init__(self, node, schema, child, sharding):
+        super().__init__(node, schema, child, sharding=sharding)
         kept = {name for name, e in node.exprs if is_col(e) == name}
         self.part = child.part if set(child.part) <= kept else ()
 
     def _run(self, ctx):
         st = self.inputs[0].run(ctx)
-        cols = [_eval_expr(e, st.table, self.schema[name]) for name, e in self.exprs]
+        cols = self.program(st.table, st.num_rows)
         return st.replace(table=Table(cols, [name for name, _ in self.exprs]), part=self.part)
 
 
@@ -434,8 +571,8 @@ class _MeshAggExec(_Exec):
         st = groupby_sharded(self.inputs[0].run(ctx), self.keys,
                              [(a.source, a.how, a.name) for a in self.aggs])
         nk = len(self.keys)
-        cols = list(st.table.columns[:nk]) + [
-            _normalize_agg_column(c, a.how) for c, a in zip(st.table.columns[nk:], self.aggs)]
+        cols = list(st.table.columns[:nk]) + _normalize_agg_columns(
+            list(st.table.columns[nk:]), [a.how for a in self.aggs])
         return st.replace(table=Table(cols, list(st.names)))
 
 
@@ -525,16 +662,10 @@ class _AggExec(_Exec):
         # keys; rebind positionally to the AggSpec names and normalize
         # onto the fused materialization contract
         nk = keys_tbl.num_columns
-        out_cols: List[Column] = []
-        out_names: List[str] = []
-        if self.keys:
-            for i, k in enumerate(self.keys):
-                out_cols.append(agg.column(i))
-                out_names.append(k)
-        for j, (_, how, name) in enumerate(spec):
-            out_cols.append(_normalize_agg_column(agg.column(nk + j), how))
-            out_names.append(name)
-        return Table(out_cols, out_names)
+        out_cols: List[Column] = [agg.column(i) for i in range(len(self.keys))]
+        out_cols += _normalize_agg_columns([agg.column(nk + j) for j in range(len(spec))],
+                                           [how for _, how, _ in spec])
+        return Table(out_cols, list(self.keys) + [name for _, _, name in spec])
 
 
 class _FusedAggExec(_Exec):
@@ -939,6 +1070,12 @@ class _Lowerer:
             self._unique[(node.table, key)] = bool(c.dtype.is_integral and len(np.unique(vals)) == len(vals))
         return self._unique[(node.table, key)]
 
+    def _row_sharding(self):
+        """How the arrays of a stage over the mesh lie: rows over the binding's axis."""
+        from ..parallel.mesh import row_sharding
+
+        return row_sharding(self.mesh.mesh, self.mesh.axis)
+
     def _lower_mesh(self, node: Node, schema: Schema) -> Optional[_Exec]:
         """The stage over the mesh, where ``node`` reads sharded rows and
         the sharded layer can run it; None sends it down the local path
@@ -956,9 +1093,9 @@ class _Lowerer:
         if isinstance(node, Filter):
             rows = (self.est.filter_rows(child.est_rows, node.predicate)
                     if self.est is not None else None)
-            return _MeshFilterExec(node, schema, child, est_rows=rows)
+            return _MeshFilterExec(node, schema, child, self._row_sharding(), est_rows=rows)
         if isinstance(node, Project):
-            return _MeshProjectExec(node, schema, child)
+            return _MeshProjectExec(node, schema, child, self._row_sharding())
         if isinstance(node, Exchange):
             if node.world != self.mesh.world:
                 raise PlanError(f"exchange stage placed for world {node.world} compiled for a "

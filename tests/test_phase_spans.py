@@ -148,8 +148,52 @@ def test_plan_stage_spans_say_their_rows_out(q1_spans):
     assert _one(q1_spans, "plan.scan")["annotations"] == {"rows_out": N_ROWS}
     kept = _one(q1_spans, "plan.filter")["annotations"]["rows_out"]
     assert 0 < kept < N_ROWS
-    assert _one(q1_spans, "plan.project")["annotations"] == {"rows_out": kept}
-    assert _one(q1_spans, "plan.aggregate")["annotations"] == {"rows_out": 6}
+    assert _one(q1_spans, "plan.project")["annotations"]["rows_out"] == kept
+    assert _one(q1_spans, "plan.aggregate")["annotations"]["rows_out"] == 6
+
+
+@pytest.mark.parametrize("stage,jit,exprs", [
+    ("plan.filter", True, 1),      # the predicate, its validity folded in: one program
+    ("plan.project", True, 1),     # disc_price; the three references are handed on
+    ("plan.aggregate", False, 0),  # FLOAT64 sums and a count: nothing to normalise, no launch
+])
+def test_stage_spans_say_what_went_into_the_stages_one_program(q1_spans, stage, jit, exprs):
+    """ISSUE 33: a Filter's or a Project's expressions, and an aggregate's
+    float64 normalisation, are ONE jitted program a stage."""
+    notes = _one(q1_spans, stage)["annotations"]
+    assert notes["jit"] is jit and notes["exprs"] == exprs
+    assert set(notes) == {"rows_out", "jit", "exprs"}
+    assert "jit" not in _one(q1_spans, "plan.scan")["annotations"]
+
+
+@pytest.mark.parametrize("case,jitted,eager", [
+    ("computed_project", 1, 0), ("references_only", 0, 0), ("like_filter", 0, 1),
+    ("like_and_integer_filter", 0, 1), ("integer_filter", 1, 0), ("normalised_min_max", 1, 0)])
+def test_expr_counters_move_as_the_stages_run(case, jitted, eager):
+    from spark_rapids_jni_tpu import runtime
+
+    assert not tracing.is_enabled()  # registry-direct: counted with tracing off
+    t = Table([Column.from_numpy(np.arange(12, dtype=np.int32) % 4, dt.INT32),
+               Column.from_numpy(np.arange(12, dtype=np.float64) / 4.0, dt.FLOAT64),
+               Column.from_pylist(["pri", "able", "prime"] * 4, dt.STRING)], ["k", "x", "s"])
+    scan = P.Scan("t")
+    plan = {
+        "computed_project": P.Project(scan, (("s", P.pcol("s")), ("y", P.pcol("x") * P.plit(2.0)), ("z", P.pcol("k") + P.plit(1)))),
+        "references_only": P.Project(scan, (("s", P.pcol("s")), ("x", P.pcol("x")))),
+        "like_filter": P.Filter(scan, P.plike(P.pcol("s"), "pri%")),
+        "like_and_integer_filter": P.Filter(scan, P.plike(P.pcol("s"), "pri%") & (P.pcol("k") < P.plit(np.int32(3)))),
+        "integer_filter": P.Filter(scan, P.pcol("k") != P.plit(np.int32(3))),
+        "normalised_min_max": P.Aggregate(P.Aggregate(scan, keys=("k", "x"), aggs=()), keys=("x",), aggs=(
+            P.AggSpec("k", "min", "lo"), P.AggSpec("k", "max", "hi"), P.AggSpec("k", "count", "n"))),
+    }[case]
+    cp = P.compile_ir(plan, {"t": t}, name=case)
+    was = _counter("plan.expr.jitted"), _counter("plan.expr.eager")
+    cp()
+    assert (_counter("plan.expr.jitted") - was[0], _counter("plan.expr.eager") - was[1]) == (jitted, eager)
+    cp()  # one a stage run, every run
+    assert (_counter("plan.expr.jitted") - was[0], _counter("plan.expr.eager") - was[1]) == (2 * jitted, 2 * eager)
+    counters = runtime.stats_report()["metrics"]["counters"]
+    assert counters.get("plan.expr.jitted", 0) >= 2 * jitted and counters.get("plan.expr.eager", 0) >= 2 * eager
 
 
 def test_groupby_phase_spans_are_children_of_the_operator(q1_spans):

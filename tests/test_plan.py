@@ -459,3 +459,259 @@ class TestIntegration:
             out = h.result(timeout_s=60)
             assert h._memory_bytes and h._memory_bytes > 0
         assert int(np.asarray(out.column("cnt").data)[0]) == tabs["fact"].num_rows
+
+
+# ---------------------------------------------------------------------------
+# the stage program (ISSUE 33): a Filter's or a Project's expressions as ONE
+# jitted device program, lane for lane what the eager evaluator gives
+# ---------------------------------------------------------------------------
+
+
+def _expr_kinds():
+    f, g, i, j = P.pcol("f"), P.pcol("g"), P.pcol("i"), P.pcol("j")
+    one = P.plit(1.0)
+    disc_price = f * (one - g)
+    kinds = {
+        "add_f64": f + g, "sub_f64": f - g, "mul_f64": f * g, "div_f64": f / g, "mod_f64": f % g,
+        "add_mixed": f + j, "sub_mixed": j - f, "mul_mixed": f * i, "div_mixed": j / f, "mod_mixed": f % i,
+        "add_int": i + j, "mul_int_lit": i * P.plit(3), "mod_int": j % P.plit(7), "div_int": j / i,
+        "eq": f == g, "ne": f != g, "lt": f < g, "le": f <= g, "gt": f > g, "ge": f >= g,
+        "le_int_lit": i <= P.plit(np.int32(4)), "ne_int": i != j,
+        "and": (f < g) & (i > P.plit(2)), "or": (f < g) | (i > P.plit(2)), "not": ~(f < g),
+        "is_null": f.is_null(), "is_not_null": i.is_not_null(),
+        "case_when": P.pwhen(f > g, f, g), "case_null_branch": P.pwhen(i > P.plit(3), P.plit(None, dt.FLOAT64), f),
+        "cast_int_to_f64": i.cast(dt.FLOAT64), "cast_f64_to_int": f.cast(dt.INT64), "cast_narrow": j.cast(dt.INT32),
+        "literal_float": P.plit(2.5), "literal_int": P.plit(7), "literal_bool": P.plit(True),
+        "null_f64": P.plit(None, dt.FLOAT64), "null_int": P.plit(None, dt.INT32),
+        "div_by_zero_lit": f / P.plit(0.0), "div_by_zero_col": j / (i - i),
+        "q1_disc_price": disc_price, "q1_charge": disc_price * (one + f),
+        "part_hash": P.ppart(("i", "j"), 4) == P.plit(1),
+        # a literal beside a FLOAT64: a constant in the traced chain, which no rewrite may fold into the pair
+        "lit_minus_f64": P.plit(1.0) - g, "f64_minus_lit": f - P.plit(0.1), "f64_plus_lit": f + P.plit(2.5),
+        "lit_plus_f64": P.plit(1e-3) + f, "f64_times_lit": f * P.plit(1.07), "f64_div_lit": f / P.plit(3.0),
+        "lit_div_f64": P.plit(1.0) / g, "f64_mod_lit": f % P.plit(7.0), "f64_lt_lit": f < P.plit(0.05),
+        "f64_plus_int_lit": f + P.plit(3), "case_lit_branch": P.pwhen(f > g, f - P.plit(1.0), P.plit(0.0)),
+    }
+    return kinds
+
+
+_KINDS = _expr_kinds()
+
+
+def _expr_table(rng, n, nulls):
+    def valid():
+        return jnp.asarray(rng.random(n) > 0.2) if nulls else None
+
+    fv = rng.uniform(-50, 50, n).round(2)
+    fv[:4] = [0.0, -0.0, 1e300, 1e-300]
+    gv = rng.uniform(0, 1, n).round(2)
+    gv[4:8] = [0.0, 1.0, np.inf, np.nan]
+    cols = [Column(dt.FLOAT64, data=jnp.asarray(fv.view(np.uint64)), validity=valid()),
+            Column(dt.FLOAT64, data=jnp.asarray(gv.view(np.uint64)), validity=valid()),
+            Column(dt.INT32, data=jnp.asarray(rng.integers(-3, 9, n).astype(np.int32)), validity=valid()),
+            Column(dt.INT64, data=jnp.asarray(rng.integers(-10**12, 10**12, n)), validity=valid())]
+    return Table(cols, ["f", "g", "i", "j"])
+
+
+def _same_column(got: Column, want: Column):
+    assert got.dtype.id == want.dtype.id
+    assert got.data.dtype == want.data.dtype and got.data.shape == want.data.shape
+    np.testing.assert_array_equal(np.asarray(got.data), np.asarray(want.data))
+    assert (got.validity is None) == (want.validity is None)
+    if want.validity is not None:
+        np.testing.assert_array_equal(np.asarray(got.validity), np.asarray(want.validity))
+
+
+@pytest.fixture(scope="module", params=[(nulls, path) for nulls in (False, True) for path in ("f64", "dd")],
+                ids=lambda p: f"{'nulls' if p[0] else 'no_nulls'}-{p[1]}")
+def stage_outputs(request):
+    """Every expression kind in ONE Project stage (one program), and the
+    eager evaluator's column of each: on the CPU's float64 datapath and on
+    the double-float32 one a chip without float64 takes."""
+    from spark_rapids_jni_tpu.ops import bitutils
+    from spark_rapids_jni_tpu.plan import compiler
+
+    nulls, path = request.param
+    mp = pytest.MonkeyPatch()
+    if path == "dd":
+        mp.setattr(bitutils, "backend_has_f64", lambda: False)
+    try:
+        t = _expr_table(np.random.default_rng(33), 257, nulls)
+        cp = P.compile_ir(P.Project(P.Scan("t"), tuple(_KINDS.items())), {"t": t}, name="kinds")
+        jitted = cp()
+        schema = {n: c.dtype for n, c in zip(t.names, t.columns)}
+        eager = {}
+        for name, e in _KINDS.items():
+            low = None if pex.is_null_lit(e) else e.lower()
+            eager[name] = compiler._materialize(low, t, e.dtype(schema), t.num_rows)
+        [stage] = [s for s in cp.stages if type(s).__name__ == "_ProjectExec" and s.program.trees]
+        yield jitted, eager, stage, (path, t)
+    finally:
+        mp.undo()
+
+
+# The dd branch is FORCED onto the CPU here (a chip without float64 takes it;
+# the CPU's own FLOAT64 datapath is real): its chains are what a compiler
+# may rewrite when it sees them whole, where the eager evaluator shows it one
+# ``jnp`` call at a time. Two such rewrites were met and fenced (ISSUE 33):
+# XLA's algebraic simplifier folds a LITERAL through 2Sum, ``(c + b) - c`` to
+# ``b``, and turns ``x / c`` into ``x * (1 / c)`` (``ops/expressions.py::_tie``);
+# XLA:CPU contracts a product into the add behind it (``f64acc._rounded``).
+# The chip itself is held lane for lane by ``benchmarks/calls/pr33_bits.py``.
+
+
+class TestStageProgram:
+    @pytest.mark.parametrize("kind", list(_KINDS))
+    def test_bit_for_bit_the_eager_evaluator(self, stage_outputs, kind):
+        jitted, eager, stage, _ = stage_outputs
+        assert len(stage.program.trees) == len(_KINDS) and not stage.program.n_eager
+        _same_column(jitted.column(kind), eager[kind])
+
+    @pytest.mark.parametrize("kind", ["lit_minus_f64", "f64_minus_lit", "f64_plus_lit", "f64_times_lit", "q1_disc_price"])
+    def test_a_literal_in_the_program_keeps_the_pairs_48_bits(self, stage_outputs, kind):
+        """What the lanes above cannot say: that BOTH sides are right. A
+        literal folded through 2Sum by the compiler left float32's 24 bits
+        (``1 - 0.01`` read 0.99000001 under jit)."""
+        jitted, _, _, (path, t) = stage_outputs
+        f, g = (np.asarray(t.column(c).data).view(np.float64) for c in ("f", "g"))
+        with np.errstate(all="ignore"):
+            want = {"lit_minus_f64": 1.0 - g, "f64_minus_lit": f - 0.1, "f64_plus_lit": f + 2.5,
+                    "f64_times_lit": f * 1.07, "q1_disc_price": f * (1.0 - g)}[kind]
+        ok = np.isfinite(want) & (np.abs(f) < 1e30) & (np.abs(f) > 1e-30)  # a pair of float32s holds no 1e300
+        got = np.asarray(jitted.column(kind).data).view(np.float64)
+        tol = 0.0 if path == "f64" and kind != "q1_disc_price" else 2.0 ** -44
+        np.testing.assert_allclose(got[ok], want[ok], rtol=max(tol, 1e-15), atol=1e-13)
+
+    @pytest.mark.parametrize("nulls", [False, True])
+    @pytest.mark.parametrize("pred", ["le_int_lit", "lt", "and", "or", "is_not_null", "part_hash"])
+    def test_filter_keeps_the_rows_the_eager_mask_keeps(self, rng, pred, nulls):
+        from spark_rapids_jni_tpu.ops import copying
+
+        t = _expr_table(rng, 300, nulls)
+        before = _counters()
+        out = P.compile_ir(P.Filter(P.Scan("t"), _KINDS[pred]), {"t": t}, name="flt")()
+        assert _moved(before) == {"jitted": 1, "eager": 0}
+        want = copying.apply_boolean_mask(t, _KINDS[pred].lower().evaluate(t))
+        assert 0 < want.num_rows < t.num_rows or (pred == "is_not_null" and not nulls)
+        for name in t.names:
+            _same_column(out.column(name), want.column(name))
+
+    def test_passthroughs_are_the_same_arrays_beside_one_computed_column(self, rng):
+        t = _expr_table(rng, 64, True)
+        s = Column.from_pylist([f"s{k}" for k in range(64)], dt.STRING)
+        t = Table(list(t.columns) + [s], t.names + ["s"])
+        ir = P.Project(P.Scan("t"), (("s", P.pcol("s")), ("f", P.pcol("f")), ("x", P.pcol("f") * P.pcol("g"))))
+        before = _counters()
+        cp = P.compile_ir(ir, {"t": t}, name="pass")
+        out = cp()
+        assert _moved(before) == {"jitted": 1, "eager": 0}
+        assert out.column("s").chars is t.column("s").chars and out.column("s").offsets is t.column("s").offsets
+        assert out.column("f").data is t.column("f").data and out.column("f").validity is t.column("f").validity
+        [stage] = [st for st in cp.stages if type(st).__name__ == "_ProjectExec" and st.program.trees]
+        assert stage.program.refs == ("f", "g") and len(stage.program.trees) == 1
+        # a Project of nothing but references launches nothing and counts nothing
+        before = _counters()
+        P.compile_ir(P.Project(P.Scan("t"), (("s", P.pcol("s")), ("g", P.pcol("g")))), {"t": t}, name="refs")()
+        assert _moved(before) == {"jitted": 0, "eager": 0}
+
+    @pytest.mark.parametrize("pred", ["like", "like_and_fixed_width"])
+    def test_a_string_predicate_stays_eager_and_is_counted_so(self, pred):
+        names = ["alpha", "beta", "alps", None, "gamma", "alto"] * 5
+        t = Table([Column.from_pylist(names, dt.STRING), icol(np.arange(30))], ["s", "k"])
+        e = P.plike(P.pcol("s"), "al%")
+        if pred == "like_and_fixed_width":
+            e = e & (P.pcol("k") < P.plit(np.int32(20)))
+        before = _counters()
+        out = P.compile_ir(P.Filter(P.Scan("t"), e), {"t": t}, name="like")()
+        assert _moved(before) == {"jitted": 0, "eager": 1}
+        limit = 30 if pred == "like" else 20
+        assert np.asarray(out.column("k").data).tolist() == [
+            k for k in range(limit) if names[k] is not None and names[k].startswith("al")]
+
+    def test_part_hash_over_a_string_key_stays_eager(self):
+        t = Table([Column.from_pylist([f"k{k % 7}" for k in range(40)], dt.STRING), icol(np.arange(40))], ["s", "k"])
+        ir = P.Project(P.Scan("t"), (("k", P.pcol("k")), ("p", P.ppart(("s",), 4))))
+        before = _counters()
+        out = P.compile_ir(ir, {"t": t}, name="strhash")()
+        assert _moved(before) == {"jitted": 0, "eager": 1}
+        from spark_rapids_jni_tpu.ops.hashing import hash_partition_map
+        np.testing.assert_array_equal(np.asarray(out.column("p").data),
+                                      np.asarray(hash_partition_map([t.column("s")], 4)))
+
+    def test_a_second_run_over_other_rows_of_the_same_shapes_compiles_nothing(self, rng):
+        import jax
+        from spark_rapids_jni_tpu.utils import metrics
+
+        tabs = {"t": _expr_table(rng, 211, True)}  # a row count no other test of this file has
+        ir = P.Project(P.Scan("t"), (("f", P.pcol("f")), ("x", P.pcol("f") * (P.plit(1.0) - P.pcol("g"))),
+                                     ("y", P.pcol("i") + P.pcol("j"))))
+        cp = P.compile_ir(ir, tabs, name="twice")
+        first = cp()
+        jax.block_until_ready([c.data for c in first.columns])
+        before = metrics.registry().value("xla.backend_compiles")
+        tabs["t"] = _expr_table(rng, 211, True)
+        second = cp()
+        jax.block_until_ready([c.data for c in second.columns])
+        assert metrics.registry().value("xla.backend_compiles") == before
+        assert not np.array_equal(np.asarray(first.column("x").data), np.asarray(second.column("x").data))
+        [stage] = [s for s in cp.stages if type(s).__name__ == "_ProjectExec" and s.program.trees]
+        assert stage.program._program._cache_size() == 1
+
+    def test_two_threads_running_one_compiled_plan_agree(self, rng):
+        import threading
+
+        t = _expr_table(rng, 500, True)
+        ir = P.Project(P.Filter(P.Scan("t"), P.pcol("i") <= P.plit(np.int32(4))),
+                       (("x", P.pcol("f") * (P.plit(1.0) - P.pcol("g"))), ("y", P.pcol("j") % P.plit(7))))
+        cp = P.compile_ir(ir, {"t": t}, name="threads")
+        want = cp()
+        results, errors = [], []
+
+        def work():
+            try:
+                for _ in range(4):
+                    results.append(cp())
+            except Exception as e:  # noqa: BLE001 - the assertion below reports it
+                errors.append(e)
+
+        threads = [threading.Thread(target=work) for _ in range(2)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+            assert not th.is_alive()
+        assert not errors and len(results) == 8
+        for got in results:
+            for name in want.names:
+                _same_column(got.column(name), want.column(name))
+
+    @pytest.mark.parametrize("source,how", [("i", "min"), ("j", "max"), ("i", "sum")])
+    def test_an_aggregates_float64_normalisation_is_the_eager_conversion(self, rng, source, how):
+        from spark_rapids_jni_tpu.ops.f64acc import i64_to_f64bits
+
+        t = _expr_table(rng, 300, True)
+        keys = Table([icol(rng.integers(0, 9, 300))], ["k"])
+        t = Table(list(keys.columns) + list(t.columns), ["k"] + t.names)
+        dedup = P.Aggregate(P.Scan("t"), keys=("k", source), aggs=())  # keeps the aggregate on the op tier
+        ir = P.Sort(P.Aggregate(dedup, keys=("k",), aggs=(P.AggSpec(source, how, "a"),
+                                                         P.AggSpec(source, "count", "n"))), (("k", True),))
+        before = _counters()
+        out = P.compile_ir(ir, {"t": t}, name="norm1")()
+        assert _moved(before)["jitted"] == 1  # one program for the stage; the count needs none
+        df = pd.DataFrame({"k": np.asarray(t.column("k").data), "v": np.asarray(t.column(source).data)})
+        df = df[np.asarray(t.column(source).validity)].drop_duplicates()
+        want = getattr(df.groupby("k").v, how)().sort_index().to_numpy().astype(np.int64)
+        assert out.column("a").dtype == dt.FLOAT64 and out.column("n").dtype == dt.INT64
+        np.testing.assert_array_equal(np.asarray(out.column("a").data),
+                                      np.asarray(i64_to_f64bits(jnp.asarray(want))))
+
+
+def _counters():
+    from spark_rapids_jni_tpu.utils import metrics
+
+    reg = metrics.registry()
+    return {k: reg.value(f"plan.expr.{k}") for k in ("jitted", "eager")}
+
+
+def _moved(before):
+    return {k: v - before[k] for k, v in _counters().items()}

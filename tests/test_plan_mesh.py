@@ -479,3 +479,96 @@ def test_exchange_spans_and_counters_appear_and_vanish_with_tracing(tmp_path):
     # tracing off: no span; the counters (registry-direct, like the plan tier's) still count
     assert _spans_of_a_run(tmp_path, False) == []
     assert reg.value("exchange.programs") == after["programs"] + 2
+
+
+# ---------------------------------------------------------------------------
+# the stage programs over a mesh (ISSUE 33): one jitted program a Filter, a
+# Project and an aggregate's float64 normalisation, over row-sharded arrays
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def ws_wh_on_a_mesh():
+    """q95's ``ws_wh`` as the cell plans it, on four virtual devices: the
+    shard-local group-by's ``wh_lo`` / ``wh_hi`` as they come (integers),
+    what the aggregate stage's one program makes of them, and the mesh
+    Filter's ``present``."""
+    from spark_rapids_jni_tpu.plan import compiler
+
+    host = WEB.host_tables(CONFIG, 33, 30_000)
+    wh, valid = host["web_sales"]["ws_warehouse_sk"]
+    wh = np.where(host["web_sales"]["ws_order_number"] % 3 == 0, 4, wh).astype(np.int32)  # a third of the orders: one warehouse
+    fact = Table([Column.from_numpy(host["web_sales"]["ws_order_number"], dt.INT64),
+                  Column.from_numpy(wh, dt.INT32, validity=valid)], ["ws_order_number", "ws_warehouse_sk"])
+    agg = pn.Aggregate(pn.Scan("web_sales"), keys=("ws_order_number",),
+                       aggs=(pn.AggSpec("ws_warehouse_sk", "min", "wh_lo"), pn.AggSpec("ws_warehouse_sk", "max", "wh_hi")))
+    plan = pn.Project(pn.Filter(agg, P.pcol("wh_lo") != P.pcol("wh_hi")), (("ws_order_number", P.pcol("ws_order_number")),))
+    cp = P.compile_ir(P.insert_exchanges(plan, 4, sharded=("web_sales",)), {"web_sales": fact}, name="ws_wh",
+                      mesh=P.MeshBinding(_mesh(4), ("web_sales",)))
+    [agg_stage] = [s for s in cp.stages if type(s).__name__ == "_MeshAggExec"]
+    [flt] = [s for s in cp.stages if type(s).__name__ == "_MeshFilterExec"]
+    ctx = compiler._RunContext(cp._tables)
+    before = {k: metrics.registry().value(f"plan.expr.{k}") for k in ("jitted", "eager")}
+    raw = table_ops.groupby_sharded(agg_stage.inputs[0].run(ctx), agg_stage.keys,
+                                    [(a.source, a.how, a.name) for a in agg_stage.aggs])
+    st = agg_stage.run(ctx)
+    out = flt.run(ctx)
+    moved = {k: metrics.registry().value(f"plan.expr.{k}") - v for k, v in before.items()}
+    eager = {n: compiler._to_float64(raw.column(n)) for n in ("wh_lo", "wh_hi")}
+    mask = (P.pcol("wh_lo") != P.pcol("wh_hi")).lower().evaluate(Table(list(eager.values()), list(eager)))
+    eager["present"] = Column(dt.BOOL8, data=compiler._keep(mask, st.present))
+    jitted = {"wh_lo": st.column("wh_lo"), "wh_hi": st.column("wh_hi"), "present": Column(dt.BOOL8, data=out.present)}
+    laid = {"wh_lo": raw.column("wh_lo").data.sharding, "wh_hi": raw.column("wh_hi").data.sharding,
+            "present": st.present.sharding}
+    return jitted, eager, laid, moved, cp
+
+
+@pytest.mark.parametrize("what", ["wh_lo", "wh_hi", "present"])
+def test_a_mesh_stages_program_gives_the_eager_lanes_laid_out_as_its_inputs(ws_wh_on_a_mesh, what):
+    jitted, eager, laid, moved, _ = ws_wh_on_a_mesh
+    got, want = jitted[what], eager[what]
+    assert got.dtype.id == want.dtype.id and got.data.dtype == want.data.dtype
+    np.testing.assert_array_equal(np.asarray(got.data), np.asarray(want.data))
+    assert (got.validity is None) == (want.validity is None)
+    if want.validity is not None:
+        np.testing.assert_array_equal(np.asarray(got.validity), np.asarray(want.validity))
+        assert got.validity.sharding.is_equivalent_to(laid[what], 1)
+    assert got.data.sharding.is_equivalent_to(laid[what], 1) and len(got.data.sharding.device_set) == 4
+    if what == "present":
+        kept = int(np.asarray(got.data).sum())
+        assert 0 < kept < int(np.asarray(eager["wh_lo"].validity).sum())  # some orders name one warehouse only
+    # one program for both columns of the aggregate stage, one for the Filter; nothing eager
+    assert moved == {"jitted": 2, "eager": 0}
+
+
+@pytest.mark.parametrize("kind", ["literal", "null_literal", "computed", "cast", "passthrough"])
+def test_a_mesh_projects_outputs_lie_as_its_inputs_do(kind):
+    from spark_rapids_jni_tpu.plan import compiler
+
+    n = 1000
+    rng = np.random.default_rng(9)
+    fact = Table([Column.from_numpy(np.arange(n, dtype=np.int64), dt.INT64),
+                  Column.from_numpy(rng.uniform(0, 9, n).round(2), dt.FLOAT64, validity=rng.random(n) > 0.1)], ["k", "x"])
+    exprs = {"literal": P.plit(2.5), "null_literal": P.plit(None, dt.FLOAT64), "computed": P.pcol("x") * (P.plit(1.0) - P.pcol("x")),
+             "cast": P.pcol("k").cast(dt.FLOAT64), "passthrough": P.pcol("x")}
+    plan = pn.Project(pn.Scan("fact"), (("k", P.pcol("k")), ("out", exprs[kind])))
+    cp = P.compile_ir(plan, {"fact": fact}, name="meshproj", mesh=P.MeshBinding(_mesh(4), ("fact",)))
+    [proj] = [s for s in cp.stages if type(s).__name__ == "_MeshProjectExec"]
+    ctx = compiler._RunContext(cp._tables)
+    st_in = proj.inputs[0].run(ctx)
+    st = proj.run(ctx)
+    got = st.column("out")
+    want = compiler._materialize(None if kind == "null_literal" else exprs[kind].lower(), st_in.table,
+                                 dt.FLOAT64, st_in.num_rows)
+    np.testing.assert_array_equal(np.asarray(got.data), np.asarray(want.data))
+    assert (got.validity is None) == (want.validity is None)
+    if want.validity is not None:
+        np.testing.assert_array_equal(np.asarray(got.validity), np.asarray(want.validity))
+    rows = st_in.present.sharding
+    assert got.data.sharding.is_equivalent_to(rows, 1) and len(got.data.sharding.device_set) == 4
+    assert st.column("k").data is st_in.column("k").data  # a reference is handed on as it is
+    if kind == "passthrough":
+        assert got.data is st_in.column("x").data and not proj.program.trees
+    # and the plan answers with the rows it was given
+    out = cp()
+    assert np.asarray(out.column("k").data).tolist() == list(range(n))
