@@ -5,8 +5,12 @@ Reproduces the reference's benchmark axes on whatever device jax sees:
 - ``row_conversion_fixed``: 212 columns cycled over 9 int types ×
   {1M, 4M} rows, both directions (reference
   benchmarks/row_conversion.cpp:27-67, 140-143),
-- ``row_conversion_mixed``: 155 columns ± STRING (reference :69-138;
-  string case >1M rows skipped there for memory — same guard here),
+- ``row_conversion_mixed``: 155 columns ± STRING, the reference's column
+  count and its >1M-row guard for strings but NOT its table: INT32,
+  FLOAT64, INT64, INT16 cycled, a STRING of 1-32 bytes at every tenth
+  column, in process. The reference's own type list (its nine fixed-width
+  types and STRING) through the sidecar is the benchmark cell
+  ``rowconv-155x1m-strings.to-rows`` (bench/data/rowconv_var_width.py),
 - ``cast_string``: string->int and string->decimal thread-per-row
   kernels (reference cast kernels, cast_string.cu:654-655),
 - ``groupby``: the hash-agg tier on the 1M-row stepping stone.
@@ -262,6 +266,10 @@ def bench_row_conversion_fixed(rows: int, reps: int, cols: int = 212) -> None:
 
 
 def bench_row_conversion_mixed(rows: int, reps: int, cols: int = 155, strings: bool = True) -> None:
+    """155 columns over a type list of this harness's own (four fixed-width
+    types, a STRING at every tenth column, lengths 1-32): not the
+    reference's table. Every rate the comments of ops/ragged_bytes.py and
+    ops/row_conversion.py quoted before PR 34 was read here."""
     from spark_rapids_jni_tpu.ops import row_conversion as rc
 
     base = [dt.INT32, dt.FLOAT64, dt.INT64, dt.INT16]
@@ -280,8 +288,7 @@ def bench_row_conversion_mixed(rows: int, reps: int, cols: int = 155, strings: b
     name = "row_conversion_mixed" + ("_strings" if strings else "")
     _report(name + "_to_rows", rows, cols, secs, nbytes)
 
-    # decode direction (the reference benches both axes,
-    # row_conversion.cpp:140-143). Known-slow: the ragged char
+    # decode direction (the reference benches both axes). Known-slow: the ragged char
     # extraction is element-granular u8 gathering — recorded honestly;
     # the Pallas DMA compaction is the planned fix.
     row_cols = rc.convert_to_rows(table)

@@ -1,13 +1,24 @@
 """Ragged byte movement as REGULAR array ops — the TPU answer to the
 reference's warp-per-row memcpy kernels (row_conversion.cu:827-874).
 
-XLA:TPU's per-ELEMENT irregular u8 gather/scatter runs at ~0.005 GB/s
-(round-2 memo; re-verified), which made the mixed/string transcode axis
-pathological (71.6 s at 155-col x 1M). The same hardware moves
-ROW-granular gathers fast: measured on v5e, ``jnp.take(pool2d, idx,
-axis=0)`` with monotonic indices reaches ~29 GB/s at 128-byte rows and
-~109 GB/s for the windowed two-tile form — ~4 orders of magnitude over
-element addressing. So every ragged access here is decomposed into
+XLA:TPU's per-ELEMENT irregular u8 gather/scatter is the slow access
+class on this hardware, and ROW-granular gathers (``jnp.take(pool2d,
+idx, axis=0)`` with monotonic indices) the fast one. The rates this memo
+used to quote for them (~0.005 GB/s; 71.6 s for the mixed transcode at
+155-col x 1M; ~29 GB/s at 128-byte rows; ~109 GB/s for the windowed
+two-tile form) were read BEFORE the benchmark existed, in process, on
+benchmarks/microbench.py's table (four fixed-width types and a STRING of
+1-32 bytes at every tenth column), not on the reference's: read them as
+claims. What PR 34 measured on the chip, through the sidecar, on the
+reference's own table (155 columns over its ten-type list, 15 STRING of
+0-32 bytes, 1 Mi rows; 0.85 GB in, 1.17 GB of rows out): the whole
+encode is ONE program of 637-639 ms of device time (3.2 GB/s of the
+request's bytes, 0.39% of the HBM roofline): ``assemble_rows``' chunked
+loop 161 ms (its three two-tile gathers 121 ms), the two 64-bit
+``cummax`` scans over its 4,587,520 tile indices 147 ms, the
+``_vacc_kernel`` 57 ms, the fifteen ``_rotl_take_kernel`` 55 ms
+(PERF.md section 5 has the table). So every ragged access here is
+decomposed into
 
 1. an axis-0 gather of fixed-width OVERLAPPING tiles (stride s, width
    2s: any s-aligned window of length <= s+1 lands in ONE tile), and
@@ -764,8 +775,9 @@ def assemble_rows(
     Dst-centric at tile granularity G = pow2 <= min_row_size (so a dst
     tile straddles at most 2 rows): tile t takes G bytes at in-row
     offset p from row r (two adjacent-tile u32 gathers from the free
-    reshape view — the windowed form measured ~109 GB/s — concatenated
-    in VMEM) and bytes past row r's end come from row r+1's head (third
+    reshape view, concatenated in VMEM; at the reference's 155-col x 1M
+    axis the three gathers of a request's 4.6 M tiles take 121 ms on
+    the chip, PR 34) and bytes past row r's end come from row r+1's head (third
     gather + zero-filling right shift). All gather indices are
     monotonic. Everything stays in u32 lanes: u8<->u32 bitcasts of 2-D
     arrays are real tiled-layout relayouts, paid once at the final 1-D

@@ -44,6 +44,7 @@ from jax import lax
 from ..columnar import Column, Table
 from ..columnar import dtype as dt
 from ..columnar.dtype import DType, TypeId
+from ..utils import metrics, tracing
 from ..utils.dispatch import op_boundary
 from . import bitutils
 
@@ -347,10 +348,6 @@ def _to_rows_fixed(layout: RowLayout, cols: Sequence[Column], n: int) -> jnp.nda
     return u32_rows_to_u8_flat(f32)
 
 
-def _var_maxlens(layout: RowLayout, cols: Sequence[Column]) -> Tuple[int, ...]:
-    return tuple(cols[i].max_char_len for i in layout.variable_cols)
-
-
 # Padded-row memory amplification cap for the fast mixed path: the
 # padded RP matrix costs N * (fixed_end + maxvar) bytes, so one huge
 # outlier string must not blow device memory (fall back to the scatter
@@ -393,9 +390,12 @@ def _jit_var_section(
     ONE program: per-column padded extraction (windowed tile gather +
     Pallas rotate), then one Pallas accumulation pass whose shift
     ladders live in VMEM — as plain XLA the ladders materialize
-    O(log(maxvar) * cols) full-width HLO temps at once (35 GB / OOM at
-    the 155-col x 1M axis, observed), and per-column dispatches cost a
-    host↔device round trip each.
+    O(log(maxvar) * cols) full-width HLO temps at once ("35 GB / OOM
+    at the 155-col x 1M axis": read before the benchmark, on
+    benchmarks/microbench.py's table, not re-measured), and per-column
+    dispatches cost a host↔device round trip each. At the reference's
+    own 155-col x 1M axis (PR 34, on the chip) the worker that runs
+    this inside the one fused program peaks at 3.65 GB.
 
     The region starts at byte 4*(fixed_end//4): when fixed_end is not
     lane-aligned, the trailing validity bytes (``tail_lane``) ride in
@@ -412,9 +412,11 @@ def _jit_var_section(
     # Serialize the per-column extractions ONLY under memory pressure:
     # each padded matrix is N * pow2(maxlen) bytes and the tile windows
     # another ~2x the char payload; when all K coexist a wide axis can
-    # tip over HBM (~4 GB observed at 155-col x 1M with large
-    # maxlens) — but forcing N sequential kernels costs real wall time,
-    # so small extractions stay concurrent.
+    # tip over HBM ("~4 GB at 155-col x 1M with large maxlens": read
+    # before the benchmark on another table) — but forcing N
+    # sequential kernels costs real wall time, so small extractions
+    # stay concurrent (the cell's fifteen 32-byte columns reckon to
+    # 0.98 GB here and are not serialized).
     n_rows = tail_lane.shape[0]
     est = sum(
         n_rows * max(_pow2_ceil(min(_round_up(maxlens[k], 4), maxvar)), 4)
@@ -446,10 +448,8 @@ def _jit_assemble(fixed32, var32, row_offsets, total_bytes: int, min_row: int):
     return assemble_rows((fixed32, var32), sizes, row_offsets, total_bytes, min_row)
 
 
-_FUSED_ENCODE_BROKEN = False
-
-
-def _encode_strings_impl(
+@partial(jax.jit, static_argnums=(0, 3, 4, 5))
+def _jit_encode_strings_fused(
     layout: RowLayout,
     cols: Tuple[Column, ...],
     row_offsets: jnp.ndarray,
@@ -457,10 +457,32 @@ def _encode_strings_impl(
     maxlens: Tuple[int, ...],
     maxvar: int,
 ) -> jnp.ndarray:
-    """Shared staging body for the mixed encode. Called DIRECTLY, each
-    stage function's own jit gives the staged pipeline (one dispatch
-    per stage); called under _jit_encode_strings_fused, the nested jits
-    inline into ONE program."""
+    """Mixed fixed+string table -> [total_bytes] u8 blob, the whole
+    encode as ONE program of regular ops (ops/ragged_bytes design memo);
+    the three stage jits inline:
+
+    1. fixed sections and slot values assemble ([N, fixed_end]),
+    2. each string column extracts to a padded [N, L_k] matrix with ONE
+       overlapping-tile gather + per-row rotate, and the variable
+       section accumulates by per-row byte shifts (strings are disjoint
+       per row, so sum == placement),
+    3. padded rows compact to the exact 8-aligned ragged blob with the
+       dst-centric two-source tile assembly (monotonic gathers).
+
+    The reference does this with a warp-per-row memcpy
+    (row_conversion.cu:827-874); on TPU the same movement is gathers of
+    fixed-width tiles + lane arithmetic. ``row_offsets`` are the [N+1]
+    int64 dst offsets, ``maxlens`` each STRING column's longest length,
+    ``maxvar`` the padded width of the variable section.
+
+    The one form there is, with no staged fallback behind it: run
+    stage by stage the same three stages materialize outputs this
+    program never allocates, and at the reference's axis (155 columns,
+    15 STRING of 0-32 bytes, 1 Mi rows, 1.17 GB of rows) that stops
+    with RESOURCE_EXHAUSTED on a 16 GB chip, where this program runs in
+    639 ms and its worker peaks at 3.65 GB (PR 34; 250 s to compile
+    cold). A compile or run-time failure here raises as any operator's
+    does."""
     var_cols = [cols[i] for i in layout.variable_cols]
     fixed32, var_starts, lens = _jit_fixed_and_slots(layout, tuple(cols))
     n = len(cols[0])
@@ -502,91 +524,6 @@ def _encode_strings_impl(
     )
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4, 5))
-def _jit_encode_strings_fused(
-    layout: RowLayout,
-    cols: Tuple[Column, ...],
-    row_offsets: jnp.ndarray,
-    total_bytes: int,
-    maxlens: Tuple[int, ...],
-    maxvar: int,
-) -> jnp.ndarray:
-    """The whole mixed encode as ONE program (nested stage jits inline)
-    — the staged pipeline minus three host↔device dispatch round
-    trips."""
-    return _encode_strings_impl(layout, cols, row_offsets, total_bytes, maxlens, maxvar)
-
-
-def _to_rows_strings_padded(
-    layout: RowLayout,
-    cols: Tuple[Column, ...],
-    row_offsets: jnp.ndarray,  # [N+1] int64 dst offsets (cumsum of sizes)
-    total_bytes: int,
-    maxlens: Tuple[int, ...],  # static per-string-col max byte length
-    maxvar: int,  # static padded width of the variable section
-) -> jnp.ndarray:
-    """Mixed fixed+string table -> [total_bytes] u8 blob, ALL regular
-    ops (ops/ragged_bytes design memo): replaces the element-granular
-    scatters that ran this axis at 0.016 GB/s.
-
-    1. fixed sections assemble as before ([N, fixed_end]),
-    2. each string column extracts to a padded [N, L_k] matrix with ONE
-       overlapping-tile gather + per-row rotate (~100 GB/s measured),
-    3. the variable section accumulates by per-row byte shifts (strings
-       are disjoint per row, so sum == placement),
-    4. padded rows compact to the exact 8-aligned ragged blob with the
-       dst-centric two-source tile assembly (monotonic gathers).
-
-    The reference does step 2-4 with a warp-per-row memcpy
-    (row_conversion.cu:827-874); on TPU the same movement is expressed
-    as gathers of fixed-width tiles + lane arithmetic. The fused
-    single-program form is tried first (dispatch count 2 instead of 5);
-    a compile/runtime failure — very wide axes have crashed the XLA:TPU
-    compiler on fully fused forms (round-3 observation) — demotes the
-    process to the staged pipeline, whose stage outputs are genuine
-    materialization points.
-    """
-    n = len(cols[0])
-    # ONE fused program for fixed+slots+var+assemble (3 fewer
-    # host↔device dispatch round trips); very wide axes have crashed
-    # the XLA:TPU compiler on the fully fused form before (round-3
-    # observation), so a compile failure falls back to the staged path
-    global _FUSED_ENCODE_BROKEN
-    if not _FUSED_ENCODE_BROKEN:
-        try:
-            out = _jit_encode_strings_fused(
-                layout, tuple(cols), row_offsets, total_bytes, maxlens, maxvar
-            )
-            # force execution INSIDE the try: async dispatch would defer
-            # a runtime failure past this handler and the fallback would
-            # never engage
-            return jax.block_until_ready(out)
-        except Exception as e:  # noqa: BLE001  # srjt-lint: allow-broad-except(any fused-program failure engages the staged fallback; see the latch note below)
-            # any fused failure must engage the staged fallback
-            # (round-3: wide axes crashed the XLA:TPU compiler;
-            # trace-time failures can surface as
-            # TypeError/NotImplementedError on other backends)
-            import logging
-
-            # A transient RESOURCE_EXHAUSTED (memory pressure from a
-            # concurrent batch) must not demote every later encode in
-            # the process: fall back for THIS call only and retry the
-            # fused form next time. Everything else latches once per
-            # process.
-            transient = "RESOURCE_EXHAUSTED" in str(e)
-            logging.getLogger(__name__).warning(
-                "fused string-encode program failed (%s: %s); falling "
-                "back to the staged pipeline %s",
-                type(e).__name__,
-                e,
-                "for this call" if transient else "for this process",
-            )
-            if not transient:
-                _FUSED_ENCODE_BROKEN = True  # pay the probe once per process
-
-    return _encode_strings_impl(layout, cols, row_offsets, total_bytes, maxlens, maxvar)
-
-
 def _to_rows_strings(
     layout: RowLayout,
     cols: Sequence[Column],
@@ -597,7 +534,7 @@ def _to_rows_strings(
 
     Scatter FALLBACK for tables whose padded-row form would exceed the
     device-memory budget (huge outlier strings): element-granular, slow,
-    but O(actual bytes). The hot path is _to_rows_strings_padded.
+    but O(actual bytes). The hot path is _jit_encode_strings_fused.
     """
     n = len(cols[0])
     var_cols = [cols[i] for i in layout.variable_cols]
@@ -660,6 +597,57 @@ def _wrap_batch_as_list_column(
     return col
 
 
+def _count_to_rows(rows: int, batches: List[Column], string_cols: int = 0,
+                   size_waits: int = 0, padded: int = 0, scatter: int = 0) -> None:
+    """Registry-direct (on with tracing off): what a call of
+    convert_to_rows moved and which form of the encode served it."""
+    reg = metrics.registry()
+    reg.counter("rowconv.to_rows.calls").inc()
+    reg.counter("rowconv.to_rows.rows").inc(rows)
+    reg.counter("rowconv.to_rows.bytes_out").inc(
+        sum(int(b.child.data.shape[0]) for b in batches)
+    )
+    reg.counter("rowconv.to_rows.batches").inc(len(batches))
+    reg.counter("rowconv.to_rows.string_cols").inc(string_cols)
+    reg.counter("rowconv.to_rows.size_waits").inc(size_waits)
+    reg.counter("rowconv.to_rows.padded").inc(padded)
+    reg.counter("rowconv.to_rows.scatter").inc(scatter)
+
+
+def _encode_strings_batch(
+    layout: RowLayout,
+    cols: Sequence[Column],
+    row_offsets: jnp.ndarray,  # [rows + 1] int64, the batch's own
+    nbytes: int,
+    maxlens: Tuple[int, ...],
+    max_size: int,  # the batch's largest row
+    batch: int,
+) -> Tuple[jnp.ndarray, str]:
+    """One batch of a mixed table -> ([nbytes] u8 blob, the form that
+    made it). The padded form wherever its [rows, fixed_end + maxvar]
+    matrix fits the budget; the scatter form for an outlier string that
+    it cannot hold. Dispatch only: nothing here waits for the device."""
+    rows = len(cols[0])
+    # static padded width of the var section, bucketed to 64B so
+    # batches of similar shape share one compiled program
+    maxvar = max(_round_up(max_size - layout.fixed_end, 64), 8)
+    form = (
+        "padded"
+        if rows * (layout.fixed_end + maxvar) <= _PADDED_ROWS_BYTE_BUDGET
+        else "scatter"
+    )
+    with tracing.span(
+        "rowconv.encode", form=form, batch=batch, rows=rows, bytes=nbytes, maxvar=maxvar
+    ):
+        if form == "padded":
+            blob = _jit_encode_strings_fused(
+                layout, tuple(cols), row_offsets, nbytes, maxlens, maxvar
+            )
+        else:  # huge outlier strings: padded form would OOM
+            blob = _to_rows_strings(layout, cols, row_offsets[:-1], nbytes)
+    return blob, form
+
+
 @op_boundary("convert_to_rows")
 def convert_to_rows(table: Table) -> List[Column]:
     """Table -> one or more LIST<INT8> columns of JCUDF rows.
@@ -672,91 +660,102 @@ def convert_to_rows(table: Table) -> List[Column]:
     cols = table.columns
 
     if n == 0:
-        return [_wrap_batch_as_list_column(jnp.zeros((0,), jnp.uint8), jnp.zeros((1,), jnp.int32))]
+        out = [_wrap_batch_as_list_column(jnp.zeros((0,), jnp.uint8), jnp.zeros((1,), jnp.int32))]
+        _count_to_rows(0, out, string_cols=len(layout.variable_cols))
+        return out
 
     if not layout.variable_cols:
         row_size = layout.row_size_fixed
         row_sizes = np.full((n,), row_size, dtype=np.int64)
         batches = _batch_boundaries(row_sizes)
         out = []
-        for rs, re, _ in batches:
-            if len(batches) <= 4:
-                # STATIC batch offsets: XLA folds the slice into the
-                # relayout kernel's first read instead of materializing
-                # a sliced copy of all 212 columns — the traced-offset
-                # form cost the >2GiB axis an extra full pass (r4:
-                # 23.3 GB/s at 4M vs 72.9 at 1M; VERDICT r4 item 5).
-                # One compile per (length, offset) pair; bounded by the
-                # <=4 batch cap (~8 GiB of rows), past which the
-                # traced-offset program keeps compile count at O(1).
-                blob = _jit_to_rows_fixed_static(layout, tuple(cols), rs, re - rs)
-            else:
-                blob = _jit_to_rows_fixed_sliced(layout, tuple(cols), rs, re - rs)
-            rel = jnp.arange(re - rs + 1, dtype=jnp.int32) * row_size
+        for k, (rs, re, nbytes) in enumerate(batches):
+            with tracing.span(
+                "rowconv.encode", form="fixed", batch=k, rows=re - rs, bytes=nbytes, maxvar=0
+            ):
+                if len(batches) <= 4:
+                    # STATIC batch offsets: XLA folds the slice into the
+                    # relayout kernel's first read instead of materializing
+                    # a sliced copy of all 212 columns — the traced-offset
+                    # form cost the >2GiB axis an extra full pass (r4:
+                    # 23.3 GB/s at 4M vs 72.9 at 1M; VERDICT r4 item 5).
+                    # One compile per (length, offset) pair; bounded by the
+                    # <=4 batch cap (~8 GiB of rows), past which the
+                    # traced-offset program keeps compile count at O(1).
+                    blob = _jit_to_rows_fixed_static(layout, tuple(cols), rs, re - rs)
+                else:
+                    blob = _jit_to_rows_fixed_sliced(layout, tuple(cols), rs, re - rs)
+                rel = jnp.arange(re - rs + 1, dtype=jnp.int32) * row_size
             out.append(_wrap_batch_as_list_column(blob, rel, uniform_stride=row_size))
+        _count_to_rows(n, out)
         return out
 
     # string path: per-row sizes -> batch split -> encode per batch.
-    # ONE jitted program for the sizes, and the host pull is kept to
-    # TWO SCALARS (total, max) in the common single-batch case — the
-    # eager per-column accumulation plus the full [N] i64 pull were
-    # most of the mixed-axis call in an earlier profile (host syncs);
-    # offsets stay on device.
+    # ONE jitted program for the sizes and ONE small transfer for all
+    # the host has to know before it can launch the encode: the byte
+    # total, the largest row and each STRING column's longest string
+    # (2 + K scalars; a column built by the sidecar's decode carries no
+    # memo of its longest string, and asking each for it was one more
+    # wait a column). Offsets stay on device.
     var_offs = tuple(cols[i].offsets for i in layout.variable_cols)
-    sizes_dev, offsets_dev, stats = _jit_row_size_stats(layout, var_offs)
-    total, max_size = (int(v) for v in np.asarray(stats))  # host sync
-    maxlens = _var_maxlens(layout, cols)
+    size_waits = 1
+    with tracing.span("rowconv.sizes", rows=n, string_cols=len(var_offs)) as sp:
+        sizes_dev, offsets_dev, stats = _jit_row_size_stats(layout, var_offs)
+        stats = [int(v) for v in np.asarray(stats)]  # host sync: the one wait
+        total, max_size, maxlens = stats[0], stats[1], tuple(stats[2:])
+        single = total <= MAX_BATCH_BYTES
+        if not single:
+            row_sizes = np.asarray(sizes_dev)  # host sync: full batch metadata
+            size_waits = 2
+        sp.annotate(total_bytes=total, max_row=max_size)
 
-    if total <= MAX_BATCH_BYTES:  # single batch: no further host pulls
-        row_offsets = offsets_dev
-        maxvar = max(_round_up(max_size - layout.fixed_end, 64), 8)
-        if n * (layout.fixed_end + maxvar) <= _PADDED_ROWS_BYTE_BUDGET:
-            blob = _to_rows_strings_padded(
-                layout, tuple(cols), row_offsets, total, maxlens, maxvar
+    forms = []
+    if single:  # no further host pulls
+        blob, form = _encode_strings_batch(
+            layout, cols, offsets_dev, total, maxlens, max_size, 0
+        )
+        forms.append(form)
+        out = [_wrap_batch_as_list_column(blob, offsets_dev)]
+    else:
+        out = []
+        for k, (rs, re, nbytes) in enumerate(_batch_boundaries(row_sizes)):
+            batch_cols = [_slice_column(c, rs, re) for c in cols]
+            sizes = jnp.asarray(row_sizes[rs:re], dtype=jnp.int64)
+            row_offsets = jnp.concatenate([jnp.zeros((1,), jnp.int64), jnp.cumsum(sizes)])
+            blob, form = _encode_strings_batch(
+                layout, batch_cols, row_offsets, nbytes, maxlens,
+                int(row_sizes[rs:re].max()), k,
             )
-        else:  # huge outlier strings: padded form would OOM
-            blob = _to_rows_strings(layout, cols, row_offsets[:-1], total)
-        return [_wrap_batch_as_list_column(blob, row_offsets)]
-
-    row_sizes = np.asarray(sizes_dev)  # host sync: full batch metadata
-    batches = _batch_boundaries(row_sizes)
-    out = []
-    for rs, re, nbytes in batches:
-        batch_cols = [_slice_column(c, rs, re) for c in cols]
-        sizes = jnp.asarray(row_sizes[rs:re], dtype=jnp.int64)
-        row_offsets = jnp.concatenate([jnp.zeros((1,), jnp.int64), jnp.cumsum(sizes)])
-        # static padded width of the var section, bucketed to 64B so
-        # batches of similar shape share one compiled program
-        max_size = int(row_sizes[rs:re].max())
-        maxvar = max(_round_up(max_size - layout.fixed_end, 64), 8)
-        if (re - rs) * (layout.fixed_end + maxvar) <= _PADDED_ROWS_BYTE_BUDGET:
-            blob = _to_rows_strings_padded(
-                layout, tuple(batch_cols), row_offsets, nbytes, maxlens, maxvar
-            )
-        else:  # huge outlier strings: padded form would OOM
-            blob = _to_rows_strings(layout, batch_cols, row_offsets[:-1], nbytes)
-        out.append(_wrap_batch_as_list_column(blob, row_offsets))
+            forms.append(form)
+            out.append(_wrap_batch_as_list_column(blob, row_offsets))
+    _count_to_rows(
+        n, out, string_cols=len(var_offs), size_waits=size_waits,
+        padded=int("padded" in forms), scatter=int("scatter" in forms),
+    )
     return out
 
 
 @partial(jax.jit, static_argnums=(0,))
 def _jit_row_size_stats(layout: RowLayout, var_offsets: Tuple[jnp.ndarray, ...]):
-    """([N] int64 8-aligned row sizes ON DEVICE, [2] {sum, max}) for the
-    string path, one program — the caller pulls only the two scalars
-    unless the table spans multiple 2 GiB batches."""
+    """([N] int64 8-aligned row sizes ON DEVICE, [N+1] offsets, [2 + K]
+    {sum, max, each STRING column's longest length}) for the string
+    path, one program: everything the host needs to size the encode
+    rides in ONE small transfer (the caller pulls the [N] sizes too only
+    when the table spans multiple 2 GiB batches)."""
     n = var_offsets[0].shape[0] - 1
     lens_total = jnp.zeros((n,), dtype=jnp.int64)
+    maxlens = []
     for offs in var_offsets:
-        lens_total = lens_total + (offs[1:] - offs[:-1]).astype(jnp.int64)
+        lens = (offs[1:] - offs[:-1]).astype(jnp.int64)
+        lens_total = lens_total + lens
+        maxlens.append(jnp.max(lens))
     sizes = (
         (lens_total + layout.fixed_end + JCUDF_ROW_ALIGNMENT - 1)
         // JCUDF_ROW_ALIGNMENT
         * JCUDF_ROW_ALIGNMENT
     )
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int64), jnp.cumsum(sizes)])
-    return sizes, offsets, jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
-
-
+    return sizes, offsets, jnp.stack([jnp.sum(sizes), jnp.max(sizes)] + maxlens)
 
 
 def _slice_column(col: Column, rs: int, re: int) -> Column:

@@ -350,6 +350,103 @@ def test_phase_spans_cover_nine_tenths_of_their_parent(q1_spans, whole, parts):
 
 
 # ---------------------------------------------------------------------------
+# kernels: rowconv.sizes and rowconv.encode under op.convert_to_rows (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+ROWCONV_ROWS = 600
+
+
+def _mixed_table(rng):
+    words = ["", "a", "spark", "tpu-native", "x" * 31, "yz"]
+    return Table([
+        Column.from_numpy(rng.integers(-9, 9, ROWCONV_ROWS).astype(np.int32), dt.INT32),
+        Column.from_pylist([words[i % len(words)] for i in range(ROWCONV_ROWS)], dt.STRING),
+        Column.from_numpy(rng.integers(0, 1 << 40, ROWCONV_ROWS).astype(np.int64), dt.INT64),
+        Column.from_pylist([None if i % 7 == 0 else words[(i * 3) % len(words)] for i in range(ROWCONV_ROWS)],
+                           dt.STRING),
+    ], ["a", "s1", "b", "s2"])
+
+
+def _traced_convert_to_rows(table):
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    rc.convert_to_rows(table)  # compiled before the traced call
+    trace_sink.reset_for_tests()
+    with tracing.enabled():
+        qt = tracing.start_trace("rowconv.test")
+        with qt.activate():
+            out = rc.convert_to_rows(table)
+        qt.finish("ok")
+    return trace_sink.recorder().last(1)[0]["spans"], out
+
+
+@pytest.fixture(scope="module")
+def rowconv_spans():
+    """{case: (spans, batches)} of one traced convert_to_rows each: a mixed
+    table in one batch, the same table with the batch limit patched small,
+    the same with the padded form's budget patched small."""
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    table = _mixed_table(np.random.default_rng(34))
+    out = {"one_batch": _traced_convert_to_rows(table)}
+    limit, budget = rc.MAX_BATCH_BYTES, rc._PADDED_ROWS_BYTE_BUDGET
+    try:
+        rc.MAX_BATCH_BYTES = 8192
+        out["several_batches"] = _traced_convert_to_rows(table)
+        rc.MAX_BATCH_BYTES, rc._PADDED_ROWS_BYTE_BUDGET = limit, 1024
+        out["scatter"] = _traced_convert_to_rows(table)
+    finally:
+        rc.MAX_BATCH_BYTES, rc._PADDED_ROWS_BYTE_BUDGET = limit, budget
+    return out
+
+
+@pytest.mark.parametrize("case", ["one_batch", "several_batches", "scatter"])
+def test_rowconv_spans_are_side_by_side_under_the_operator(rowconv_spans, case):
+    spans, batches = rowconv_spans[case]
+    op = _one(spans, "op.convert_to_rows")
+    sizes = _one(spans, "rowconv.sizes")  # the one span that waits, whatever the batches
+    encodes = sorted(_by_name(spans)["rowconv.encode"], key=lambda s: s["ts"])
+    assert len(encodes) == len(batches)
+    assert all(s["parent"] == op["span"] for s in [sizes] + encodes)  # never nested in each other
+    assert all(sizes["ts"] <= e["ts"] for e in encodes)
+    assert [e["annotations"]["batch"] for e in encodes] == list(range(len(batches)))
+
+
+def test_rowconv_sizes_says_what_the_one_transfer_told_the_host(rowconv_spans):
+    spans, batches = rowconv_spans["one_batch"]
+    blob = int(batches[0].child.data.shape[0])
+    sizes = np.diff(np.asarray(batches[0].offsets))
+    assert _one(spans, "rowconv.sizes")["annotations"] == {
+        "rows": ROWCONV_ROWS, "string_cols": 2, "total_bytes": blob, "max_row": int(sizes.max())}
+
+
+@pytest.mark.parametrize("case,form", [("one_batch", "padded"), ("several_batches", "padded"), ("scatter", "scatter")])
+def test_rowconv_encode_says_its_form_and_its_batch(rowconv_spans, case, form):
+    spans, batches = rowconv_spans[case]
+    assert (len(batches) > 2) if case == "several_batches" else (len(batches) == 1)
+    for e, b in zip(sorted(_by_name(spans)["rowconv.encode"], key=lambda s: s["ts"]), batches):
+        notes = e["annotations"]
+        assert set(notes) == {"form", "batch", "rows", "bytes", "maxvar"}
+        assert notes["form"] == form and notes["rows"] == len(b) and notes["bytes"] == int(b.child.data.shape[0])
+        assert notes["maxvar"] % 64 == 0 and notes["maxvar"] >= 64
+
+
+def test_rowconv_counters_show_in_the_stats_report():
+    from spark_rapids_jni_tpu import runtime
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    assert not tracing.is_enabled()  # registry-direct: counted with tracing off
+    names = ("calls", "rows", "bytes_out", "batches", "string_cols", "size_waits", "padded", "scatter")
+    before = {k: _counter(f"rowconv.to_rows.{k}") for k in names}
+    (batch,) = rc.convert_to_rows(_mixed_table(np.random.default_rng(35)))
+    moved = {k: _counter(f"rowconv.to_rows.{k}") - v for k, v in before.items()}
+    assert moved == {"calls": 1, "rows": ROWCONV_ROWS, "bytes_out": int(batch.child.data.shape[0]), "batches": 1,
+                     "string_cols": 2, "size_waits": 1, "padded": 1, "scatter": 0}
+    counters = runtime.stats_report()["metrics"]["counters"]
+    assert all(counters[f"rowconv.to_rows.{k}"] >= moved[k] for k in names)
+
+
+# ---------------------------------------------------------------------------
 # compiles: the counters and the xla.compile span
 # ---------------------------------------------------------------------------
 
@@ -593,6 +690,19 @@ def traced_convert(tmp_path_factory):
             "stats": stats, "cols": 3}
 
 
+def test_the_workers_stats_hold_the_transcodes_counters(traced_convert):
+    """ISSUE 34: registry-direct, so they count in a worker whether or not
+    its tracing is on, and ride the STATS verb's snapshot."""
+    counters = traced_convert["stats"]["snapshot"]["counters"]
+    got = {k[len("rowconv.to_rows."):]: v for k, v in counters.items() if k.startswith("rowconv.to_rows.")}
+    assert got == {"calls": 1, "rows": 500, "bytes_out": 500 * 24, "batches": 1, "string_cols": 0,
+                   "size_waits": 0, "padded": 0, "scatter": 0}
+    encode = _one(traced_convert["spans"], "rowconv.encode")
+    assert encode["parent"] == _one(traced_convert["spans"], "op.convert_to_rows")["span"]
+    assert encode["annotations"] == {"form": "fixed", "batch": 0, "rows": 500, "bytes": 500 * 24, "maxvar": 0}
+    assert "rowconv.sizes" not in _by_name(traced_convert["spans"])  # a fixed-width table waits for no sizes
+
+
 def _descends_from(span, ancestor_id, by_id):
     while span is not None:
         if span["parent"] == ancestor_id:
@@ -746,6 +856,7 @@ SYNTHETIC = [
     _s("groupby.keys", 8), _s("groupby.keys", 12), _s("op.groupby_aggregate", 9999),
     _s("groupby.agg.sum", 1000), _s("groupby.agg.sum", 1200), _s("groupby.agg.mean", 1500),
     _s("groupby.agg.count_all", 300), _s("groupby.aggregate_not_an_agg", 77),
+    _s("rowconv.sizes", 3), _s("rowconv.sizes", 4), _s("rowconv.sizes", 5), _s("rowconv.encode", 40),
 ]
 EXPECTED = {
     "sidecar_payload_read_ms": 210.0,
@@ -757,6 +868,7 @@ EXPECTED = {
     "sidecar_crc_ms": 520.0,
     "groupby_order_ms": 550.0,
     "groupby_agg_ms": 2000.0,
+    "rowconv_size_wait_ms": 6.0,
 }
 ALL_READERS = sorted(EXPECTED) + ["plan_stage_self_ms"]
 

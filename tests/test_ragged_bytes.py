@@ -166,10 +166,10 @@ def test_padded_vs_scatter_encode_parity(rng):
     )
     offsets = jnp.asarray(np.concatenate([[0], np.cumsum(sizes)]))
     total = int(np.sum(sizes))
-    maxlens = rc._var_maxlens(layout, cols)
+    maxlens = tuple(cols[i].max_char_len for i in layout.variable_cols)
     maxvar = max(rc._round_up(int(sizes.max()) - layout.fixed_end, 64), 8)
     fast = np.asarray(
-        rc._to_rows_strings_padded(layout, tuple(cols), offsets, total, maxlens, maxvar)
+        rc._jit_encode_strings_fused(layout, tuple(cols), offsets, total, maxlens, maxvar)
     )
     slow = np.asarray(rc._to_rows_strings(layout, cols, offsets[:-1], total))
     np.testing.assert_array_equal(fast, slow)
