@@ -4,15 +4,29 @@ TPU-first: sort-based grouping instead of a device hash table — XLA has
 a first-class sort but no general hash table; sort + segment-reduce is
 the canonical accelerator formulation. Pipeline:
 
-1. stable sort rows by key columns (ops/sort total-order keys),
+1. stable sort rows by key columns (ops/sort total-order keys); with a
+   row mask (``present``: the rows a filter kept, handed on in place of a
+   compacted table) one more lane in front of the keys puts the absent
+   rows last,
 2. group boundaries from neighbor inequality (nulls compare equal,
-   SQL GROUP BY semantics),
+   SQL GROUP BY semantics), counted over the present rows only: the
+   absent rows trail, so "is this sorted row present" is one compare of
+   an iota with the mask's count (``live``), never a gather of the mask,
+   and they take the segment id one past the last group,
 3. ``jax.ops.segment_*`` reductions with num_segments synced to host
    once (the output-allocation sync every engine pays); a FLOAT64 sum
    or mean is ONE device program after that sync (``_f64_sum_mean``:
    gather, exact accumulation, the mean's long division and the
-   rounding, compiled once per shape and group count and kept),
+   rounding, compiled once per shape and group count and kept). Every
+   aggregate reads ``live`` as part of its rows' validity. ``count_all``
+   is no reduction at all: a group's rows are the next group's start less
+   its own (the last group ends where the present rows do),
 4. group keys gathered from each segment's first row.
+
+The masked form answers bit for bit what the form over the compacted table
+answers (the sort is stable and the exact sums do not depend on order); it
+trades the filter's N-sized ``nonzero`` scatter and a gather a column for a
+group-by over N slots in place of the kept rows.
 
 Supported aggs: sum, count (valid), count_all, min, max, mean,
 nunique, and the variance family — var/std (sample, Spark
@@ -121,7 +135,18 @@ def _keys_equal_neighbor(col: Column, order: jnp.ndarray) -> jnp.ndarray:
     return same_valid & (same | both_null)
 
 
-def _segment_ids(keys: Table, order: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
+def _live_rows(present: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(live [N] bool, count): which SORTED rows are present, and how many.
+    ``sorted_order(..., present=)`` puts the absent rows last, so the sorted
+    mask is an iota under the mask's count: no gather through ``order``."""
+    count = jnp.sum(present, dtype=jnp.int32)
+    return jnp.arange(present.shape[0], dtype=jnp.int32) < count, count
+
+
+def _segment_ids(keys: Table, order: jnp.ndarray, live=None) -> Tuple[jnp.ndarray, int]:
+    """Sorted rows' group ids and the group count (the one host sync).
+    With ``live`` the groups are those of the live rows; the rows behind
+    them take the id ``num``, one past the last group."""
     n = keys.num_rows
     if n == 0:
         return jnp.zeros((0,), jnp.int32), 0
@@ -129,9 +154,13 @@ def _segment_ids(keys: Table, order: jnp.ndarray) -> Tuple[jnp.ndarray, int]:
     for col in keys.columns:
         eq = eq & _keys_equal_neighbor(col, order)
     starts = jnp.concatenate([jnp.ones((1,), bool), ~eq])
-    seg = jnp.cumsum(starts).astype(jnp.int32) - 1
-    num = int(seg[-1]) + 1  # host sync: group count
-    return seg, num
+    if live is None:
+        seg = jnp.cumsum(starts).astype(jnp.int32) - 1
+        num = int(seg[-1]) + 1  # host sync: group count
+        return seg, num
+    opened = jnp.cumsum(starts & live).astype(jnp.int32)
+    seg = jnp.where(live, opened - 1, opened[-1])
+    return seg, int(opened[-1])  # host sync: group count
 
 
 def _static_groups(num: int) -> int:
@@ -145,7 +174,7 @@ def _static_groups(num: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("num", "how"))
-def _f64_sum_mean(data, validity, order, seg, *, num: int, how: str):
+def _f64_sum_mean(data, validity, order, seg, live, *, num: int, how: str):
     """Exact FLOAT64 ``sum`` or ``mean`` of every group as ONE program:
     the column's u64 lanes and validity gathered through ``order``, then
     ops/f64acc's windowed integer accumulation, carry normalisation, the
@@ -153,9 +182,12 @@ def _f64_sum_mean(data, validity, order, seg, *, num: int, how: str):
     any row valid [num]) — the lanes the un-jitted chain returns, on
     every backend. ``f64acc``'s public functions stay plain (the fused
     pipeline and the mesh programs trace them into their own programs);
-    the program boundary is here, at the eager op."""
+    the program boundary is here, at the eager op. ``live`` (None, or the
+    sorted rows that are present) is part of a row's validity: ``num`` may
+    be padded past the id the absent rows carry, so they are masked, not
+    left to fall out of range."""
     bits = data[order]
-    valid = jnp.ones(order.shape, bool) if validity is None else validity[order]
+    valid = _sorted_valid(validity, order, live)
     if how == "sum":
         out_bits = f64acc.segment_sum_f64bits(bits, seg, num, valid=valid)
     else:
@@ -164,11 +196,18 @@ def _f64_sum_mean(data, validity, order, seg, *, num: int, how: str):
     return out_bits, any_valid
 
 
+def _sorted_valid(validity, order, live) -> jnp.ndarray:
+    """[N] bool: the sorted row holds a value and is present."""
+    if validity is None:
+        return jnp.ones(order.shape, bool) if live is None else live
+    return validity[order] if live is None else validity[order] & live
+
+
 def _is_f64_sum_mean(col: Column, how: str) -> bool:
     return how in ("sum", "mean") and col.dtype.id == TypeId.FLOAT64
 
 
-def _agg_column(col: Column, order, seg, num, how: str) -> Column:
+def _agg_column(col: Column, order, seg, num, how: str, live=None) -> Column:
     d = col.dtype
     if _is_f64_sum_mean(col, how):
         # exact on all backends: windowed integer accumulation over
@@ -178,17 +217,14 @@ def _agg_column(col: Column, order, seg, num, how: str) -> Column:
         metrics.registry().counter("groupby.agg.jitted").inc()
         padded = _static_groups(num)
         out_bits, any_valid = _f64_sum_mean(
-            col.data, col.validity, order, seg, num=padded, how=how
+            col.data, col.validity, order, seg, live, num=padded, how=how
         )
         if padded != num:
             out_bits, any_valid = out_bits[:num], any_valid[:num]
         return Column(dt.FLOAT64, data=out_bits, validity=any_valid)
     metrics.registry().counter("groupby.agg.eager").inc()
-    sorted_valid = col.valid_mask()[order]
+    sorted_valid = _sorted_valid(col.validity, order, live)
 
-    if how == "count_all":
-        data = jax.ops.segment_sum(jnp.ones_like(seg, jnp.int64), seg, num)
-        return Column(dt.INT64, data=data)
     if how == "count":
         data = jax.ops.segment_sum(sorted_valid.astype(jnp.int64), seg, num)
         return Column(dt.INT64, data=data)
@@ -357,23 +393,37 @@ def _from_total_order(key: jnp.ndarray, d) -> jnp.ndarray:
 
 @op_boundary("groupby_aggregate")
 def groupby_aggregate(
-    keys: Table, values: Table, aggs: Sequence[Tuple[str, str]]
+    keys: Table, values: Table, aggs: Sequence[Tuple[str, str]], present=None
 ) -> Table:
     """GROUP BY keys, computing aggs = [(value_col_name, how), ...].
 
     Returns a Table of unique keys followed by one column per agg named
     ``{col}_{how}``. Row order is key-sorted (callers needing original
     first-appearance order can re-sort; SQL imposes none).
+
+    ``present`` (a ``bool[N]`` device array, or None: every row) names the
+    rows that take part: the answer is, bit for bit, that over
+    ``apply_boolean_mask(..., present)`` of both tables, with no
+    compaction — the absent rows are sorted last and masked out of every
+    aggregate (every ``how`` takes it, ``nunique`` too).
     """
     # phase spans (srjt-trace): each times what the HOST did in the
     # phase — dispatching the phase's programs, and in
     # ``groupby.segments`` the one sync — not what the device did
     n = keys.num_rows
-    with tracing.span("groupby.sort", rows=n, keys=len(keys.columns)):
-        order = sorted_order(keys)
+    with tracing.span("groupby.sort", rows=n, keys=len(keys.columns), masked=present is not None):
+        order = sorted_order(keys, present=present)
     with tracing.span("groupby.segments") as sp:
-        seg, num = _segment_ids(keys, order)
+        # ``end``: where the last group's rows end in the sorted rows
+        live, end = (None, n) if present is None else _live_rows(present)
+        seg, num = _segment_ids(keys, order, live)
         sp.annotate(groups=num)
+    if present is not None and num == 0 and n:
+        # no row is present: the empty table's answer, dtypes and all
+        order = seg = seg[:0]
+        keys, values = gather(keys, order), gather(values, order)
+        present = live = None
+        n = end = 0
 
     with tracing.span("groupby.keys"):
         first_of_group = jnp.searchsorted(seg, jnp.arange(num, dtype=jnp.int32), side="left")
@@ -388,30 +438,46 @@ def groupby_aggregate(
             jit=_is_f64_sum_mean(col, how),
         ):
             if how == "nunique":
-                out_cols.append(_nunique_column(keys, col, num))
+                out_cols.append(_nunique_column(keys, col, num, present, live))
+            elif how == "count_all":
+                out_cols.append(_group_sizes(first_of_group, end))
             else:
-                out_cols.append(_agg_column(col, order, seg, num, how))
+                out_cols.append(_agg_column(col, order, seg, num, how, live))
         out_names.append(f"{col_name}_{how}")
     return Table(out_cols, out_names)
 
 
-def _nunique_column(keys: Table, col: Column, num: int) -> Column:
+def _group_sizes(first_of_group: jnp.ndarray, end) -> Column:
+    """COUNT(*) of every group from where the groups start in the sorted
+    rows: a group's rows are the next group's start less its own, and the
+    last group ends at ``end``, the count of the rows that are present.
+    No scatter: a ``segment_sum`` of ones over 6 M rows was 432 ms a q1
+    request on the v5e (PERF.md, PR 33) for numbers the boundaries hold."""
+    metrics.registry().counter("groupby.agg.eager").inc()
+    bounds = jnp.concatenate([first_of_group.astype(jnp.int64),
+                              jnp.reshape(jnp.asarray(end, jnp.int64), (1,))])
+    return Column(dt.INT64, data=bounds[1:] - bounds[:-1])
+
+
+def _nunique_column(keys: Table, col: Column, num: int, present=None, live=None) -> Column:
     """COUNT(DISTINCT col) per group, nulls excluded (SQL semantics).
 
     Re-sorts by (keys..., col) so equal values are adjacent within each
     group; a value is a NEW distinct when it is valid and differs from
     its predecessor (or the predecessor is another group / null — nulls
-    sort first within the group under nulls_first)."""
+    sort first within the group under nulls_first). Under a row mask
+    (``present``, and ``live`` as ``_live_rows`` gives it) the second sort
+    takes the mask's lane too."""
     both = Table(list(keys.columns) + [col], list(keys.names) + ["__v"])
-    order2 = sorted_order(both)
-    seg2, num2 = _segment_ids(keys, order2)
+    order2 = sorted_order(both, present=present)
+    seg2, num2 = _segment_ids(keys, order2, live)
     if num2 != num:
         raise AssertionError("group count mismatch between sort orders")
     n = keys.num_rows
     if n == 0:
         return Column(dt.INT64, data=jnp.zeros((0,), jnp.int64))
 
-    valid = col.valid_mask()[order2]
+    valid = _sorted_valid(col.validity, order2, live)
     same_val = _keys_equal_neighbor(col, order2)  # [n-1], value equal to prev
     same_group = seg2[1:] == seg2[:-1]
     prev_valid = valid[:-1]

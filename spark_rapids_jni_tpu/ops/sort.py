@@ -105,13 +105,17 @@ def sorted_order(
     table: Table,
     ascending: Optional[Sequence[bool]] = None,
     nulls_first: Optional[Sequence[bool]] = None,
+    present: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Stable gather indices ordering the table by its columns (leftmost
-    key is most significant), parity with cudf::sorted_order semantics."""
+    key is most significant), parity with cudf::sorted_order semantics.
+    ``present`` (``bool[N]``: the rows a filter kept) puts one more lane in
+    front of every key, so the absent rows lie last and the rows that are
+    present come first, in the order they would have had alone."""
     ncols = table.num_columns
     asc = list(ascending) if ascending is not None else [True] * ncols
     nf = list(nulls_first) if nulls_first is not None else [True] * ncols
-    lanes: List[jnp.ndarray] = []
+    lanes: List[jnp.ndarray] = [] if present is None else [(~present).astype(jnp.uint8)]
     for col, a, f in zip(table.columns, asc, nf):
         lanes.extend(_column_keys(col, a, f))
     if tracing.is_enabled():  # lands on the caller's span: groupby.sort, op.sort_by_key, join.factorize

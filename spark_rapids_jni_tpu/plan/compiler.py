@@ -16,8 +16,11 @@ windows, sorts, unions — lowers to the tested ``ops/`` operators over
 the (small) intermediate tables. On that operator tier a Filter's and a
 Project's expressions are still ONE device program a stage
 (``_StageProgram``, ISSUE 33), and so is the float64 normalisation of an
-aggregate's outputs (``_normalize_agg_columns``); what follows a Filter's
-mask (the compaction) and the operators themselves launch as ``ops/`` does.
+aggregate's outputs (``_normalize_agg_columns``); the operators themselves
+launch as ``ops/`` does. A Filter whose rows reach an Aggregate through
+nothing but Projects does not compact where its mask keeps half its rows or
+more (ISSUE 35): it hands its input on with the mask beside it
+(``_MaskedTable``), and the group-by groups the rows that are present.
 
 Estimates (Theseus, arxiv 2508.05029: the plan is where data-movement /
 memory decisions belong): every stage carries ``rows``/``bytes``
@@ -80,6 +83,23 @@ Schema = Dict[str, DType]
 
 _FUSED_AGGS = ("sum", "count", "count_all", "min", "max", "mean")
 _FILTER_SELECTIVITY = 0.5  # conservative: only UNDERestimates are gated
+# The least share of its rows a Filter under an Aggregate has to keep to hand
+# its mask on in place of a compacted table. Deferring trades the filter's
+# N-sized ``nonzero`` scatter and a gather a column for a group-by over N slots
+# in place of k N rows. Reckoned from the v5e's program times at N = 6.0 M
+# (PERF.md 5, PR 33; ms): compacting costs 741 + k 336 and the group-by behind
+# it k 1,430, deferred the group-by is 1,470 and the filter nothing: they cross
+# at k = 0.41. Measured (PERF.md 6, PR 35: q1's plan at N = 6,001,215 with the
+# share kept swept, both forms forced, ``benchmarks/calls/pr35_forms.py``): a
+# deferred request is 1,327–1,336 ms whatever is kept; a compacted one 628 at
+# k = 0.01, 747 at 0.1, 1,002 at 0.25, 1,432 at 0.5, 1,858 at 0.75, 2,506 at
+# 0.986: they cross at k = 0.44. One half leaves the stretch between to the
+# compacted form (7% dearer there at most) and is right wherever the group-by
+# is lighter than q1's eight aggregates, where the crossing lies lower still; a
+# filter that keeps 1% is better compacted by 2.1x. Read from the mask's own
+# count at run time, never from the planner's estimate (a column without a
+# sketch estimates at ``_FILTER_SELECTIVITY`` whatever it keeps).
+_DEFER_MIN_KEEP = 0.5
 _MAX_DENSE_GROUPS = 1 << 22
 
 
@@ -140,6 +160,31 @@ def _keep(mask: Column, present=None):
     if mask.validity is not None:
         keep = keep & mask.validity
     return keep if present is None else present & keep
+
+
+class _MaskedTable:
+    """What a deferred Filter hands on: its INPUT's columns, whole, and
+    ``present``, the rows its predicate keeps (``bool[N]`` on the device) —
+    the one-chip twin of ``ShardedTable.present``. Only the Project stages
+    between such a Filter and its Aggregate, and that Aggregate, ever see
+    one (``_defer_filters`` marks a Filter only where they are its sole
+    readers)."""
+
+    __slots__ = ("table", "present")
+
+    def __init__(self, table: Table, present):
+        self.table, self.present = table, present
+
+    @property
+    def num_rows(self) -> int:
+        """Slots, not rows (as ``ShardedTable.num_rows``); the Filter's span says ``kept``."""
+        return self.table.num_rows
+
+
+def _rows_of(t):
+    """(table, present): a stage's input as its columns and the rows of them
+    that are present (None: all of them)."""
+    return (t.table, t.present) if isinstance(t, _MaskedTable) else (t, None)
 
 
 class _StageProgram:
@@ -397,14 +442,29 @@ class _FilterExec(_Exec):
         self.pred = node.predicate
         self.program = _StageProgram([(self.pred, dt.BOOL8)], child.schema,
                                      sharding=sharding, fold=True)
+        # set by ``_defer_filters`` once every stage is lowered: this stage's
+        # rows reach an op-tier Aggregate through nothing but jitted Projects
+        # and nothing else reads them
+        self.deferrable = False
 
     def _run(self, ctx):
         from ..ops import copying
 
         t = self.inputs[0].run(ctx)
-        # the mask is one program; the compaction behind it (``jnp.nonzero``
-        # and a gather a column) is still the eager op's
-        return copying.apply_boolean_mask(t, self.program(t, t.num_rows))
+        keep = self.program(t, t.num_rows)  # the mask: one program
+        if self.deferrable:
+            # the stage's one wait, where ``jnp.nonzero`` would read its size:
+            # the mask's own count says whether compacting pays
+            kept = int(jnp.sum(keep))
+            if kept >= _DEFER_MIN_KEEP * t.num_rows:
+                _durable("plan.filter.deferred").inc()
+                tracing.annotate(deferred=True, kept=kept)
+                return _MaskedTable(t, keep)
+        # the compaction is the eager op's: ``jnp.nonzero`` and a gather a column
+        out = copying.apply_boolean_mask(t, keep)
+        _durable("plan.filter.compacted").inc()
+        tracing.annotate(deferred=False, kept=out.num_rows)
+        return out
 
 
 class _ProjectExec(_Exec):
@@ -417,8 +477,10 @@ class _ProjectExec(_Exec):
                                      child.schema, sharding=sharding)
 
     def _run(self, ctx):
-        t = self.inputs[0].run(ctx)
-        return Table(self.program(t, t.num_rows), [name for name, _ in self.exprs])
+        t, present = _rows_of(self.inputs[0].run(ctx))
+        out = Table(self.program(t, t.num_rows), [name for name, _ in self.exprs])
+        # under a deferred Filter the trees run over every slot and the mask rides on
+        return out if present is None else _MaskedTable(out, present)
 
 
 class _JoinExec(_Exec):
@@ -628,22 +690,10 @@ class _AggExec(_Exec):
     def _run(self, ctx):
         from ..ops.aggregate import groupby_aggregate
 
-        t = self.inputs[0].run(ctx)
+        t, present = _rows_of(self.inputs[0].run(ctx))
         n = t.num_rows
         if not self.keys and n == 0:
-            # SQL global aggregates yield ONE row on empty input (the
-            # fused tier does; the sort-based kernel yields zero groups)
-            cols, names = [], []
-            for a in self.aggs:
-                if a.how in ("count", "count_all", "nunique"):
-                    cols.append(Column(dt.INT64, data=jnp.zeros((1,), jnp.int64)))
-                else:
-                    cols.append(Column(
-                        dt.FLOAT64, data=jnp.zeros((1,), jnp.uint64),
-                        validity=jnp.zeros((1,), bool),
-                    ))
-                names.append(a.name)
-            return Table(cols, names)
+            return self._global_of_nothing()
         if self.keys:
             keys_tbl = t.select(list(self.keys))
         else:
@@ -656,8 +706,9 @@ class _AggExec(_Exec):
                 self.keys[0] if self.keys else t.names[0]
             )
             spec.append((src, a.how, a.name))
-        values = t
-        agg = groupby_aggregate(keys_tbl, values, [(s, h) for s, h, _ in spec])
+        agg = groupby_aggregate(keys_tbl, t, [(s, h) for s, h, _ in spec], present=present)
+        if not self.keys and agg.num_rows == 0:
+            return self._global_of_nothing()  # a deferred Filter's mask kept no row
         # groupby_aggregate names outputs {src}_{how} in order after the
         # keys; rebind positionally to the AggSpec names and normalize
         # onto the fused materialization contract
@@ -666,6 +717,20 @@ class _AggExec(_Exec):
         out_cols += _normalize_agg_columns([agg.column(nk + j) for j in range(len(spec))],
                                            [how for _, how, _ in spec])
         return Table(out_cols, list(self.keys) + [name for _, _, name in spec])
+
+    def _global_of_nothing(self) -> Table:
+        """SQL global aggregates yield ONE row on empty input (the fused
+        tier does; the sort-based kernel yields zero groups)."""
+        cols = []
+        for a in self.aggs:
+            if a.how in ("count", "count_all", "nunique"):
+                cols.append(Column(dt.INT64, data=jnp.zeros((1,), jnp.int64)))
+            else:
+                cols.append(Column(
+                    dt.FLOAT64, data=jnp.zeros((1,), jnp.uint64),
+                    validity=jnp.zeros((1,), bool),
+                ))
+        return Table(cols, [a.name for a in self.aggs])
 
 
 class _FusedAggExec(_Exec):
@@ -1186,6 +1251,32 @@ class _Lowerer:
             "rewritten away before compilation")
 
 
+def _defer_filters(stages: List[_Exec]) -> None:
+    """Mark the one-chip Filter stages that may hand their mask on in place
+    of a compacted table (ISSUE 35): under an op-tier Aggregate (the fuser
+    bailed; never a mesh stage) through nothing but Projects whose every
+    tree is a reference or in the stage's one program (a jitted elementwise
+    program cannot raise on a row the filter would have removed; a STRING
+    handed on costs nothing), with no other reader anywhere along the way
+    (``_Lowerer.lower`` memoises by node: a shared CTE subtree keeps its
+    compaction). Two conditions of shape, decided here once every stage is
+    lowered and its readers can be counted; the third, that the mask keeps
+    at least ``_DEFER_MIN_KEEP`` of its rows, the stage reads at run time."""
+    readers: Dict[int, int] = {}
+    for ex in stages:
+        for i in ex.inputs:
+            readers[id(i)] = readers.get(id(i), 0) + 1
+    for ex in stages:
+        if type(ex) is not _AggExec:
+            continue
+        below = ex.inputs[0]
+        while (type(below) is _ProjectExec and readers[id(below)] == 1
+               and not below.program.n_eager):
+            below = below.inputs[0]
+        if type(below) is _FilterExec and readers[id(below)] == 1:
+            below.deferrable = True
+
+
 # ---------------------------------------------------------------------------
 # the public compile surface
 # ---------------------------------------------------------------------------
@@ -1374,6 +1465,7 @@ def compile_ir(plan: Node, tables: Dict[str, Table],
         _durable(f"plan.rewrites.{rule}").inc(n)
     low = _Lowerer(tables, catalog, est=est, mesh=mesh)
     root = low.local(opt_plan)
+    _defer_filters(low.all_execs)
     cp = CompiledPlan(name, root, placed, low.all_execs, raw_nodes,
                       _count_nodes(opt_plan), fired, opt_plan,
                       obligations=obligations, node_execs=low._execs,
@@ -1409,6 +1501,7 @@ def lower_ir(opt_plan: Node, tables: Dict[str, Table], name: str = "plan", *,
 
     low = _Lowerer(tables, catalog, est=_stats.make_estimator(tables))
     root = low.lower(opt_plan)
+    _defer_filters(low.all_execs)
     _durable("plan.lower_only").inc()
     cp = CompiledPlan(name, root, tables, low.all_execs,
                       raw_nodes if raw_nodes is not None else opt_nodes,

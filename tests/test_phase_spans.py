@@ -76,9 +76,9 @@ def _lineitem(rng):
     )
 
 
-def _q1_shaped_plan():
+def _q1_shaped_plan(cutoff=2436):
     disc_price = P.pcol("price") * (P.plit(1.0) - P.pcol("disc"))
-    x = P.Filter(P.Scan("lineitem"), P.pcol("shipdate") <= P.plit(np.int32(2436)))
+    x = P.Filter(P.Scan("lineitem"), P.pcol("shipdate") <= P.plit(np.int32(cutoff)))
     x = P.Project(x, (
         ("flag", P.pcol("flag")),
         ("status", P.pcol("status")),
@@ -96,10 +96,14 @@ def _q1_shaped_plan():
 
 @pytest.fixture(scope="module")
 def q1_spans():
+    return _spans_of_a_q1_shaped_query()
+
+
+def _spans_of_a_q1_shaped_query(cutoff=2436):
     """The span tree of one traced q1-shaped query through the scheduler
     (the second run: the first has compiled everything)."""
     table = _lineitem(np.random.default_rng(26))
-    cp = P.compile_ir(_q1_shaped_plan(), {"lineitem": table}, name="q1_shaped")
+    cp = P.compile_ir(_q1_shaped_plan(cutoff), {"lineitem": table}, name="q1_shaped")
     sched = serve.Scheduler(max_concurrent=1, name="phase-spans")
     prev = tracing.is_enabled()
     try:
@@ -146,9 +150,13 @@ def test_plan_stage_spans_nest_as_the_plan_does(q1_spans, child, parent):
 
 def test_plan_stage_spans_say_their_rows_out(q1_spans):
     assert _one(q1_spans, "plan.scan")["annotations"] == {"rows_out": N_ROWS}
-    kept = _one(q1_spans, "plan.filter")["annotations"]["rows_out"]
-    assert 0 < kept < N_ROWS
-    assert _one(q1_spans, "plan.project")["annotations"]["rows_out"] == kept
+    # ISSUE 35: the Filter keeps 94% of its rows and an Aggregate reads them
+    # through one jitted Project, so it hands its mask on: ``rows_out`` is the
+    # slot count (as a mesh stage's), ``kept`` the rows that are present
+    notes = _one(q1_spans, "plan.filter")["annotations"]
+    assert notes["deferred"] is True and 0 < notes["kept"] < N_ROWS
+    assert notes["rows_out"] == N_ROWS
+    assert _one(q1_spans, "plan.project")["annotations"]["rows_out"] == N_ROWS
     assert _one(q1_spans, "plan.aggregate")["annotations"]["rows_out"] == 6
 
 
@@ -162,7 +170,8 @@ def test_stage_spans_say_what_went_into_the_stages_one_program(q1_spans, stage, 
     float64 normalisation, are ONE jitted program a stage."""
     notes = _one(q1_spans, stage)["annotations"]
     assert notes["jit"] is jit and notes["exprs"] == exprs
-    assert set(notes) == {"rows_out", "jit", "exprs"}
+    own = {"deferred", "kept"} if stage == "plan.filter" else set()  # ISSUE 35
+    assert set(notes) == {"rows_out", "jit", "exprs"} | own
     assert "jit" not in _one(q1_spans, "plan.scan")["annotations"]
 
 
@@ -196,6 +205,37 @@ def test_expr_counters_move_as_the_stages_run(case, jitted, eager):
     assert counters.get("plan.expr.jitted", 0) >= 2 * jitted and counters.get("plan.expr.eager", 0) >= 2 * eager
 
 
+@pytest.mark.parametrize("share,deferred", [("most", True), ("half", True), ("a_row_under_half", False), ("none", False)])
+def test_filter_counters_and_spans_say_which_form_ran(share, deferred):
+    """ISSUE 35: ``plan.filter.deferred`` a Filter run that handed its mask
+    on, ``plan.filter.compacted`` one that ran ``apply_boolean_mask``
+    (registry-direct: counted with tracing off, shown by ``stats_report``);
+    the span says ``deferred`` and ``kept``, ``groupby.sort`` says ``masked``."""
+    from spark_rapids_jni_tpu import runtime
+
+    assert not tracing.is_enabled()
+    ship = np.asarray(_lineitem(np.random.default_rng(26)).column("shipdate").data)
+    middle = int(np.sort(ship)[N_ROWS // 2 - 1])  # ``<= middle`` keeps half the rows or a few more
+    cutoff = {"most": 2436, "half": middle, "a_row_under_half": middle - 1, "none": -1}[share]
+    was = _counter("plan.filter.deferred"), _counter("plan.filter.compacted")
+    spans = _spans_of_a_q1_shaped_query(cutoff)  # two runs: one untraced, one traced
+    moved = _counter("plan.filter.deferred") - was[0], _counter("plan.filter.compacted") - was[1]
+    assert moved == ((2, 0) if deferred else (0, 2))
+    kept = int((ship <= cutoff).sum())
+    assert (2 * kept >= N_ROWS) is deferred
+    notes = _one(spans, "plan.filter")["annotations"]
+    assert notes["deferred"] is deferred and notes["kept"] == kept
+    assert notes["rows_out"] == (N_ROWS if deferred else kept)
+    sort = _one(spans, "groupby.sort")["annotations"]
+    assert sort["masked"] is deferred and sort["rows"] == notes["rows_out"]
+    assert sort["key_lanes"] == (5 if deferred else 4)
+    if kept:
+        assert _one(spans, "groupby.segments")["annotations"] == {"groups": 6}
+    counters = runtime.stats_report()["metrics"]["counters"]
+    assert counters.get("plan.filter.deferred", 0) >= moved[0]
+    assert counters.get("plan.filter.compacted", 0) >= moved[1]
+
+
 def test_groupby_phase_spans_are_children_of_the_operator(q1_spans):
     op = _one(q1_spans, "op.groupby_aggregate")["span"]
     names = _by_name(q1_spans)
@@ -203,8 +243,9 @@ def test_groupby_phase_spans_are_children_of_the_operator(q1_spans):
         assert _one(q1_spans, phase)["parent"] == op
     assert _one(q1_spans, "groupby.sort")["annotations"] == {"rows": _one(
         q1_spans, "plan.project")["annotations"]["rows_out"], "keys": 2,
-        # two int8 keys: a null rank and one lane each (PR 32), no STRING
-        "key_lanes": 4, "string_keys": 0}
+        # two int8 keys: a null rank and one lane each (PR 32), no STRING;
+        # and in front of them the deferred Filter's mask (ISSUE 35)
+        "key_lanes": 5, "string_keys": 0, "masked": True}
     assert _one(q1_spans, "groupby.segments")["annotations"] == {"groups": 6}
     aggs = sorted(
         (s["name"], s["annotations"]["col"], s["annotations"]["dtype"])
@@ -325,7 +366,8 @@ def test_sort_spans_say_their_key_lanes(star_spans):
     spans, _, _ = star_spans
     joined = _one(spans, "join.expand")["annotations"]["rows_out"]
     # an 18-byte brand: a null rank, three 8-byte lanes and the length
-    assert _one(spans, "groupby.sort")["annotations"] == {"rows": joined, "keys": 1, "key_lanes": 5, "string_keys": 1}
+    assert _one(spans, "groupby.sort")["annotations"] == {"rows": joined, "keys": 1, "key_lanes": 5, "string_keys": 1,
+                                                             "masked": False}
     assert _one(spans, "op.sort_by_key")["annotations"] == {"key_lanes": 5, "string_keys": 1}
 
 

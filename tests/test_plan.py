@@ -706,12 +706,281 @@ class TestStageProgram:
                                       np.asarray(i64_to_f64bits(jnp.asarray(want))))
 
 
-def _counters():
+def _counters(prefix="plan.expr", names=("jitted", "eager")):
     from spark_rapids_jni_tpu.utils import metrics
 
     reg = metrics.registry()
-    return {k: reg.value(f"plan.expr.{k}") for k in ("jitted", "eager")}
+    return {k: reg.value(f"{prefix}.{k}") for k in names}
 
 
 def _moved(before):
     return {k: v - before[k] for k, v in _counters().items()}
+
+
+# ---------------------------------------------------------------------------
+# a one-chip Filter under an Aggregate hands its mask on (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+
+def _bench_module(kind, name):
+    import importlib.util
+    import os
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", os.path.join(bench, kind, f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _filter_counters():
+    return _counters("plan.filter", ("deferred", "compacted"))
+
+
+def _filters_moved(before):
+    return {k: v - before[k] for k, v in _filter_counters().items()}
+
+
+def _no_compaction(monkeypatch):
+    """Fail the run that launches the compaction's ``jnp.nonzero``."""
+    def nonzero(*a, **k):
+        raise AssertionError("a deferred Filter launched jnp.nonzero")
+
+    monkeypatch.setattr(jnp, "nonzero", nonzero)
+
+
+Q1_ROWS = 20_000  # the cell's rehearsal size
+
+
+@pytest.fixture(scope="module")
+def q1_cell():
+    """seed -> (the cell's own q1 module, its lineitem on the device, the
+    pandas frames): ``bench/queries/tpch_q1.py`` over ``bench/data/tpch_lineitem.py``."""
+    q1, data = _bench_module("queries", "tpch_q1"), _bench_module("data", "tpch_lineitem")
+    kinds = {"l_returnflag": dt.INT8, "l_linestatus": dt.INT8, "l_shipdate": dt.TIMESTAMP_DAYS}
+
+    def get(seed):
+        host = data.host_tables({}, seed, Q1_ROWS)["lineitem"]
+        table = Table([Column.from_numpy(np.ascontiguousarray(a), kinds.get(c, dt.FLOAT64)) for c, a in host.items()],
+                      list(host))
+        return q1, table, {"lineitem": pd.DataFrame(host)}
+
+    return get
+
+
+def _q1_with_cutoff(q1, cutoff):
+    """The cell's plan with another DELTA: the same stages, another share kept."""
+    from unittest import mock
+
+    with mock.patch.object(q1, "CUTOFF", cutoff):
+        return q1.plan(P)
+
+
+def _assert_q1_answer(q1, out, frames, cutoff):
+    from unittest import mock
+
+    with mock.patch.object(q1, "CUTOFF", cutoff):
+        want = q1.reference(frames)
+    assert out.num_rows == len(want)
+    for name in out.names:
+        got = np.asarray(out.column(name).data)
+        if name in q1.EXACT:
+            np.testing.assert_array_equal(got, want[name].to_numpy())
+        else:
+            np.testing.assert_allclose(got.view(np.float64), want[name].to_numpy(), rtol=1e-9, atol=0)
+
+
+class TestDeferredFilter:
+    @pytest.mark.parametrize("seed", [7, 3_500_000_011])
+    def test_q1_defers_and_answers_as_pandas(self, q1_cell, seed, monkeypatch):
+        q1, table, frames = q1_cell(seed)
+        cp = P.compile_ir(q1.plan(P), {"lineitem": table}, name="q1")
+        assert [s.kind for s in cp.stages] == ["scan", "filter", "project", "aggregate", "sort"]
+        assert cp.stages[1].deferrable
+        assert P.verify_estimates(cp) == [] and P.verify_plan(cp.optimized, catalog_of({"lineitem": table})) == []
+        before = _filter_counters()
+        _no_compaction(monkeypatch)
+        out = cp()
+        assert _filters_moved(before) == {"deferred": 1, "compacted": 0}
+        _assert_q1_answer(q1, out, frames, q1.CUTOFF)
+        got = {n: c.dtype for n, c in zip(out.names, out.columns)}
+        assert got == cp.schema
+        # a deferred stage reports its slots, as a mesh stage does; the aggregate its groups
+        rows = [s["actual_rows"] for s in cp.last_report["stages"]]
+        assert rows == [Q1_ROWS, Q1_ROWS, Q1_ROWS, 4, 4]
+
+    @pytest.mark.parametrize("stats", ["stats_on", "stats_off"])
+    @pytest.mark.parametrize("share,deferred", [("keeps_99_percent", True), ("keeps_half_and_more", True),
+                                               ("keeps_under_half", False), ("keeps_1_percent", False)])
+    def test_the_masks_own_count_decides_not_the_estimate(self, q1_cell, share, deferred, stats, monkeypatch):
+        """Same plan, same stages, same ESTIMATE (a TIMESTAMP column has no
+        sketch: the default one half, statistics on or off): the stage
+        reads the mask's popcount and compacts below one half."""
+        monkeypatch.setenv("SRJT_STATS_ENABLED", "1" if stats == "stats_on" else "0")
+        q1, table, frames = q1_cell(7)
+        ship = np.sort(frames["lineitem"].l_shipdate.to_numpy())
+        want_share = {"keeps_99_percent": 0.99, "keeps_half_and_more": 0.52,
+                      "keeps_under_half": 0.48, "keeps_1_percent": 0.01}[share]
+        cutoff = int(ship[int(want_share * Q1_ROWS)])
+        kept = int((ship <= cutoff).sum())
+        assert (2 * kept >= Q1_ROWS) is deferred
+        cp = P.compile_ir(_q1_with_cutoff(q1, cutoff), {"lineitem": table}, name=share)
+        flt = cp.stages[1]
+        assert flt.kind == "filter" and flt.deferrable and flt.est_rows == Q1_ROWS // 2
+        before = _filter_counters()
+        out = cp()
+        assert _filters_moved(before) == {"deferred": int(deferred), "compacted": int(not deferred)}
+        _assert_q1_answer(q1, out, frames, cutoff)
+        assert cp.last_report["stages"][1]["actual_rows"] == (Q1_ROWS if deferred else kept)
+
+    def test_both_forms_of_q1_give_the_same_lanes(self, q1_cell):
+        """Exact sums, exact means, counts and keys: bit for bit, not within a gap."""
+        q1, table, _ = q1_cell(7)
+        plan = q1.plan(P)
+        deferred = P.compile_ir(plan, {"lineitem": table}, name="q1-deferred")
+        compacted = P.compile_ir(plan, {"lineitem": table}, name="q1-compacted")
+        compacted.stages[1].deferrable = False
+        before = _filter_counters()
+        a, b = deferred(), compacted()
+        assert _filters_moved(before) == {"deferred": 1, "compacted": 1}
+        assert a.names == b.names
+        for name in a.names:
+            ca, cb = a.column(name), b.column(name)
+            assert ca.dtype == cb.dtype and (ca.validity is None) == (cb.validity is None)
+            np.testing.assert_array_equal(np.asarray(ca.data), np.asarray(cb.data), err_msg=name)
+            np.testing.assert_array_equal(np.asarray(ca.valid_mask()), np.asarray(cb.valid_mask()))
+
+    def _t(self, rng, n=600):
+        words = ["pri", "able", "prime", "ought"]
+        # an INT8 key, as q1's flags: the fused tier takes INT32 keys only, so the op tier groups
+        return Table([icol(rng.integers(0, 5, n), dt.INT8), fcol(rng.uniform(0, 9, n).round(2)),
+                      icol(rng.integers(0, 100, n)),
+                      Column.from_pylist([words[i] for i in rng.integers(0, 4, n)], dt.STRING)],
+                     ["k", "x", "d", "s"])
+
+    def _frame(self, t):
+        return pd.DataFrame({"k": np.asarray(t.column("k").data), "d": np.asarray(t.column("d").data),
+                             "x": np.asarray(t.column("x").data).view(np.float64), "s": t.column("s").to_pylist()})
+
+    @pytest.mark.parametrize("shape,deferrable,moved", [
+        # the Filter's rows reach the Aggregate through one jitted Project
+        ("project_between", True, {"deferred": 1, "compacted": 0}),
+        # ... through nothing at all
+        ("filter_under_aggregate", True, {"deferred": 1, "compacted": 0}),
+        # ... through two Projects, a STRING handed on as it is
+        ("two_projects_and_a_string_reference", True, {"deferred": 1, "compacted": 0}),
+        # a Project with an eager (STRING-reading) tree between: compaction stays
+        ("eager_project_between", False, {"deferred": 0, "compacted": 1}),
+        # a Filter read by two stages (a shared subtree): compaction stays
+        ("filter_read_twice", False, {"deferred": 0, "compacted": 1}),
+        # a Project between that another stage reads too: compaction stays
+        ("project_read_twice", False, {"deferred": 0, "compacted": 1}),
+        # a Filter under a Join, under a Sort: no Aggregate reads it
+        ("filter_under_join", False, {"deferred": 0, "compacted": 1}),
+        ("filter_under_sort", False, {"deferred": 0, "compacted": 1}),
+    ])
+    def test_only_a_filter_whose_sole_readers_lead_to_an_aggregate_defers(self, rng, shape, deferrable, moved):
+        t = self._t(rng)
+        scan = P.Scan("t")
+        flt = P.Filter(scan, P.pcol("d") < P.plit(np.int32(80)))
+        aggs = (P.AggSpec("x", "sum", "sx"), P.AggSpec(None, "count_all", "n"))
+        if shape == "project_between":
+            plan = P.Aggregate(P.Project(flt, (("k", P.pcol("k")), ("x", P.pcol("x") * P.plit(2.0)))), keys=("k",), aggs=aggs)
+        elif shape == "filter_under_aggregate":
+            plan = P.Aggregate(flt, keys=("k",), aggs=aggs)
+        elif shape == "two_projects_and_a_string_reference":
+            inner = P.Project(flt, (("k", P.pcol("k")), ("s", P.pcol("s")), ("x", P.pcol("x") + P.plit(1.0))))
+            plan = P.Aggregate(P.Project(inner, (("s", P.pcol("s")), ("k", P.pcol("k")), ("x", P.pcol("x") * P.plit(2.0)))),
+                               keys=("s", "k"), aggs=aggs)
+        elif shape == "eager_project_between":
+            proj = P.Project(flt, (("k", P.pcol("k")), ("x", P.pcol("x")), ("p", P.plike(P.pcol("s"), "pri%"))))
+            plan = P.Aggregate(proj, keys=("k", "p"), aggs=aggs)
+        elif shape == "filter_read_twice":
+            left = P.Aggregate(flt, keys=("k",), aggs=aggs)
+            right = P.Project(P.Aggregate(flt, keys=("k",), aggs=(P.AggSpec("d", "max", "hi"),)),
+                              (("k2", P.pcol("k")), ("hi", P.pcol("hi"))))
+            plan = P.Join(left, right, on=(("k", "k2"),), how="inner")
+        elif shape == "project_read_twice":
+            proj = P.Project(flt, (("k", P.pcol("k")), ("x", P.pcol("x") * P.plit(2.0)), ("d", P.pcol("d"))))
+            left = P.Aggregate(proj, keys=("k",), aggs=aggs)
+            right = P.Project(P.Sort(proj, (("d", True),)), (("k2", P.pcol("k")), ("d", P.pcol("d"))))
+            plan = P.Join(left, right, on=(("k", "k2"),), how="semi")  # every group has a match
+        elif shape == "filter_under_join":
+            dim = P.Project(P.Aggregate(scan, keys=("k",), aggs=()), (("k2", P.pcol("k")),))
+            plan = P.Aggregate(P.Join(flt, dim, on=(("k", "k2"),), how="inner"), keys=("k",), aggs=aggs)
+        else:
+            plan = P.Sort(flt, (("d", True), ("k", True)))
+        cp = P.compile_ir(plan, {"t": t}, name=shape)
+        filters = [s for s in cp.stages if s.kind == "filter"]
+        assert len(filters) == 1 and filters[0].deferrable is deferrable
+        assert P.verify_estimates(cp) == []
+        before = _filter_counters()
+        out = cp()
+        assert _filters_moved(before) == moved
+        df = self._frame(t)
+        df = df[df.d < 80]
+        if shape in ("project_between", "two_projects_and_a_string_reference", "project_read_twice"):
+            df = df.assign(x=(df.x + (1.0 if shape.startswith("two") else 0.0)) * 2.0)
+        if shape == "filter_under_sort":
+            assert out.num_rows == len(df)
+            return
+        if shape == "eager_project_between":
+            keys = ["k", "p"]
+            df = df.assign(p=df.s.str.startswith("pri"))
+        else:
+            keys = ["s", "k"] if shape.startswith("two") else ["k"]
+        want = df.groupby(keys).agg(sx=("x", "sum"), n=("x", "size")).reset_index().sort_values(keys)
+        order = np.lexsort([np.asarray(out.column(k).data) if k != "s" else np.array(out.column(k).to_pylist())
+                            for k in reversed(keys)])
+        np.testing.assert_array_equal(np.asarray(out.column("n").data)[order], want.n.to_numpy())
+        np.testing.assert_allclose(np.asarray(out.column("sx").data).view(np.float64)[order], want.sx.to_numpy(), rtol=1e-12)
+
+    @pytest.mark.parametrize("keys", [(), ("k",)])
+    @pytest.mark.parametrize("how_many", ["no_row_passes", "one_row_passes"])
+    def test_a_global_aggregate_over_an_all_false_mask_yields_its_one_row(self, rng, how_many, keys, monkeypatch):
+        """The mask keeps nothing: below one half, so the stage compacts and
+        the aggregate sees the empty table — and where the threshold is
+        lowered to let the all-false mask through, it answers the same."""
+        import spark_rapids_jni_tpu.plan.compiler as pc
+
+        t = self._t(rng)
+        cut = -1 if how_many == "no_row_passes" else int(np.asarray(t.column("d").data).min())
+        plan = P.Aggregate(P.Filter(P.Scan("t"), P.pcol("d") <= P.plit(np.int32(cut))), keys=keys, aggs=(
+            P.AggSpec("x", "sum", "sx"), P.AggSpec("x", "mean", "mx"), P.AggSpec("d", "max", "hi"),
+            P.AggSpec("x", "count", "c"), P.AggSpec(None, "count_all", "n"), P.AggSpec("d", "nunique", "u")))
+        cp = P.compile_ir(plan, {"t": t}, name="global")
+        assert cp.stages[1].deferrable
+        compacted = cp()
+        monkeypatch.setattr(pc, "_DEFER_MIN_KEEP", 0.0)  # the mask rides whatever it keeps
+        before = _filter_counters()
+        deferred = cp()
+        assert _filters_moved(before) == {"deferred": 1, "compacted": 0}
+        kept = int((np.asarray(t.column("d").data) <= cut).sum())
+        assert compacted.num_rows == deferred.num_rows == (1 if not keys or kept else 0)
+        for name in compacted.names:
+            a, b = compacted.column(name), deferred.column(name)
+            assert a.dtype == b.dtype == cp.schema[name]
+            np.testing.assert_array_equal(np.asarray(a.valid_mask()), np.asarray(b.valid_mask()))
+            np.testing.assert_array_equal(np.asarray(a.data), np.asarray(b.data))
+        if not keys:
+            assert int(deferred.column("n").data[0]) == kept == int(deferred.column("c").data[0])
+            assert bool(deferred.column("sx").valid_mask()[0]) is (kept > 0)
+
+    def test_a_deferred_plan_on_two_threads_agrees(self, q1_cell):
+        """Nothing of a run is kept on the stages: the mask rides in the run's own tables."""
+        import threading
+
+        q1, table, frames = q1_cell(7)
+        cp = P.compile_ir(q1.plan(P), {"lineitem": table}, name="q1")
+        outs = [None, None]
+
+        def run(i):
+            outs[i] = cp()
+
+        threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        for out in outs:
+            _assert_q1_answer(q1, out, frames, q1.CUTOFF)

@@ -572,3 +572,25 @@ def test_a_mesh_projects_outputs_lie_as_its_inputs_do(kind):
     # and the plan answers with the rows it was given
     out = cp()
     assert np.asarray(out.column("k").data).tolist() == list(range(n))
+
+
+@pytest.mark.parametrize("world", [1, 2])
+def test_q95s_local_filters_compact_and_its_mesh_filter_keeps_present(world):
+    """ISSUE 35: the one-chip deferral is the op tier's alone. q95's local
+    Filters feed joins (every run of one is counted ``compacted``); the
+    Filter over the mesh never compacted and is counted as neither."""
+    from spark_rapids_jni_tpu.plan.compiler import _FilterExec, _MeshFilterExec
+
+    tables = _tables(WEB.host_tables(CONFIG, 7, ROWS))
+    plan = P.insert_exchanges(Q95.plan(P), max(world, 2), sharded=SHARDED)
+    mesh = P.MeshBinding(_mesh(world), SHARDED) if world > 1 else None
+    cp = P.compile_ir(plan, tables, name="q95-filters", mesh=mesh)
+    filters = [s for s in cp.stages if isinstance(s, _FilterExec)]
+    local = [s for s in filters if type(s) is _FilterExec]
+    assert not any(s.deferrable for s in filters)
+    assert len(filters) - len(local) == (1 if world > 1 else 0)  # ``wh_lo <> wh_hi`` over the mesh
+    assert all(isinstance(s, _MeshFilterExec) for s in filters if s not in local)
+    reg = metrics.registry()
+    was = reg.value("plan.filter.deferred"), reg.value("plan.filter.compacted")
+    assert _answer(_run(cp))[0] > 0
+    assert (reg.value("plan.filter.deferred") - was[0], reg.value("plan.filter.compacted") - was[1]) == (0, len(local))

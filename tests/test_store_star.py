@@ -22,6 +22,7 @@ from spark_rapids_jni_tpu import serve
 from spark_rapids_jni_tpu.columnar import Column, Table
 from spark_rapids_jni_tpu.columnar import dtype as dt
 from spark_rapids_jni_tpu.columnar.dtype import TypeId
+from spark_rapids_jni_tpu.utils import metrics
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench")
 SEEDS = (7, 3200000033, 2200007920)
@@ -138,3 +139,19 @@ def test_the_star_equals_its_pandas_reference(star, name, seed):
     got, nulls = _host(out, q.EXACT)
     numbers = COMPARE.table_numbers(got, nulls, want, q.EXACT)
     assert numbers["shape_diff"] == 0 and numbers["exact_diff"] == 0 and numbers["rel_gap"] <= 1e-9, numbers
+
+
+@pytest.mark.parametrize("name", ["q3", "q55"])
+def test_the_stars_filters_feed_joins_and_keep_their_compaction(star, name):
+    """ISSUE 35: a Filter hands its mask on only where an Aggregate reads it
+    through Projects alone. The star's filters are dimension filters under
+    Joins: every one compacts, and the STRING-keyed group-by runs unmasked."""
+    q = QUERIES[name]
+    tables, _ = star(SEEDS[0])
+    cp = P.compile_ir(q.plan(P), {t: tables[t] for t in q.TABLES}, name=name)
+    filters = [s for s in cp.stages if s.kind == "filter"]
+    assert len(filters) == 2 and not any(s.deferrable for s in filters)
+    reg = metrics.registry()
+    was = reg.value("plan.filter.deferred"), reg.value("plan.filter.compacted")
+    cp()
+    assert (reg.value("plan.filter.deferred") - was[0], reg.value("plan.filter.compacted") - was[1]) == (0, 2)
