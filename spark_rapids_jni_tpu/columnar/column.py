@@ -117,8 +117,10 @@ class Column:
             if len(self) == 0:
                 ml = 0
             else:
+                from ..utils import tracing  # not at import: utils reaches memgov
+
                 offs = self.offsets
-                ml = int(jnp.max(offs[1:] - offs[:-1]))
+                ml = int(tracing.device_wait(jnp.max(offs[1:] - offs[:-1]), "max_char_len"))
             self._max_char_len = ml
         return ml
 

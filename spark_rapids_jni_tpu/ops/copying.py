@@ -22,6 +22,7 @@ from jax import lax
 from ..columnar import Column, Table
 from ..columnar import dtype as dt
 from ..columnar.dtype import TypeId
+from ..utils import tracing
 
 __all__ = ["gather", "gather_column", "apply_boolean_mask", "concatenate", "slice_table"]
 
@@ -77,7 +78,7 @@ def gather_column(col: Column, idx: jnp.ndarray, check_bounds: bool = False) -> 
         new_offs = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32), jnp.cumsum(lens, dtype=jnp.int32)]
         )
-        total = int(new_offs[-1])  # host sync: chars allocation
+        total = int(tracing.device_wait(new_offs[-1], "string_chars"))  # host sync: chars allocation
         if total == 0:
             chars = jnp.zeros((0,), jnp.uint8)
         else:
@@ -92,7 +93,7 @@ def gather_column(col: Column, idx: jnp.ndarray, check_bounds: bool = False) -> 
         new_offs = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32), jnp.cumsum(lens, dtype=jnp.int32)]
         )
-        total = int(new_offs[-1])
+        total = int(tracing.device_wait(new_offs[-1], "list_children"))
         j = jnp.arange(total, dtype=jnp.int32)
         row_of = jnp.searchsorted(new_offs, j, side="right").astype(jnp.int32) - 1
         src = offs[safe[row_of]] + (j - new_offs[row_of])
@@ -113,7 +114,8 @@ def apply_boolean_mask(table: Table, mask) -> Table:
             m = m & mask.validity
     else:
         m = jnp.asarray(mask, bool)
-    idx = jnp.nonzero(m)[0].astype(jnp.int32)  # host sync on size
+    # ``jnp.nonzero`` reads its own size: the wait for the mask comes first, under its own name
+    idx = jnp.nonzero(tracing.device_wait(m, "mask_nonzero"))[0].astype(jnp.int32)  # host sync on size
     return gather(table, idx)
 
 

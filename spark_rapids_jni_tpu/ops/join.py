@@ -110,7 +110,7 @@ def _expand_rows(counts: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.nda
     slot_within_row, cum) after the one host sync every join pays for
     the output allocation size."""
     cum = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
-    total = int(cum[-1])  # host sync: output size
+    total = int(tracing.device_wait(cum[-1], "join_size"))  # host sync: output size
     if total == 0:
         z = jnp.zeros((0,), jnp.int32)
         return z, z, cum
@@ -282,7 +282,7 @@ def semi_anti_gather_map(
         hi = jnp.searchsorted(rid_sorted, probe_id, side="right")
         keep = (hi > lo) if how == "semi" else (hi == lo)
     with tracing.span("join.expand") as sp:
-        total = int(jnp.sum(keep))  # host sync: output size
+        total = int(tracing.device_wait(jnp.sum(keep), "join_size"))  # host sync: output size
         sp.annotate(rows_out=total)
         out = jnp.nonzero(keep, size=total)[0].astype(jnp.int32)
     _count_join(left_keys.num_rows, total)

@@ -42,7 +42,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils import knobs
+from ..utils import knobs, tracing
 
 _VMEM = pltpu.VMEM
 
@@ -616,7 +616,8 @@ def build_paged_table(
     )
     nm, n_pages, c_max = (
         int(x)
-        for x in np.asarray(jnp.stack([nm_dev, page_first[-1], jnp.max(pages_b)]))
+        for x in np.asarray(tracing.device_wait(
+            jnp.stack([nm_dev, page_first[-1], jnp.max(pages_b)]), "paged_table"))
     )
     if nm == 0 or n_pages == 0 or n_pages > _PJ_MAX_PAGES:
         return None
@@ -692,6 +693,7 @@ def _probe_kernel(fp_ref, cl_ref, *rest, n_pages: int, nlimb: int, blk: int):
     o_ref[...] += upd
 
 
+@tracing.launches
 @functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
 def _probe_impl(
     u, lvalid, limbs, meta, num_buckets: int, n_pages: int, nlimb: int,

@@ -448,6 +448,7 @@ def _jit_assemble(fixed32, var32, row_offsets, total_bytes: int, min_row: int):
     return assemble_rows((fixed32, var32), sizes, row_offsets, total_bytes, min_row)
 
 
+@tracing.launches
 @partial(jax.jit, static_argnums=(0, 3, 4, 5))
 def _jit_encode_strings_fused(
     layout: RowLayout,
@@ -584,10 +585,14 @@ def _to_rows_strings(
     return blob
 
 
+# the blob's eager bitcast is a program of its own name
+_launch_bitcast = tracing.launches(lax.bitcast_convert_type)
+
+
 def _wrap_batch_as_list_column(
     blob: jnp.ndarray, rel_offsets: jnp.ndarray, uniform_stride: int = 0
 ) -> Column:
-    child = Column(dt.INT8, data=lax.bitcast_convert_type(blob, jnp.int8))
+    child = Column(dt.INT8, data=_launch_bitcast(blob, jnp.int8))
     col = Column(dt.LIST, offsets=rel_offsets.astype(jnp.int32), child=child)
     if uniform_stride:
         # producer-known constant row stride: lets the decoder skip the
@@ -701,11 +706,13 @@ def convert_to_rows(table: Table) -> List[Column]:
     size_waits = 1
     with tracing.span("rowconv.sizes", rows=n, string_cols=len(var_offs)) as sp:
         sizes_dev, offsets_dev, stats = _jit_row_size_stats(layout, var_offs)
-        stats = [int(v) for v in np.asarray(stats)]  # host sync: the one wait
+        # host sync: the one wait
+        stats = [int(v) for v in np.asarray(tracing.device_wait(stats, "row_sizes"))]
         total, max_size, maxlens = stats[0], stats[1], tuple(stats[2:])
         single = total <= MAX_BATCH_BYTES
         if not single:
-            row_sizes = np.asarray(sizes_dev)  # host sync: full batch metadata
+            # host sync: full batch metadata
+            row_sizes = np.asarray(tracing.device_wait(sizes_dev, "row_sizes_full"))
             size_waits = 2
         sp.annotate(total_bytes=total, max_row=max_size)
 
@@ -735,6 +742,7 @@ def convert_to_rows(table: Table) -> List[Column]:
     return out
 
 
+@tracing.launches
 @partial(jax.jit, static_argnums=(0,))
 def _jit_row_size_stats(layout: RowLayout, var_offsets: Tuple[jnp.ndarray, ...]):
     """([N] int64 8-aligned row sizes ON DEVICE, [N+1] offsets, [2 + K]
@@ -765,7 +773,7 @@ def _slice_column(col: Column, rs: int, re: int) -> Column:
     if col.dtype.id == TypeId.STRING:
         offs = col.offsets[rs : re + 1]
         base, end = offs[0], offs[-1]
-        chars = lax.dynamic_slice_in_dim(col.chars, base, int(end - base))
+        chars = lax.dynamic_slice_in_dim(col.chars, base, int(tracing.device_wait(end - base, "string_chars")))
         return Column(col.dtype, validity=v, offsets=offs - base, chars=chars)
     return Column(col.dtype, data=col.data[rs:re], validity=v)
 
@@ -1376,6 +1384,7 @@ def _jit_gather_fixed(blob, starts, fixed_end: int, n: int):
     return _jit_gather_fixed_impl(blob, starts, jnp.arange(fixed_end, dtype=jnp.int64))
 
 
+@tracing.launches
 @partial(jax.jit, static_argnums=(0, 2, 3))
 def _jit_to_rows_fixed_static(layout: RowLayout, cols: Tuple[Column, ...],
                               rs: int, n: int):
@@ -1391,6 +1400,7 @@ def _jit_to_rows_fixed_static(layout: RowLayout, cols: Tuple[Column, ...],
     return _to_rows_fixed(layout, sliced, n)
 
 
+@tracing.launches
 @partial(jax.jit, static_argnums=(0, 3))
 def _jit_to_rows_fixed_sliced(layout: RowLayout, cols: Tuple[Column, ...],
                               rs, n: int):

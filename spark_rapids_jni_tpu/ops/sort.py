@@ -38,6 +38,7 @@ from .copying import gather
 __all__ = ["sorted_order", "sort_by_key", "string_key_lanes"]
 
 
+@tracing.launches
 @functools.partial(jax.jit, static_argnames=("lanes",))
 def _string_lanes(offsets, chars, *, lanes: int) -> Tuple[jnp.ndarray, ...]:
     """``lanes`` big-endian u64 lanes of each row's bytes (shorter rows
@@ -135,6 +136,10 @@ def sorted_order(
 _LEXSORT_LANES = 12
 
 
+# ``jnp.lexsort`` is a program of its own name when called eagerly
+_launch_lexsort = tracing.launches(jnp.lexsort)
+
+
 def _lexsort(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
     """Stable order of major-first ``lanes``."""
     order = None
@@ -143,12 +148,15 @@ def _lexsort(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
         if order is not None:
             chunk = [k[order] for k in chunk]
         # lexsort: LAST key is primary -> reverse to make the major lane dominate
-        step = jnp.lexsort(tuple(reversed(chunk)))
+        step = _launch_lexsort(tuple(reversed(chunk)))
         order = step if order is None else order[step]
     return order
 
 
 @op_boundary("sort_by_key")
 def sort_by_key(values: Table, keys: Table, ascending=None, nulls_first=None) -> Table:
+    # what the sort waits for is what its caller left in the device's queue (q1: the seven
+    # aggregate programs behind ``values``): named here, before the operator reads its input
+    tracing.device_wait((keys, values), "sort_input")
     order = sorted_order(keys, ascending, nulls_first)
     return gather(values, order)

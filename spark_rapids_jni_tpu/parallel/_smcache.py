@@ -24,6 +24,8 @@ from typing import Callable
 
 import jax
 
+from ..utils import tracing
+
 # every distributed site imports the symbol from here so the memo and
 # the primitive stay in one place
 shard_map = jax.shard_map
@@ -39,7 +41,8 @@ def cached_sm(key, build: Callable):
     if f is None:
         while len(_CACHE) >= _MAX_ENTRIES:
             _CACHE.popitem(last=False)
-        f = _CACHE[key] = build()
+        # every mesh program is launched from here: ONE place for its ``device.launch`` event
+        f = _CACHE[key] = tracing.launches(build())
     else:
         _CACHE.move_to_end(key)
     return f

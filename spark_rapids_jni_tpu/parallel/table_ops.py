@@ -675,7 +675,7 @@ def _join_once(
         outs = f(*in_lanes)
     _count_exchange(left.num_rows, sum(int(a.dtype.itemsize) for a in l_lanes))
     _count_exchange(right.num_rows, sum(int(a.dtype.itemsize) for a in r_lanes), programs=0)
-    ovf = bool(np.asarray(outs[-1]).any())
+    ovf = bool(np.asarray(tracing.device_wait(outs[-1], "overflow_flags")).any())
     keep = np.asarray(outs[-3])
     sel = jnp.asarray(np.flatnonzero(keep))
 
@@ -905,7 +905,8 @@ def exchange_sharded(st: ShardedTable, key_cols: Sequence[str]) -> ShardedTable:
     )
     with tracing.span("exchange.table", keys=list(key_cols), parts=n_parts) as sp:
         dest, sent = count(st.present, *key_in)
-        sent = np.asarray(sent)  # the one wait: n_parts x n_parts counts; the rows that entered are their sum
+        # the one wait: n_parts x n_parts counts; the rows that entered are their sum
+        sent = np.asarray(tracing.device_wait(sent, "exchange_counts"))
         rows_in, max_bucket = int(sent.sum()), int(sent.max())
         capacity = _counted_capacity(max_bucket, per_shard)
         slots_out = n_parts * n_parts * capacity
@@ -1146,7 +1147,8 @@ def gather_table(st: ShardedTable) -> Table:
     from .mesh import replicated
 
     with tracing.span("exchange.gather", slots=st.num_rows, parts=st.n_parts, cols=st.table.num_columns) as sp:
-        present, flags = jax.device_get((st.present, [flag for _keys, _cap, flag in st.overflow]))
+        present, flags = jax.device_get(tracing.device_wait(
+            (st.present, [flag for _keys, _cap, flag in st.overflow]), "overflow_flags"))
         for (keys, cap, _flag), flag in zip(st.overflow, flags):
             if flag.any():
                 _exchange_counter("overflows").inc()

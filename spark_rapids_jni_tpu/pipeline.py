@@ -35,7 +35,7 @@ from .columnar import Column, Table
 from .columnar import dtype as dt
 from .ops import bitutils
 from .ops.expressions import Expression
-from .utils import deadline, metrics
+from .utils import deadline, metrics, tracing
 from .utils.dispatch import op_boundary
 
 __all__ = ["Agg", "GroupKey", "JoinSpec", "PlanSpec", "CompiledPipeline", "compile_plan"]
@@ -138,7 +138,7 @@ class CompiledPipeline:
 
     def __init__(self, plan: PlanSpec):
         self.plan = plan
-        self._fn = jax.jit(self._trace)
+        self._fn = tracing.launches(jax.jit(self._trace))
         self._build_handles: Dict[str, object] = {}
         self._build_finalizer = None
         metrics.counter("pipeline.compiles").inc()

@@ -246,7 +246,7 @@ class _StageProgram:
                 self.slots.append(("eager", low, want))
         self.refs = tuple(sorted(refs))
         self.n_eager = sum(1 for s in self.slots if s[0] == "eager")
-        self._program = jax.jit(self._body, static_argnums=0)
+        self._program = tracing.launches(jax.jit(self._body, static_argnums=0))
 
     def _body(self, rows: int, cols, present):
         if cols:
@@ -308,6 +308,7 @@ def _to_float64(col: Column) -> Column:
     raise PlanError(f"cannot normalize an aggregate over {col.dtype!r}")
 
 
+@tracing.launches
 @jax.jit
 def _to_float64_program(cols):
     """``_to_float64`` of every column of one aggregate stage, as one program."""
@@ -455,7 +456,7 @@ class _FilterExec(_Exec):
         if self.deferrable:
             # the stage's one wait, where ``jnp.nonzero`` would read its size:
             # the mask's own count says whether compacting pays
-            kept = int(jnp.sum(keep))
+            kept = int(tracing.device_wait(jnp.sum(keep), "mask_popcount"))
             if kept >= _DEFER_MIN_KEEP * t.num_rows:
                 _durable("plan.filter.deferred").inc()
                 tracing.annotate(deferred=True, kept=kept)

@@ -464,6 +464,8 @@ def _write_table(table, framed: bool = None):
     # every device array to the host first (the wait for the kernel
     # that produced it included), then the list of wire pieces
     with tracing.span("sidecar.worker.d2h") as sp:
+        # ONE wait for all the table's arrays: what is left of the span is the copy
+        tracing.device_wait(table, "result")
         host = []
         for col in table.columns:
             d = col.dtype
@@ -512,6 +514,8 @@ def _op_convert_to_rows(payload: bytes) -> ReplyPieces:
     # device to host (the wait for the transcode kernel included), then
     # the list of wire pieces over those host arrays: one span each
     with tracing.span("sidecar.worker.d2h") as sp:
+        # ONE wait for the encode: what is left of the span is the copy
+        tracing.device_wait(batches, "result")
         host = [
             (
                 len(col),

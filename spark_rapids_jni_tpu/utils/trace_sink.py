@@ -302,14 +302,24 @@ def reset_for_tests() -> None:
 # ---------------------------------------------------------------------------
 
 
-def _fmt_span(s: dict) -> str:
+def _fmt_span(s: dict, parent: Optional[dict] = None) -> str:
     dur = s.get("dur_us", 0.0)
     dur_txt = f"{dur / 1e3:.2f}ms" if dur < 1e6 else f"{dur / 1e6:.3f}s"
-    ann = s.get("annotations") or {}
+    ann = dict(s.get("annotations") or {})
+    name = s.get("name")
+    if name == "device.wait":
+        # the host's wait for the device, by its site, and the share of
+        # the span that asked for it: "it sat 843 ms in exchange.table,
+        # waiting for what?" answered without a profiler
+        name = f"device.wait({ann.pop('what', '?')})"
+        if parent is not None and parent.get("dur_us"):
+            dur_txt += f" = {100.0 * dur / parent['dur_us']:.1f}% of {parent.get('name')}"
+    elif name == "device.launch":
+        name = f"device.launch({ann.pop('program', '?')})"
     ann_txt = "".join(f" {k}={v}" for k, v in sorted(ann.items()))
     status = s.get("status", "ok")
     status_txt = "" if status == "ok" else f" [{status}]"
-    return f"{s.get('name')} {dur_txt}{status_txt} (pid {s.get('pid')}){ann_txt}"
+    return f"{name} {dur_txt}{status_txt} (pid {s.get('pid')}){ann_txt}"
 
 
 def render_trace(rec: dict) -> str:
@@ -347,11 +357,11 @@ def render_trace(rec: dict) -> str:
         lines.append(f"  ({rec['dropped_spans']} spans dropped at the "
                      "in-memory cap; the span log has them all)")
 
-    def walk(s: dict, indent: int) -> None:
-        lines.append("  " * indent + "- " + _fmt_span(s))
+    def walk(s: dict, indent: int, parent: Optional[dict] = None) -> None:
+        lines.append("  " * indent + "- " + _fmt_span(s, parent))
         for c in sorted(children.get(s["span"], ()),
                         key=lambda x: x.get("ts", 0.0)):
-            walk(c, indent + 1)
+            walk(c, indent + 1, s)
 
     for r in sorted(roots, key=lambda x: x.get("ts", 0.0)):
         walk(r, 1)

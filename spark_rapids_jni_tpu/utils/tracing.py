@@ -64,6 +64,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import os
 import random
 import struct
@@ -88,6 +89,8 @@ __all__ = [
     "op_span",
     "closed_span",
     "event_span",
+    "device_wait",
+    "launches",
     "annotate",
     "start_trace",
     "current_context",
@@ -390,6 +393,82 @@ def closed_span(name: str, dur_s: float, t_wall: Optional[float] = None,
     sp = Span(ctx, name, parent.span_id, parent.depth + 1, annotations)
     sp.t_wall = time.time() - dur_s if t_wall is None else t_wall
     _record_and_emit(ctx, sp._record(max(float(dur_s), 0.0)))
+
+
+# ---------------------------------------------------------------------------
+# device time under the span that asked for it (ISSUE 36)
+# ---------------------------------------------------------------------------
+#
+# A span times the HOST, and since the operators launch whole programs the
+# host mostly dispatches and waits. Two records put a name on the device's
+# share, both children of whatever span is open, both one boolean read and
+# nothing else with tracing off:
+#
+# - ``device.wait`` (a span): the host's wait for the device at a sync
+#   site, apart from the transfer or ``int()`` that follows it;
+# - ``device.launch`` (an event): one where the system launches a program
+#   it built itself, named as the device trace names that program less
+#   its ``jit_`` prefix, so a reader matches the k-th launch of a name
+#   with the k-th program of that name and hands the program's device
+#   time to the launching span's layer (bench/benchlib/attribution.py).
+
+
+def device_wait(x, what: str):
+    """Wait for the device arrays of ``x`` inside a ``device.wait`` span
+    (annotation ``what``: a short fixed label of the site) and return
+    ``x``; the site's own ``int()`` / ``np.asarray`` / ``device_get``
+    then follows under the parent span and finds the value ready. With
+    tracing off, or with no sampled trace active, ``x`` comes back at
+    once and the site blocks where it always did: no
+    ``block_until_ready``, no clock read, no allocation."""
+    if not _enabled:
+        return x
+    a = _current.get()
+    if a is None or not a[0].sampled:
+        return x
+    with span("device.wait", what=what):
+        jax.block_until_ready(x)
+    return x
+
+
+def _note_launch(program: str, args, kwargs) -> None:
+    a = _current.get()
+    if a is None or not a[0].sampled:
+        return
+    for leaf in jax.tree_util.tree_leaves((args, kwargs)):
+        if isinstance(leaf, jax.core.Tracer):
+            return  # traced into an enclosing program: nothing launches here
+    event_span("device.launch", program=program)
+
+
+def launches(fn):
+    """``fn`` (a ``jax.jit`` object, or a ``jax.numpy`` / ``jax.lax``
+    function that launches one program of its own name when called
+    eagerly) with a ``device.launch`` event in front of every real call.
+    Applied OUTSIDE ``jax.jit``: no module name, no cache key and no
+    compiled program changes. ``program`` is the function's own
+    ``__name__``, never typed by hand, so ``jit_`` + ``program`` is the
+    module the call lowers to and what the device trace calls the
+    program, less its fingerprint. No event while the call is being
+    traced into an enclosing program (an argument is a tracer). The
+    jitted object's ``lower``, ``trace``, ``eval_shape``, ``clear_cache``
+    and ``_cache_size`` stay reachable, and ``__wrapped__`` is ``fn``. A
+    callable with no ``__name__`` has no program a trace could name: it
+    comes back as it is."""
+    program = getattr(fn, "__name__", None)
+    if program is None:
+        return fn
+
+    @functools.wraps(fn)
+    def launch(*args, **kwargs):
+        if _enabled:
+            _note_launch(program, args, kwargs)
+        return fn(*args, **kwargs)
+
+    for attr in ("lower", "trace", "eval_shape", "clear_cache", "_cache_size"):
+        if hasattr(fn, attr):
+            setattr(launch, attr, getattr(fn, attr))
+    return launch
 
 
 def annotate(**kw) -> None:

@@ -953,3 +953,308 @@ def test_plan_stage_self_time_on_a_three_deep_nest():
     assert read({"spans": spans, "requests": [object()]}) == pytest.approx(41.0)
     again = [dict(s, span=s["span"] + "2", parent=s["parent"] and s["parent"] + "2") for s in spans]
     assert read({"spans": spans + again, "requests": [object(), object()]}) == pytest.approx(41.0)
+
+
+# ---------------------------------------------------------------------------
+# device time under the span that asked for it (ISSUE 36): a ``device.wait``
+# span at every host sync, a ``device.launch`` event at every program the
+# system builds itself
+# ---------------------------------------------------------------------------
+
+
+def _under(spans, name, key):
+    """[(annotation ``key``, parent span's name)] of the spans called ``name``, in start order."""
+    by_id = {s["span"]: s["name"] for s in spans}
+    hits = sorted((s for s in spans if s["name"] == name), key=lambda s: s["ts"])
+    return [(s["annotations"][key], by_id.get(s["parent"])) for s in hits]
+
+
+def _waits(spans):
+    return _under(spans, "device.wait", "what")
+
+
+def _launches(spans):
+    return _under(spans, "device.launch", "program")
+
+
+def _traced(fn, *args, **kwargs):
+    """(spans, result) of ``fn(*args)`` under a trace of its own; compiled by an untraced call first."""
+    fn(*args, **kwargs)
+    trace_sink.reset_for_tests()
+    with tracing.enabled():
+        qt = tracing.start_trace("device.test")
+        with qt.activate():
+            out = fn(*args, **kwargs)
+        qt.finish("ok")
+    return trace_sink.recorder().last(1)[0]["spans"], out
+
+
+@pytest.mark.parametrize("what,parent", [
+    ("mask_popcount", "plan.filter"),      # a Filter that may defer reads its mask's count
+    ("sort_order", "groupby.segments"),    # the phase's dispatch stalls behind the sort: named before it starts
+    ("group_count", "groupby.segments"),   # the group-by's one read of a value
+    ("sort_input", "op.sort_by_key"),      # the Sort waits for what the aggregates left in the queue
+])
+def test_each_sync_site_of_a_q1_is_one_device_wait_under_its_span(q1_spans, what, parent):
+    assert _waits(q1_spans).count((what, parent)) == 1
+    assert len(_waits(q1_spans)) == 4  # and the request has no other
+
+
+def test_a_q1s_programs_are_launched_under_the_spans_that_built_them(q1_spans):
+    assert _launches(q1_spans) == [
+        ("_body", "plan.filter"), ("_body", "plan.project"), ("lexsort", "groupby.sort"),
+        ("_f64_sum_mean", "groupby.agg.sum"), ("_f64_sum_mean", "groupby.agg.sum"),
+        ("_f64_sum_mean", "groupby.agg.mean"), ("lexsort", "op.sort_by_key")]
+
+
+def test_a_compacting_filter_waits_for_its_mask_before_nonzero_reads_its_size():
+    spans = _spans_of_a_q1_shaped_query(cutoff=260)  # keeps a tenth: it compacts
+    assert ("mask_popcount", "plan.filter") in _waits(spans) and ("mask_nonzero", "plan.filter") in _waits(spans)
+
+
+@pytest.mark.parametrize("what,parent,count", [
+    ("group_count", "join.factorize", 1),   # the XLA tier's dense ids
+    ("join_size", "join.expand", 1),        # the output-size wait
+    ("string_chars", "join.gather", 1),     # the brand's characters through the map
+    ("max_char_len", "groupby.sort", 1),    # a gathered STRING key has lost its memo
+    ("sort_order", "groupby.segments", 1),
+    ("group_count", "groupby.segments", 1),
+    ("string_chars", "groupby.keys", 1),
+    ("sort_input", "op.sort_by_key", 1),
+    ("string_chars", "op.sort_by_key", 1),
+])
+def test_each_sync_site_of_a_join_and_a_string_key_is_one_device_wait(star_spans, what, parent, count):
+    spans, _, _ = star_spans
+    assert _waits(spans).count((what, parent)) == count, _waits(spans)
+
+
+def test_a_star_requests_waits_all_have_a_site_and_its_programs_a_layer(star_spans):
+    spans, _, _ = star_spans
+    assert len(_waits(spans)) == 10, _waits(spans)  # the nine above and the Sort's key's longest string
+    # the INT64 sum is eager pieces (no program of the system's own under ``groupby.agg.sum``); its
+    # normalisation to FLOAT64 is the aggregate stage's one program
+    assert _launches(spans) == [
+        ("lexsort", "join.factorize"), ("_string_lanes", "groupby.sort"), ("lexsort", "groupby.sort"),
+        ("_string_lanes", "groupby.segments"), ("_to_float64_program", "plan.aggregate"),
+        ("_string_lanes", "op.sort_by_key"), ("lexsort", "op.sort_by_key")]
+
+
+def test_the_paged_hash_join_waits_for_its_table_and_launches_its_probe(monkeypatch):
+    from spark_rapids_jni_tpu.ops import join as join_ops
+
+    monkeypatch.setenv("SRJT_PALLAS_INTERPRET", "1")
+    rng = np.random.default_rng(36)
+    left = Table([Column.from_numpy(rng.integers(0, 50, 600).astype(np.int32), dt.INT32)], ["k"])
+    right = Table([Column.from_numpy(np.arange(40, dtype=np.int32), dt.INT32),
+                   Column.from_numpy(np.arange(40, dtype=np.int64), dt.INT64)], ["k", "v"])
+    spans, out = _traced(join_ops.inner_join, left, right, on=["k"])
+    assert _one(spans, "join.probe")["annotations"]["tier"] == "pallas" and out.num_rows > 0
+    assert _waits(spans) == [("paged_table", "join.factorize"), ("join_size", "join.expand")]
+    assert _launches(spans) == [("_probe_impl", "join.probe")]
+
+
+@pytest.mark.parametrize("case,waits", [
+    ("one_batch", [("row_sizes", "rowconv.sizes")]),
+    ("scatter", [("row_sizes", "rowconv.sizes")]),
+])
+def test_convert_to_rows_with_strings_waits_once_and_launches_three_programs(rowconv_spans, case, waits):
+    spans, _ = rowconv_spans[case]
+    assert _waits(spans) == waits
+    want = [("_jit_row_size_stats", "rowconv.sizes"), ("bitcast_convert_type", "op.convert_to_rows")]
+    if case == "one_batch":  # the scatter form is eager pieces: no program of the system's own
+        want.insert(1, ("_jit_encode_strings_fused", "rowconv.encode"))
+    assert _launches(spans) == want
+
+
+def test_a_table_that_spans_batches_waits_for_its_sizes_twice_and_once_a_sliced_string(rowconv_spans):
+    spans, batches = rowconv_spans["several_batches"]
+    waits = _waits(spans)
+    assert waits[:2] == [("row_sizes", "rowconv.sizes"), ("row_sizes_full", "rowconv.sizes")]
+    assert set(waits[2:]) == {("string_chars", "op.convert_to_rows")}
+    assert len(waits[2:]) <= 2 * len(batches)  # two STRING columns a batch; a batch that is the whole column slices nothing
+    assert _launches(spans).count(("_jit_encode_strings_fused", "rowconv.encode")) == len(batches)
+
+
+def test_a_fixed_width_encode_waits_for_nothing_and_launches_the_encode_and_the_bitcast():
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    table = Table([Column.from_numpy(np.arange(50, dtype=np.int32), dt.INT32),
+                   Column.from_numpy(np.arange(50, dtype=np.int64), dt.INT64)], ["a", "b"])
+    spans, _ = _traced(rc.convert_to_rows, table)
+    assert _waits(spans) == []
+    assert _launches(spans) == [("_jit_to_rows_fixed_static", "rowconv.encode"),
+                                ("bitcast_convert_type", "op.convert_to_rows")]
+
+
+def test_a_sharded_exchange_and_its_gather_wait_once_each_and_launch_the_mesh_programs():
+    from spark_rapids_jni_tpu.parallel import table_ops
+    from spark_rapids_jni_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
+    keys = np.arange(4000, dtype=np.int64) * 7 + 1
+    table = Table([Column.from_numpy(keys, dt.INT64), Column.from_numpy(np.arange(4000, dtype=np.int64), dt.INT64)],
+                  ["k", "v"])
+
+    def there_and_back():
+        return table_ops.gather_table(table_ops.exchange_sharded(table_ops.shard_table(table, mesh), ["k"]))
+
+    spans, out = _traced(there_and_back)
+    assert out.num_rows == 4000
+    assert _waits(spans) == [("exchange_counts", "exchange.table"), ("overflow_flags", "exchange.gather")]
+    assert _launches(spans) == [("count_program", "exchange.table"), ("exchange_program", "exchange.table")]
+
+
+def _module_of(lowered) -> str:
+    import re
+
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+def _launch_case(case):
+    """(the wrapped program, its arguments, its keywords) of one of the programs the system wraps."""
+    from jax.sharding import PartitionSpec
+
+    from spark_rapids_jni_tpu.ops import aggregate, pallas_kernels, row_conversion as rc, sort
+    from spark_rapids_jni_tpu.parallel._smcache import cached_sm, shard_map
+    from spark_rapids_jni_tpu.parallel.mesh import make_mesh
+    from spark_rapids_jni_tpu.plan import compiler
+
+    n = 64
+    order, seg = jnp.arange(n, dtype=jnp.int32), jnp.zeros((n,), jnp.int32)
+    x = Column.from_numpy(np.arange(n, dtype=np.int32), dt.INT32)
+    if case == "f64_sum_mean":
+        return aggregate._f64_sum_mean, (jnp.arange(n, dtype=jnp.uint64), None, order, seg, None), {"num": 1, "how": "sum"}
+    if case == "lexsort":
+        return sort._launch_lexsort, ((order, seg),), {}
+    if case in ("string_lanes", "row_size_stats"):
+        strings = Column.from_pylist(["a", "bcd", "", "efghijklm"], dt.STRING)
+        if case == "string_lanes":
+            return sort._string_lanes, (strings.offsets, strings.chars), {"lanes": 2}
+        return rc._jit_row_size_stats, (rc.compute_row_layout([dt.INT32, dt.STRING]), (strings.offsets,)), {}
+    if case == "stage_program":
+        stage = compiler._StageProgram([(P.pcol("x") + P.plit(np.int32(1)), dt.INT32)], {"x": dt.INT32})
+        return stage._program, (n, (x,), None), {}
+    if case == "to_float64_program":
+        return compiler._to_float64_program, ((x,),), {}
+    if case in ("to_rows_fixed_static", "to_rows_fixed_sliced"):
+        return getattr(rc, "_jit_" + case), (rc.compute_row_layout([dt.INT32]), (x,), 0, n), {}
+    if case == "mesh_program":
+        mesh, spec = make_mesh({"data": 4}, devices=jax.devices()[:4]), PartitionSpec("data")
+
+        def count_program(a):
+            return a + 1
+
+        program = cached_sm(("test_phase_spans", mesh),
+                            lambda: jax.jit(shard_map(count_program, mesh=mesh, in_specs=(spec,), out_specs=spec)))
+        return program, (jnp.arange(8, dtype=jnp.int32),), {}
+    assert case == "probe_impl"
+    table = pallas_kernels.build_paged_table(jnp.arange(40, dtype=jnp.int32), None)
+    u = pallas_kernels._order_map_u(jnp.arange(n, dtype=jnp.int32))
+    return pallas_kernels._probe_impl, (u, jnp.ones((n,), bool), table.limbs, table.meta, table.num_buckets,
+                                        table.n_pages, table.nlimb, table.c_max, True), {}
+
+
+@pytest.mark.parametrize("case", ["f64_sum_mean", "string_lanes", "lexsort", "stage_program", "to_float64_program",
+                                  "row_size_stats", "to_rows_fixed_static", "to_rows_fixed_sliced", "mesh_program",
+                                  "probe_impl"])
+def test_a_wrapped_program_says_one_launch_a_call_by_the_name_its_call_lowers_to(case):
+    fn, args, kwargs = _launch_case(case)
+    spans, _ = _traced(fn, *args, **kwargs)
+    (launch,) = [s for s in spans if s["name"] == "device.launch"]
+    assert "jit_" + launch["annotations"]["program"] == _module_of(fn.lower(*args, **kwargs))
+    assert launch["dur_us"] == 0 and launch["parent"] == _one(spans, "device.test")["span"]
+
+
+def test_the_fused_encode_and_the_eager_bitcast_are_named_as_the_device_names_them(rowconv_spans):
+    from jax import lax
+
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    # an eager primitive's program is ``jit_`` + the primitive's own name
+    assert rc._launch_bitcast.__name__ == lax.bitcast_convert_type_p.name == "bitcast_convert_type"
+    assert rc._launch_bitcast.__wrapped__ is lax.bitcast_convert_type
+    programs = {p for p, _ in _launches(rowconv_spans["one_batch"][0])}
+    assert rc._jit_encode_strings_fused.__name__ in programs
+
+
+def test_no_launch_while_a_program_is_traced_into_an_enclosing_one():
+    from spark_rapids_jni_tpu.ops import aggregate, sort
+
+    n = 32
+    bits, order, seg = jnp.arange(n, dtype=jnp.uint64), jnp.arange(n, dtype=jnp.int32), jnp.zeros((n,), jnp.int32)
+
+    def enclosing(bits, order, seg):
+        again = sort._launch_lexsort((order, seg))
+        return aggregate._f64_sum_mean(bits, None, again.astype(jnp.int32), seg, None, num=1, how="sum")
+
+    trace_sink.reset_for_tests()
+    with tracing.enabled():
+        qt = tracing.start_trace("device.test")
+        with qt.activate():
+            jax.block_until_ready(jax.jit(enclosing)(bits, order, seg))  # traced and compiled under the trace
+        qt.finish("ok")
+    spans = trace_sink.recorder().last(1)[0]["spans"]
+    assert [s for s in spans if s["name"] == "device.launch"] == []
+
+
+def test_the_wrapper_keeps_the_jitted_objects_own_handles():
+    from spark_rapids_jni_tpu.ops import aggregate
+
+    fn = aggregate._f64_sum_mean
+    assert fn.__name__ == "_f64_sum_mean" and callable(fn.lower) and callable(fn.clear_cache)
+    assert isinstance(fn._cache_size(), int) and fn.__wrapped__.lower == fn.lower
+    nameless = object()  # what a test of the mesh programs' memo puts there: nothing to name, nothing to wrap
+    assert tracing.launches(nameless) is nameless
+
+
+def test_tracing_off_no_wait_no_launch_and_no_block_until_ready(monkeypatch):
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: calls.append(1) or real(x))
+    table = _lineitem(np.random.default_rng(26))
+    cp = P.compile_ir(_q1_shaped_plan(), {"lineitem": table}, name="q1_off")
+    sched = serve.Scheduler(max_concurrent=1, name="phase-spans-off")
+    trace_sink.reset_for_tests()
+    assert not tracing.is_enabled()
+    try:
+        out = sched.submit(cp).result()
+        rc.convert_to_rows(_mixed_table(np.random.default_rng(34)))
+        x = jnp.arange(3)
+        assert tracing.device_wait(x, "anything") is x
+    finally:
+        sched.shutdown()
+    assert out.num_rows > 0 and calls == []
+    assert trace_sink.recorder().last(1) == []
+    # armed but with no trace active there is nobody to tell: still no wait
+    with tracing.enabled():
+        assert tracing.device_wait(x, "anything") is x
+        assert calls == []
+        qt = tracing.start_trace("device.test")
+        with qt.activate():
+            assert tracing.device_wait(x, "anything") is x
+        qt.finish("ok")
+    assert calls == [1]
+    assert _waits(trace_sink.recorder().last(1)[0]["spans"]) == [("anything", "device.test")]
+
+
+def test_the_rendered_tree_says_what_a_span_waited_for_and_the_share_it_took(q1_spans):
+    rec = {"trace": "00", "name": "serve.query", "status": "ok", "duration_s": 0.9, "spans": [
+        {"span": "a", "parent": None, "name": "op.exchange_sharded", "ts": 1.0, "dur_us": 850e3, "pid": 7},
+        {"span": "b", "parent": "a", "name": "exchange.table", "ts": 1.1, "dur_us": 846e3, "pid": 7,
+         "annotations": {"capacity": 65536}},
+        {"span": "c", "parent": "b", "name": "device.launch", "ts": 1.2, "dur_us": 0.0, "pid": 7,
+         "annotations": {"program": "count_program"}},
+        {"span": "d", "parent": "b", "name": "device.wait", "ts": 1.3, "dur_us": 843e3, "pid": 7,
+         "annotations": {"what": "exchange_counts"}},
+    ]}
+    text = trace_sink.render_trace(rec)
+    assert "- exchange.table 846.00ms (pid 7) capacity=65536" in text
+    assert "- device.wait(exchange_counts) 843.00ms = 99.6% of exchange.table (pid 7)" in text
+    assert "- device.launch(count_program) 0.00ms (pid 7)" in text
+    # and on a real request: the group-by's one sync under the span that asked for it
+    import re
+
+    real = trace_sink.render_trace({"name": "serve.query", "spans": q1_spans})
+    assert re.search(r"- device\.wait\(group_count\) [\d.]+ms = [\d.]+% of groupby\.segments \(pid \d+\)$", real, re.M)
