@@ -741,6 +741,27 @@ def _filters_moved(before):
     return {k: v - before[k] for k, v in _filter_counters().items()}
 
 
+def _form_counters():
+    return _counters("groupby", ("dense", "sorted"))
+
+
+def _forms_moved(before):
+    return {k: v - before[k] for k, v in _form_counters().items()}
+
+
+def _traced_spans(cp):
+    """(spans, answer) of one run of a compiled plan under a trace of its own."""
+    from spark_rapids_jni_tpu.utils import trace_sink, tracing
+
+    trace_sink.reset_for_tests()
+    with tracing.enabled():
+        qt = tracing.start_trace("plan.test")
+        with qt.activate():
+            out = cp()
+        qt.finish("ok")
+    return trace_sink.recorder().last(1)[0]["spans"], out
+
+
 def _no_compaction(monkeypatch):
     """Fail the run that launches the compaction's ``jnp.nonzero``."""
     def nonzero(*a, **k):
@@ -832,6 +853,42 @@ class TestDeferredFilter:
         assert _filters_moved(before) == {"deferred": int(deferred), "compacted": int(not deferred)}
         _assert_q1_answer(q1, out, frames, cutoff)
         assert cp.last_report["stages"][1]["actual_rows"] == (Q1_ROWS if deferred else kept)
+
+    @pytest.mark.parametrize("seed", [7, 3_700_000_011])
+    def test_q1_numbers_its_groups_from_the_flags_codes(self, q1_cell, seed, clean_state):
+        """ISSUE 37: two int8 dictionary codes over 3 x 2 values: the group-by
+        behind the deferred Filter reads their domain, sorts nothing and
+        gathers nothing, and answers what pandas and the sort path answer —
+        the latter lane for lane."""
+        from unittest import mock
+
+        from spark_rapids_jni_tpu.ops import aggregate
+
+        q1, table, frames = q1_cell(seed)
+        cp = P.compile_ir(q1.plan(P), {"lineitem": table}, name="q1-dense")
+        cp()
+        forms, filters = _form_counters(), _filter_counters()
+        spans, out = _traced_spans(cp)
+        assert _forms_moved(forms) == {"dense": 1, "sorted": 0}
+        assert _filters_moved(filters) == {"deferred": 1, "compacted": 0}
+        names = [s["name"] for s in spans]
+        assert "groupby.sort" not in names and names.count("op.groupby_aggregate") == 1
+        assert [s["annotations"] for s in spans if s["name"] == "groupby.segments"] == [
+            {"dense": True, "domain": 6, "groups": 4}]
+        under_the_groupby = {s["span"] for s in spans if s["name"].startswith(("groupby.", "op.groupby"))}
+        launched = [s["annotations"]["program"] for s in spans
+                    if s["name"] == "device.launch" and s["parent"] in under_the_groupby]
+        assert launched == ["_key_domain", "_slot_counts", "_slot_group_ids"] + ["_f64_sum_mean"] * 7
+        _assert_q1_answer(q1, out, frames, q1.CUTOFF)
+        with mock.patch.object(aggregate, "_DENSE_MAX_SLOTS", 0):
+            sorted_ = cp()
+        assert _forms_moved(forms) == {"dense": 1, "sorted": 1}
+        assert out.names == sorted_.names
+        for name in out.names:
+            a, b = out.column(name), sorted_.column(name)
+            assert a.dtype == b.dtype and (a.validity is None) == (b.validity is None), name
+            np.testing.assert_array_equal(np.asarray(a.data), np.asarray(b.data), err_msg=name)
+            np.testing.assert_array_equal(np.asarray(a.valid_mask()), np.asarray(b.valid_mask()), err_msg=name)
 
     def test_both_forms_of_q1_give_the_same_lanes(self, q1_cell):
         """Exact sums, exact means, counts and keys: bit for bit, not within a gap."""
@@ -937,7 +994,8 @@ class TestDeferredFilter:
 
     @pytest.mark.parametrize("keys", [(), ("k",)])
     @pytest.mark.parametrize("how_many", ["no_row_passes", "one_row_passes"])
-    def test_a_global_aggregate_over_an_all_false_mask_yields_its_one_row(self, rng, how_many, keys, monkeypatch):
+    def test_a_global_aggregate_over_an_all_false_mask_yields_its_one_row(self, rng, how_many, keys, monkeypatch,
+                                                                         clean_state):
         """The mask keeps nothing: below one half, so the stage compacts and
         the aggregate sees the empty table — and where the threshold is
         lowered to let the all-false mask through, it answers the same."""
@@ -952,10 +1010,15 @@ class TestDeferredFilter:
         assert cp.stages[1].deferrable
         compacted = cp()
         monkeypatch.setattr(pc, "_DEFER_MIN_KEEP", 0.0)  # the mask rides whatever it keeps
-        before = _filter_counters()
-        deferred = cp()
+        before, forms = _filter_counters(), _form_counters()
+        spans, deferred = _traced_spans(cp)
         assert _filters_moved(before) == {"deferred": 1, "compacted": 0}
         kept = int((np.asarray(t.column("d").data) <= cut).sum())
+        # ISSUE 37: a global aggregate's column of zeros, and one row's INT8 key, span ONE value: dense
+        # with a domain of 1; a mask that keeps nothing has no domain, sorts, and is SQL's one row all the same
+        assert _forms_moved(forms) == ({"dense": 1, "sorted": 0} if kept else {"dense": 0, "sorted": 1})
+        assert [s["annotations"] for s in spans if s["name"] == "groupby.segments"][0] == (
+            {"dense": True, "domain": 1, "groups": 1} if kept else {"dense": False, "domain": 0})
         assert compacted.num_rows == deferred.num_rows == (1 if not keys or kept else 0)
         for name in compacted.names:
             a, b = compacted.column(name), deferred.column(name)
