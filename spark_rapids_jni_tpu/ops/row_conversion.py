@@ -785,7 +785,7 @@ def _slice_column(col: Column, rs: int, re: int) -> Column:
 
 @op_boundary("convert_from_rows")
 def convert_from_rows(rows: Column, dtypes: Sequence[DType]) -> Table:
-    """LIST<INT8> column of JCUDF rows + schema -> Table.
+    """LIST<INT8> (or LIST<UINT8>) column of JCUDF rows + schema -> Table.
 
     Parity: RowConversion.convertFromRows (RowConversion.java:137) ->
     convert_from_rows (row_conversion.cu:2031-2252).
@@ -795,22 +795,73 @@ def convert_from_rows(rows: Column, dtypes: Sequence[DType]) -> Table:
     dtypes = list(dtypes)
     layout = compute_row_layout(dtypes)
     n = len(rows)
-    blob = lax.bitcast_convert_type(rows.child.data, jnp.uint8)
-    starts = rows.offsets[:-1].astype(jnp.int64)
+    blob = _row_bytes(rows)
 
     if n == 0:
+        _count_from_rows(0, 0, None)
         return Table([_empty_column(d) for d in dtypes])
 
-    uniform = _offsets_uniform(rows, blob.shape[0], layout.row_size_fixed, n)
-    if uniform:
-        # constant row stride (always true for all-fixed-width tables we
-        # produced): the row gather is a free reshape + static slice,
-        # fused with the group decode in one program
-        col_datas, valid = _decode_fixed_uniform(layout, tuple(dtypes), blob)
-        return _assemble_from_rows(dtypes, col_datas, valid, blob, starts, n)
-    fixed = _gather_fixed(layout, blob, starts, n)
-    col_datas, valid = _decode_fixed_cols(layout, tuple(dtypes), fixed)
-    return _assemble_from_rows(dtypes, col_datas, valid, blob, starts, n)
+    # dispatch only: the decode's device time is waited for by whoever
+    # reads the columns (the sidecar's reply: sidecar.worker.d2h)
+    with tracing.span(
+        "rowconv.decode", rows=n, cols=len(dtypes), bytes=int(blob.shape[0])
+    ) as sp:
+        uniform = _offsets_uniform(rows, blob.shape[0], layout.row_size_fixed, n)
+        form = "uniform" if uniform else "gathered"
+        sp.annotate(form=form)
+        # the rows' starts only where the row gather or a STRING column reads them
+        starts = (
+            rows.offsets[:-1].astype(jnp.int64)
+            if not uniform or layout.variable_cols
+            else None
+        )
+        if uniform:
+            # constant row stride (always true for all-fixed-width tables we
+            # produced): the row gather is a free reshape + static slice,
+            # fused with the group decode in one program
+            col_datas, valid = _decode_fixed_uniform(layout, tuple(dtypes), blob)
+        else:
+            fixed = _gather_fixed(layout, blob, starts, n)
+            col_datas, valid = _decode_fixed_cols(layout, tuple(dtypes), fixed)
+        table = _assemble_from_rows(dtypes, col_datas, valid, blob, starts, n)
+    _count_from_rows(n, int(blob.shape[0]), form)
+    return table
+
+
+def _row_bytes(rows: Column) -> jnp.ndarray:
+    """The rows' blob as uint8: a UINT8 child as it is (the sidecar puts
+    the wire's bytes so), an INT8 child through one bitcast program."""
+    data = rows.child.data
+    return data if data.dtype == jnp.uint8 else _launch_bitcast(data, jnp.uint8)
+
+
+def rows_from_host(offsets: np.ndarray, blob: np.ndarray) -> Column:
+    """A LIST<UINT8> rows column from host ``int32`` offsets and row bytes,
+    each put on the device once, as it is. The row stride is read from the
+    host offsets here: ``_uniform_stride`` is that stride where every row
+    has it and 0 where the offsets are ragged, so ``convert_from_rows``
+    takes no device round trip to choose its form."""
+    col = Column(
+        dt.LIST,
+        offsets=jnp.asarray(offsets),
+        child=Column(dt.UINT8, data=jnp.asarray(blob)),
+    )
+    stride = int(offsets[1]) if len(offsets) > 1 and offsets[0] == 0 else 0
+    uniform = stride > 0 and bool(np.all(np.diff(offsets) == stride))
+    col._uniform_stride = stride if uniform else 0
+    return col
+
+
+def _count_from_rows(rows: int, bytes_in: int, form) -> None:
+    """Registry-direct (on with tracing off): what a call of
+    convert_from_rows moved and which form of the decode served it."""
+    reg = metrics.registry()
+    reg.counter("rowconv.from_rows.calls").inc()
+    reg.counter("rowconv.from_rows.rows").inc(rows)
+    reg.counter("rowconv.from_rows.bytes_in").inc(bytes_in)
+    reg.counter("rowconv.from_rows.uniform").inc(int(form == "uniform"))
+    reg.counter("rowconv.from_rows.gathered").inc(int(form == "gathered"))
+    reg.counter("rowconv.from_rows.stride_probes")  # shows 0 until _offsets_uniform probes
 
 
 def _gather_fixed(layout: RowLayout, blob, starts, n: int):
@@ -844,23 +895,27 @@ def _jit_padded_gather(blob, starts, fixed_end: int):
     return padded_extract(blob, starts, fixed_end)[:, :fixed_end]
 
 
+@tracing.launches
 @jax.jit
 def _offsets_uniform_probe(offsets, stride):
     return (offsets[0] == 0) & jnp.all(offsets[1:] - offsets[:-1] == stride)
 
 
 def _offsets_uniform(rows: Column, blob_len: int, stride: int, n: int) -> bool:
-    """Constant-row-stride check. Prefer the producer-attached stride
-    metadata (zero syncs); otherwise reduce ON DEVICE and pull one
-    scalar — pulling the whole offsets array would move 8B/row over the
-    runtime, and even the scalar sync is a full host↔device round
-    trip, which is why the metadata path matters."""
+    """Constant-row-stride check. Prefer the stride the producer attached
+    (zero syncs): ``convert_to_rows``' fixed-width batches carry it, and
+    the sidecar's worker reads it from the host offsets it already holds
+    (``rows_from_host``). A rows column that arrives on the device from
+    another producer is reduced ON DEVICE and one scalar pulled — a full
+    host↔device round trip, counted in ``rowconv.from_rows.stride_probes``."""
     if blob_len != n * stride:
         return False
     known = getattr(rows, "_uniform_stride", None)
     if known is not None:
         return known == stride
-    return bool(_offsets_uniform_probe(rows.offsets, jnp.asarray(stride, rows.offsets.dtype)))
+    metrics.registry().counter("rowconv.from_rows.stride_probes").inc()
+    probe = _offsets_uniform_probe(rows.offsets, jnp.asarray(stride, rows.offsets.dtype))
+    return bool(tracing.device_wait(probe, "row_stride"))
 
 
 def _finish_column(d: DType, data, vmask, blob, starts) -> Column:
@@ -1072,7 +1127,7 @@ def convert_from_rows_grouped(rows: Column, dtypes: Sequence[DType]) -> GroupedR
     dtypes = tuple(dtypes)
     layout = compute_row_layout(dtypes)
     n = len(rows)
-    blob = lax.bitcast_convert_type(rows.child.data, jnp.uint8)
+    blob = _row_bytes(rows)
     starts = rows.offsets[:-1].astype(jnp.int64)
     if n == 0:
         return GroupedRows(
@@ -1116,6 +1171,7 @@ def _decode_grouped_fixed(layout: RowLayout, dtypes: Tuple[DType, ...], fixed: j
     return tuple(ga.values()), vt
 
 
+@tracing.launches
 @partial(jax.jit, static_argnums=(0, 1))
 def _decode_fixed_uniform(layout: RowLayout, dtypes: Tuple[DType, ...], blob: jnp.ndarray):
     """Uniform-stride decode: [n*row_size] u8 blob -> grouped columns in
@@ -1125,6 +1181,7 @@ def _decode_fixed_uniform(layout: RowLayout, dtypes: Tuple[DType, ...], blob: jn
     return _decode_fixed_groups(layout, dtypes, _uniform_fixed(layout, blob, n))
 
 
+@tracing.launches
 @partial(jax.jit, static_argnums=(0, 1))
 def _decode_fixed_cols(layout: RowLayout, dtypes: Tuple[DType, ...], fixed: jnp.ndarray):
     """[N, fixed_end] u8 -> (per-column data arrays, [N, C] validity).
@@ -1375,6 +1432,7 @@ def convert_from_rows_fixed_width_optimized(rows: Column, dtypes: Sequence[DType
 # ---------------------------------------------------------------------------
 
 
+@tracing.launches
 @jax.jit
 def _jit_gather_fixed_impl(blob, starts, iota):
     return blob[starts[:, None] + iota[None, :]]

@@ -50,11 +50,12 @@ VERDICT r3 item 2):
   3 CONVERT_FROM_ROWS in:  u32 ncols, i32[ncols] type_ids, i32[ncols]
                            scales, u64 nrows, i32[nrows+1] offsets,
                            u64 blob_len, u8 blob
-                      out: serialized table (_write_table; inside the
-                           worker a reply may be ``ReplyPieces``, the
-                           headers and host arrays in wire order, which
-                           ``reply()`` gathers onto the wire unjoined:
-                           the bytes on the wire are the same)
+                      out: serialized table (_write_table's walker
+                           layout; inside the worker the reply is
+                           ``ReplyPieces``, the headers and host arrays
+                           in wire order, which ``reply()`` gathers onto
+                           the wire unjoined: the bytes on the wire are
+                           the same)
   4 CAST_TO_INTEGER   in:  u8 ansi, i32 out_type_id, serialized table
                            (one STRING column)
                       out: serialized table (one column); ANSI failures
@@ -537,37 +538,42 @@ def _op_convert_to_rows(payload: bytes) -> ReplyPieces:
     return resp
 
 
-def _op_convert_from_rows(payload: bytes) -> bytes:
-    import jax.numpy as jnp
+def _op_convert_from_rows(payload: bytes) -> ReplyPieces:
+    """The rows' way to the device under ``sidecar.worker.decode_table``
+    (the phase the to-row ops' span of that name times: wire bytes to
+    device arrays; here the parse, and one put each of the offsets and
+    of the rows as they came, their stride read on the host), the decode,
+    then ``_write_table``'s legacy reply: ``ReplyPieces``."""
     import numpy as np
 
-    from .columnar import Column
     from .columnar.dtype import DType, TypeId
-    from .ops.row_conversion import convert_from_rows
+    from .ops.row_conversion import convert_from_rows, rows_from_host
+    from .utils import tracing
 
-    pos = 0
-    (ncols,) = struct.unpack_from("<I", payload, pos)
-    pos += 4
-    type_ids = np.frombuffer(payload, np.int32, ncols, pos)
-    pos += 4 * ncols
-    scales = np.frombuffer(payload, np.int32, ncols, pos)
-    pos += 4 * ncols
-    (nrows,) = struct.unpack_from("<Q", payload, pos)
-    pos += 8
-    offs = np.frombuffer(payload, np.int32, nrows + 1, pos)
-    pos += 4 * (nrows + 1)
-    (blen,) = struct.unpack_from("<Q", payload, pos)
-    pos += 8
-    blob = np.frombuffer(payload, np.uint8, blen, pos)
-    dtypes = [
-        DType(TypeId(int(t)), int(s) if TypeId(int(t)).name.startswith("DECIMAL") else 0)
-        for t, s in zip(type_ids, scales)
-    ]
-    rows = Column(
-        DType(TypeId.LIST),
-        offsets=jnp.asarray(offs),
-        child=Column(DType(TypeId.INT8), data=jnp.asarray(blob).view(jnp.int8)),
-    )
+    with tracing.span("sidecar.worker.decode_table", bytes=len(payload)) as sp:
+        pos = 0
+        (ncols,) = struct.unpack_from("<I", payload, pos)
+        pos += 4
+        type_ids = np.frombuffer(payload, np.int32, ncols, pos)
+        pos += 4 * ncols
+        scales = np.frombuffer(payload, np.int32, ncols, pos)
+        pos += 4 * ncols
+        (nrows,) = struct.unpack_from("<Q", payload, pos)
+        pos += 8
+        offs = np.frombuffer(payload, np.int32, nrows + 1, pos)
+        pos += 4 * (nrows + 1)
+        (blen,) = struct.unpack_from("<Q", payload, pos)
+        pos += 8
+        blob = np.frombuffer(payload, np.uint8, blen, pos)
+        dtypes = [
+            DType(TypeId(int(t)), int(s) if TypeId(int(t)).name.startswith("DECIMAL") else 0)
+            for t, s in zip(type_ids, scales)
+        ]
+        rows = rows_from_host(offs, blob)
+        # the put returns before its bytes are on the device: with tracing
+        # on, the phase waits for them (off, nothing waits here)
+        tracing.device_wait(rows, "rows_put")
+        sp.annotate(rows=int(nrows), cols=int(ncols))
     return _write_table(convert_from_rows(rows, dtypes))
 
 
