@@ -105,7 +105,7 @@ def main() -> int:
 
     @functools.partial(jax.jit, static_argnames=("how",))
     def cut_finish(gs, cnt, *, how):
-        limbs, emax, has_nan, has_pinf, has_ninf = gs
+        limbs, emax, has_nan, has_pinf, has_ninf, _ = gs
         negative, mag = f64acc._carry_normalize(limbs)
         rem = None
         if how == "mean":

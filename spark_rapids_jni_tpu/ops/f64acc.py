@@ -140,6 +140,7 @@ class _GroupSum(NamedTuple):
     has_nan: jnp.ndarray  # [G] bool
     has_pinf: jnp.ndarray
     has_ninf: jnp.ndarray
+    any_live: jnp.ndarray  # [G] bool: the group holds a valid, present row
 
 
 # one-hot bytes per group x row the MXU path may materialize (256 MB)
@@ -150,7 +151,7 @@ _MXU_CHUNK = 1 << 26
 
 
 def _accumulate_mxu(
-    neg, e_eff, mant, is_nan, is_pinf, is_ninf, live, emax, seg, num_segments
+    neg, e_eff, mant, is_nan, is_pinf, is_ninf, live, emax, any_live, seg, num_segments
 ) -> _GroupSum:
     """Per-group limb reduction as a signed one-hot int8 MXU contraction.
 
@@ -224,7 +225,8 @@ def _accumulate_mxu(
                 acc[:, 8 * LIMBS + 1] > 0,
                 acc[:, 8 * LIMBS + 2] > 0,
             )
-        )
+        ),
+        any_live,
     )
 
 
@@ -235,7 +237,7 @@ def _accumulate(bits, valid, seg, num_segments) -> _GroupSum:
         # below would jnp.max over a zero-size array, which errors.
         z64 = jnp.zeros((num_segments, LIMBS), _I64)
         zb = jnp.zeros((num_segments,), bool)
-        return _GroupSum(z64, jnp.ones((num_segments,), _I32), zb, zb, zb)
+        return _GroupSum(z64, jnp.ones((num_segments,), _I32), zb, zb, zb, zb)
     neg, e_eff, mant, is_nan, is_pinf, is_ninf = _decompose(bits)
     if valid is not None:
         live = valid
@@ -250,7 +252,9 @@ def _accumulate(bits, valid, seg, num_segments) -> _GroupSum:
     # 1M rows the 10-lane scatter alone is ~0.4 s. For small group
     # counts — the fused-pipeline regime (q1 has 6 groups, a global sum
     # 1) — G masked bandwidth-bound reductions are orders of magnitude
-    # cheaper than one scatter pass.
+    # cheaper than one scatter pass. e_eff is at least 1 on every row, so
+    # the maxima before their clamp are > 0 exactly where the group holds
+    # a live row: that is ``any_live``, and no pass over the rows asks it.
     small = num_segments <= 16
     if small:
         emax = jnp.stack(
@@ -258,6 +262,7 @@ def _accumulate(bits, valid, seg, num_segments) -> _GroupSum:
         )
     else:
         emax = jax.ops.segment_max(e_live, seg, num_segments=num_segments)
+    any_live = emax > 0
     emax = jnp.maximum(emax, 1)  # empty / all-invalid groups: any base works
 
     if num_segments * bits.shape[0] <= _MXU_ONEHOT_BUDGET:
@@ -265,7 +270,8 @@ def _accumulate(bits, valid, seg, num_segments) -> _GroupSum:
         # bit-identical to the payload reduction below, at matmul
         # bandwidth instead of per-element i64 ALU
         return _accumulate_mxu(
-            neg, e_eff, mant, is_nan, is_pinf, is_ninf, live, emax, seg, num_segments
+            neg, e_eff, mant, is_nan, is_pinf, is_ninf, live, emax, any_live, seg,
+            num_segments,
         )
 
     shift = emax[seg] - e_eff  # >= 0 for live rows
@@ -297,6 +303,7 @@ def _accumulate(bits, valid, seg, num_segments) -> _GroupSum:
         acc[..., LIMBS] > 0,
         acc[..., LIMBS + 1] > 0,
         acc[..., LIMBS + 2] > 0,
+        any_live,
     )
 
 
@@ -465,11 +472,16 @@ def segment_sum_f64bits(
     addend — below any representable ulp). Integer-only: identical bits
     on CPU and TPU. Invalid rows (valid=False) contribute nothing.
     """
+    return _segment_sum(bits, seg, num_segments, valid)[0]
+
+
+def _segment_sum(bits, seg, num_segments, valid):
+    """``segment_sum_f64bits`` and, beside it, [G] bool: the group holds a
+    valid row (read off the accumulation's exponent maxima)."""
     gs = _accumulate(bits, valid, seg, num_segments)
     negative, mag = _carry_normalize(gs.limbs)
-    return _round_to_bits(
-        negative, mag, gs.emax, gs.has_nan, gs.has_pinf, gs.has_ninf
-    )
+    out = _round_to_bits(negative, mag, gs.emax, gs.has_nan, gs.has_pinf, gs.has_ninf)
+    return out, gs.any_live
 
 
 def _limb_divide(mag: jnp.ndarray, cnt: jnp.ndarray):
@@ -516,6 +528,13 @@ def segment_mean_f64bits(
     division; the remainder folds into the sticky bit, so the result is
     the f64 nearest-even rounding of (exact sum / count). Returns
     (mean_bits [G] u64, count [G] i64)."""
+    out, cnt, _ = _segment_mean(bits, seg, num_segments, valid)
+    return out, cnt
+
+
+def _segment_mean(bits, seg, num_segments, valid):
+    """``segment_mean_f64bits``' (bits, count) and, beside them, [G] bool:
+    the group holds a valid row (read off the exponent maxima)."""
     gs = _accumulate(bits, valid, seg, num_segments)
     live = valid if valid is not None else jnp.ones(bits.shape, bool)
     if num_segments == 0:
@@ -534,7 +553,7 @@ def segment_mean_f64bits(
     out = _round_to_bits(
         negative, q, gs.emax, gs.has_nan, gs.has_pinf, gs.has_ninf, extra_sticky=rem
     )
-    return out, cnt
+    return out, cnt, gs.any_live
 
 
 def u64_to_f64bits(x: jnp.ndarray) -> jnp.ndarray:

@@ -27,8 +27,16 @@ N = 600
 def _eager_chain(col, order, seg, num, how):
     """The branch of ``_agg_column`` as it stood before the one program:
     every ``jnp`` call a launch of its own."""
-    valid = col.valid_mask()[order]
-    bits = col.data[order]
+    return _eager_lanes(col.data, col.valid_mask(), order, seg, None, num, how)
+
+
+def _eager_lanes(data, validity, order, seg, live, num, how):
+    """``_eager_chain`` over ``_f64_sum_mean``'s own arguments: the rows
+    through ``order`` (None: where they lie), ``live`` masking the absent
+    ones, and "any row valid" asked of the rows by a ``segment_max``."""
+    valid, bits = (validity, data) if order is None else (validity[order], data[order])
+    if live is not None:
+        valid = valid & live
     if how == "sum":
         out = f64acc.segment_sum_f64bits(bits, seg, num, valid=valid)
     else:
@@ -155,3 +163,88 @@ def test_a_drifting_group_count_meets_one_program(rng):
         assert got.data.shape == (groups,) and got.validity.shape == (groups,)
         sizes.add(aggregate._f64_sum_mean._cache_size())
     assert len(sizes) == 1
+
+
+LANE_CASES = [
+    # groups (compiled for ``_static_groups`` of it), what the last group holds
+    (1, "plain"), (4, "plain"), (16, "plain"), (17, "plain"), (64, "plain"),
+    (4, "nulls only"), (17, "nulls only"),
+    (4, "absent rows only"), (64, "absent rows only"),
+    (9, "padded"), (17, "padded"),  # compiled for 10 and 20
+    (1, "zero rows"), (17, "zero rows"),
+    (4, "nan and infinities"), (17, "nan and infinities"),
+    (4, "signed zeros"), (17, "signed zeros"),
+    (4, "subnormals"), (17, "subnormals"),
+]
+
+
+def _lanes_input(rng, groups, holds, n=240):
+    """``_f64_sum_mean``'s (data, validity, order, seg, live) over ``groups``
+    groups, the last of which holds ``holds``. Up to 16 groups the rows lie
+    where they are numbered (the dense form, ``order`` None); above, they
+    are gathered through a permutation (the sorted form)."""
+    n = 0 if holds == "zero rows" else n
+    seg = rng.integers(0, groups, n).astype(np.int32)
+    seg[:groups] = np.arange(groups)[:n]
+    vals = _values(rng, n, "plain")
+    valid = rng.random(n) < 0.9
+    live = np.ones(n, bool)
+    last = seg == groups - 1
+    k = int(last.sum())
+    if holds == "nulls only":
+        valid[last] = False
+    elif holds == "absent rows only":
+        live[last] = False
+    elif holds == "padded":  # the absent rows carry the id past the last group, as the dense form numbers them
+        live[rng.random(n) < 0.2] = False
+        seg[~live] = groups
+    elif holds in ("nan and infinities", "signed zeros", "subnormals"):
+        valid[last] = True
+        if holds == "nan and infinities":
+            vals[last] = rng.choice([np.nan, np.inf, -np.inf], k)
+        elif holds == "signed zeros":
+            vals[last] = rng.choice([0.0, -0.0], k)
+        else:
+            vals[last] = 5e-324 * rng.integers(-(1 << 20), 1 << 20, k)
+    if groups <= 16:
+        order, data, validity = None, vals, valid
+    else:
+        perm = rng.permutation(n).astype(np.int32)
+        data, validity = np.empty_like(vals), np.empty_like(valid)
+        data[perm], validity[perm] = vals, valid  # what ``order`` gathers is the rows above
+        order = jnp.asarray(perm)
+    return (jnp.asarray(data.view(np.uint64)), jnp.asarray(validity), order,
+            jnp.asarray(seg), jnp.asarray(live))
+
+
+@pytest.mark.parametrize("how", ["sum", "mean"])
+@pytest.mark.parametrize("groups,holds", LANE_CASES)
+def test_any_valid_comes_from_the_exponent_maxima_lane_for_lane(rng, groups, holds, how):
+    data, validity, order, seg, live = _lanes_input(rng, groups, holds)
+    num = aggregate._static_groups(groups)
+    want_bits, want_valid = _eager_lanes(data, validity, order, seg, live, num, how)
+    got_bits, got_valid = aggregate._f64_sum_mean(data, validity, order, seg, live, num=num, how=how)
+    np.testing.assert_array_equal(np.asarray(got_bits), want_bits)
+    np.testing.assert_array_equal(np.asarray(got_valid), want_valid)
+    if holds in ("nulls only", "absent rows only", "zero rows"):
+        assert not want_valid[groups - 1]
+    elif holds in ("nan and infinities", "signed zeros", "subnormals"):
+        assert want_valid[groups - 1]
+    if holds == "padded":
+        assert num > groups and not want_valid[groups:].any()
+
+
+@pytest.mark.parametrize("how", ["sum", "mean"])
+@pytest.mark.parametrize("groups", [1, 4, 16, 17, 64])
+def test_no_scatter_asks_which_groups_hold_a_row(how, groups):
+    """Up to 16 groups the program is masked reductions and a contraction:
+    no scatter at all. Above, the exponent maxima are one ``segment_max``
+    (and a mean's count one ``segment_sum``): none more."""
+    n = 4096
+    rows = lambda dtype: jax.ShapeDtypeStruct((n,), dtype)  # noqa: E731
+    text = aggregate._f64_sum_mean.lower(
+        rows(jnp.uint64), rows(jnp.bool_), None, rows(jnp.int32), rows(jnp.bool_),
+        num=aggregate._static_groups(groups), how=how,
+    ).as_text()
+    want = 0 if groups <= 16 else (1 if how == "sum" else 2)
+    assert text.count('"stablehlo.scatter"(') == want
