@@ -33,7 +33,7 @@ from ..columnar.dtype import TypeId
 from ..utils import metrics, tracing
 from ..utils.dispatch import op_boundary
 from . import bitutils
-from .copying import gather
+from .copying import gather, gather_column
 
 __all__ = ["sorted_order", "sort_by_key", "string_key_lanes"]
 
@@ -77,11 +77,14 @@ def string_key_lanes(col: Column) -> List[jnp.ndarray]:
     return list(_string_lanes(col.offsets, col.chars, lanes=lanes))
 
 
-def _column_keys(col: Column, ascending: bool, nulls_first: bool) -> List[jnp.ndarray]:
+def _column_keys(col: Column, ascending: bool, nulls_first: bool,
+                 string_lanes: Optional[Sequence[jnp.ndarray]] = None) -> List[jnp.ndarray]:
     """Minor-to-major NOT applied here; returns [null_key, k2?, k1] style
-    major-first list of u-int key lanes for one column."""
+    major-first list of u-int key lanes for one column. A STRING key's
+    value lanes are ``string_lanes`` where given (made before a program
+    that traces this: their count is a host value)."""
     if col.dtype.id == TypeId.STRING:
-        lanes = string_key_lanes(col)
+        lanes = string_key_lanes(col) if string_lanes is None else list(string_lanes)
     elif col.dtype.id == TypeId.DECIMAL128:
         # flip sign bit of the top limb; compare limbs high->low
         top = col.data[:, 3] ^ jnp.uint32(1 << 31)
@@ -153,10 +156,72 @@ def _lexsort(lanes: Sequence[jnp.ndarray]) -> jnp.ndarray:
     return order
 
 
+def _lane_count(col: Column, string_lanes) -> int:
+    """How many lanes ``_column_keys`` makes for the key ``col``, without
+    making them: its null rank and its value's."""
+    if string_lanes is not None:
+        return 1 + len(string_lanes)
+    return 1 + (2 if col.dtype.id == TypeId.DECIMAL128 else 1)
+
+
+def _ragged(col: Column) -> bool:
+    """A column whose gather needs the host's total of its characters or children."""
+    return col.dtype.id in (TypeId.STRING, TypeId.LIST)
+
+
+@tracing.launches
+@functools.partial(jax.jit, static_argnames=("ascending", "nulls_first"))
+def _sort_gather(keys, string_lanes, values, *, ascending, nulls_first):
+    """A Sort as ONE program: every key's lanes (``_column_keys``; a STRING
+    key's value lanes come in as ``string_lanes``), their stable
+    ``_lexsort``, and every fixed-width value column's data and validity
+    gathered through the order. The order is a permutation, so no bounds
+    mask and no clip. Returns the sorted columns and the order."""
+    lanes: List[jnp.ndarray] = []
+    for col, sl, a, f in zip(keys, string_lanes, ascending, nulls_first):
+        lanes.extend(_column_keys(col, a, f, sl))
+    order = _lexsort(lanes).astype(jnp.int32)
+    out = [Column(c.dtype, data=c.data[order], validity=None if c.validity is None else c.validity[order])
+           for c in values]
+    return out, order
+
+
 @op_boundary("sort_by_key")
 def sort_by_key(values: Table, keys: Table, ascending=None, nulls_first=None) -> Table:
+    """``values`` in the stable order of ``keys`` (``gather(values,
+    sorted_order(keys, ...))``, to the bit): the key lanes, the sort and
+    every fixed-width column's gather are one program (``_sort_gather``);
+    a STRING key's lanes are made before it, a STRING or LIST value column
+    is gathered after it, through the order."""
     # what the sort waits for is what its caller left in the device's queue (q1: the seven
     # aggregate programs behind ``values``): named here, before the operator reads its input
     tracing.device_wait((keys, values), "sort_input")
-    order = sorted_order(keys, ascending, nulls_first)
-    return gather(values, order)
+    if values.num_rows == 0:  # nothing to order: the empty gather every caller of ``gather`` gets
+        return gather(values, sorted_order(keys, ascending, nulls_first))
+    ncols = keys.num_columns
+    asc = tuple(ascending) if ascending is not None else (True,) * ncols
+    nf = tuple(nulls_first) if nulls_first is not None else (True,) * ncols
+    string_lanes = tuple(tuple(string_key_lanes(c)) if c.dtype.id == TypeId.STRING else None
+                         for c in keys.columns)
+    # a STRING key enters as its validity and offsets (its null rank and length): no characters
+    key_cols = tuple(Column(c.dtype, validity=c.validity, offsets=c.offsets) if sl is not None else c
+                     for c, sl in zip(keys.columns, string_lanes))
+    if tracing.is_enabled():
+        tracing.annotate(
+            key_lanes=sum(_lane_count(c, sl) for c, sl, _, _ in zip(key_cols, string_lanes, asc, nf)),
+            string_keys=sum(sl is not None for sl in string_lanes),
+        )
+    fixed, order = _sort_gather(key_cols, string_lanes,
+                                tuple(c for c in values.columns if not _ragged(c)),
+                                ascending=asc, nulls_first=nf)
+    reg = metrics.registry()
+    reg.counter("sort.one_program").inc()
+    fixed = iter(fixed)
+    cols = []
+    for c in values.columns:
+        if _ragged(c):
+            reg.counter("sort.columns_outside").inc()
+            cols.append(gather_column(c, order))
+        else:
+            cols.append(next(fixed))
+    return Table(cols, values.names)

@@ -1095,7 +1095,7 @@ def test_a_q1s_programs_are_launched_under_the_spans_that_built_them(q1_sorted_s
         ("_body", "plan.filter"), ("_body", "plan.project"), ("_key_domain", "groupby.segments"),
         ("lexsort", "groupby.sort"),
         ("_f64_sum_mean", "groupby.agg.sum"), ("_f64_sum_mean", "groupby.agg.sum"),
-        ("_f64_sum_mean", "groupby.agg.mean"), ("lexsort", "op.sort_by_key")]
+        ("_f64_sum_mean", "groupby.agg.mean"), ("_sort_gather", "op.sort_by_key")]
 
 
 def test_a_dense_q1s_programs_are_launched_under_the_spans_that_built_them(q1_spans):
@@ -1103,7 +1103,7 @@ def test_a_dense_q1s_programs_are_launched_under_the_spans_that_built_them(q1_sp
         ("_body", "plan.filter"), ("_body", "plan.project"), ("_key_domain", "groupby.segments"),
         ("_slot_counts", "groupby.segments"), ("_slot_group_ids", "groupby.segments"),
         ("_f64_sum_mean", "groupby.agg.sum"), ("_f64_sum_mean", "groupby.agg.sum"),
-        ("_f64_sum_mean", "groupby.agg.mean"), ("lexsort", "op.sort_by_key")]
+        ("_f64_sum_mean", "groupby.agg.mean"), ("_sort_gather", "op.sort_by_key")]
 
 
 def test_a_compacting_filter_waits_for_its_mask_before_nonzero_reads_its_size():
@@ -1135,7 +1135,7 @@ def test_a_star_requests_waits_all_have_a_site_and_its_programs_a_layer(star_spa
     assert _launches(spans) == [
         ("lexsort", "join.factorize"), ("_string_lanes", "groupby.sort"), ("lexsort", "groupby.sort"),
         ("_string_lanes", "groupby.segments"), ("_to_float64_program", "plan.aggregate"),
-        ("_string_lanes", "op.sort_by_key"), ("lexsort", "op.sort_by_key")]
+        ("_string_lanes", "op.sort_by_key"), ("_sort_gather", "op.sort_by_key")]
 
 
 def test_the_paged_hash_join_waits_for_its_table_and_launches_its_probe(monkeypatch):
